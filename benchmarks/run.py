@@ -14,7 +14,9 @@
   bench_obs            (framework)     telemetry overhead + off-is-free
 
 Prints ``name,us_per_call,derived`` CSV. ``--full`` uses paper-scale rounds.
-Suites exposing ``LAST_RECORDS`` also write ``BENCH_<suite>.json``.
+Suites exposing ``LAST_RECORDS`` also write ``BENCH_<suite>.json``, stamped
+with the platform, device kind and device count they ran on. A failing
+suite does not stop the others, but the run then exits non-zero.
 """
 from __future__ import annotations
 
@@ -56,7 +58,7 @@ def main() -> None:
         "heterogeneity": bench_heterogeneity,
         "obs": bench_obs,
     }
-    rows = []
+    rows, failed = [], []
     for name, mod in suites.items():
         if args.only and name not in args.only:
             continue
@@ -67,10 +69,14 @@ def main() -> None:
         except Exception as e:  # a failing suite must not hide the others
             print(f"[{name}] FAILED: {type(e).__name__}: {e}", file=sys.stderr)
             rows.append((f"{name}_FAILED", 0.0, type(e).__name__))
+            failed.append(name)
         print(f"===== {name} done in {time.time()-t0:.0f}s =====", flush=True)
         if getattr(mod, "LAST_RECORDS", None):
             import jax
-            payload = {"platform": jax.default_backend(),
+            dev = jax.devices()[0]
+            payload = {"platform": dev.platform,
+                       "device_kind": dev.device_kind,
+                       "device_count": len(jax.devices()),
                        "quick": quick,
                        "entries": mod.LAST_RECORDS}
             out_path = os.path.join(REPO_ROOT, f"BENCH_{name}.json")
@@ -81,6 +87,10 @@ def main() -> None:
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        print(f"\n{len(failed)} suite(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

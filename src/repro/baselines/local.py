@@ -53,10 +53,10 @@ class LocalStrategy(Strategy):
 
 def train(train_x, train_y, test_x, test_y, *, rounds: int = 100, lr: float = 0.5,
           batch_size: int = 32, seed: int = 0, eval_every: int = 20,
-          dp_cfg=None, sigma: float = 0.0, schedule=None):
+          dp_cfg=None, sigma: float = 0.0, schedule=None, kernels=None):
     feat, classes = train_x.shape[-1], int(jnp.max(jnp.asarray(train_y))) + 1
     strategy = LocalStrategy(feat_dim=feat, num_classes=classes, lr=lr,
-                             dp_cfg=dp_cfg, sigma=sigma)
+                             dp_cfg=dp_cfg, sigma=sigma, kernels=kernels)
     data = FederatedData(train_x, train_y, test_x, test_y)
     state, hist = Engine(strategy, eval_every=eval_every,
                          schedule=schedule).fit(

@@ -757,7 +757,7 @@ def make_p4_lm_step(api_private, api_proxy, train_cfg: TrainConfig,
         vmap-only lowering leaked ~13 GB/step of embedding-gather traffic
         across pods, shard_map removes it by construction)."""
         from jax.sharding import PartitionSpec as P
-        from repro.sharding.rules import _CTX, shard_map_compat
+        from repro.sharding.rules import _CTX
         ctx = getattr(_CTX, "val", None)
         mesh = ctx[0] if ctx else None
         # NOTE: partial-manual shard_map over "pod" is the structurally right
@@ -765,7 +765,7 @@ def make_p4_lm_step(api_private, api_proxy, train_cfg: TrainConfig,
         # spmd_partitioner_util.cc) when nested auto axes remain — kept behind
         # a flag; the shipping fix is untied embeddings + unsharded gather
         # table (§Perf hillclimb 3, iter 3). The small-scale twin of this
-        # layout is the ShardedEngine client mesh (same compat wrapper).
+        # layout is the ShardedEngine client mesh.
         if (p4_cfg.manual_pod and mesh is not None
                 and "pod" in getattr(mesh, "axis_names", ())):
             pspec = lambda tree: jax.tree_util.tree_map(lambda _: P("pod"), tree)
@@ -774,11 +774,11 @@ def make_p4_lm_step(api_private, api_proxy, train_cfg: TrainConfig,
                 new_p, new_o, loss = _vmapped(p, o, b, k)
                 return new_p, new_o, jax.lax.pmean(jnp.mean(loss), "pod")
 
-            new_params, new_opt, loss = shard_map_compat(
-                body, mesh,
+            new_params, new_opt, loss = jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(pspec(params), pspec(opt_states), pspec(batch), P()),
                 out_specs=(pspec(params), pspec(opt_states), P()),
-                manual_axes={"pod"},
+                axis_names={"pod"}, check_vma=False,
             )(params, opt_states, batch, key)
             return new_params, new_opt, {"loss": loss}
         new_params, new_opt, loss = _vmapped(params, opt_states, batch, key)

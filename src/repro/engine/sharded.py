@@ -42,7 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.engine.loop import CHUNK_STATS, Engine, _cache_get, _cache_put
 from repro.engine.strategy import FederatedData, runtime_params
-from repro.sharding.rules import CLIENT_AXIS, client_specs, shard_map_compat
+from repro.sharding.rules import CLIENT_AXIS, client_specs
 
 
 def _pad_rows(arr, target: int):
@@ -315,11 +315,11 @@ class ShardedEngine(Engine):
                     st, _h = carry
                     return st, out
 
-            return shard_map_compat(
-                sharded, mesh,
+            return jax.shard_map(
+                sharded, mesh=mesh,
                 in_specs=(sspec, P(), P(axis), P(axis), P(), P())
                 + (P(None, axis),) * len(pf),
-                out_specs=(sspec, P()),
+                out_specs=(sspec, P()), check_vma=False,
             )(state, phase_key, train_x, train_y, rounds, rt, *pf)
 
         jfn = jax.jit(chunk, donate_argnums=0)

@@ -15,6 +15,16 @@ Tile sizes are autotuned on first use and cached per
 ``(kernel, shape, dtype, backend)``; explicit tiles in ``KernelConfig``
 bypass the tuner. The cache is process-global — every jit trace after the
 first hits it, so tracing inside vmap/scan pays the search exactly once.
+The search itself always runs eagerly, in a fresh thread (JAX's trace
+state is per thread), even when the first use is inside a jit/vmap/scan
+trace, so it times device execution of each candidate and never the staging
+of traced ops. A candidate that fails to compile or run is counted in the
+``kernels.autotune`` probe; when every candidate fails the search raises,
+naming the kernel and shape.
+
+Every dispatch records the backend it resolved to in the
+``kernels.backend`` probe (``"<kernel>:<backend>"`` counters, bumped once
+per trace), so a run can prove which implementation it traced.
 
 Fused DP-SGD entry points (paper Eqs. 10–11 hot loop): ``dp_clip`` /
 ``dp_clip_flat`` fuse flatten→norm→scale→accumulate→noise so the (B, D)
@@ -26,6 +36,7 @@ per-leaf noise loop.
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -75,7 +86,14 @@ _TUNE_CACHE: Dict[_TuneKey, Tuple[int, ...]] = {}
 # seconds the searches spent, per scope via probe_deltas("kernels.autotune")
 _TUNE_STATS = Probe("kernels.autotune", {"hits": 0, "misses": 0,
                                          "candidates_timed": 0,
+                                         "candidates_failed": 0,
                                          "search_seconds": 0.0})
+
+_KERNELS = ("dp_clip", "dp_round", "l1_distance")
+# resolved backend per dispatched kernel, counted at trace time
+_BACKEND_STATS = Probe("kernels.backend",
+                       {f"{k}:{b}": 0 for k in _KERNELS
+                        for b in ("pallas", "interpret", "ref")})
 
 
 def clear_autotune_cache() -> None:
@@ -87,15 +105,31 @@ def autotune_cache_stats() -> Dict[str, int]:
     return dict(_TUNE_STATS, entries=len(_TUNE_CACHE))
 
 
+def tuned_tiles() -> Dict[str, Dict[Tuple[int, ...], Tuple[int, ...]]]:
+    """The tuner's choices so far: kernel -> {shape: tile}."""
+    out: Dict[str, Dict[Tuple[int, ...], Tuple[int, ...]]] = {}
+    for (name, shape, _, _), tile in _TUNE_CACHE.items():
+        out.setdefault(name, {})[shape] = tile
+    return out
+
+
+def _resolve_for(kernel_name: str, cfg: KernelConfig) -> str:
+    backend = resolve_backend(cfg.backend)
+    _BACKEND_STATS[f"{kernel_name}:{backend}"] += 1
+    return backend
+
+
 def autotune(kernel_name: str, shape: Sequence[int], dtype, backend: str,
              candidates: Sequence[Tuple[int, ...]],
              time_fn: Callable[[Tuple[int, ...]], float],
              trials: int = 2) -> Tuple[int, ...]:
     """Pick the fastest candidate tiling for ``kernel_name`` on ``shape``.
 
-    ``time_fn(candidate) -> seconds`` runs one timed call; candidates that
-    raise are skipped. The winner is memoized per (kernel, shape, dtype,
-    backend) so repeated traces (vmap/scan/re-jit) never re-search."""
+    ``time_fn(candidate) -> seconds`` runs one timed call, in a thread of its
+    own so that it executes eagerly even when called under a trace. A
+    candidate that raises is counted in ``candidates_failed`` and skipped;
+    if none survives, this raises with every candidate's error. The winner is memoized per (kernel, shape,
+    dtype, backend) so repeated traces (vmap/scan/re-jit) never re-search."""
     key: _TuneKey = (kernel_name, tuple(int(s) for s in shape),
                      jnp.dtype(dtype).name, backend)
     if key in _TUNE_CACHE:
@@ -103,24 +137,46 @@ def autotune(kernel_name: str, shape: Sequence[int], dtype, backend: str,
         return _TUNE_CACHE[key]
     _TUNE_STATS["misses"] += 1
     search_t0 = time.perf_counter()
-    best, best_t = None, float("inf")
-    for cand in candidates:
-        try:
-            t = min(float(time_fn(cand)) for _ in range(max(1, trials)))
-        except Exception:
-            continue
-        _TUNE_STATS["candidates_timed"] += 1
-        if t < best_t:
-            best, best_t = tuple(cand), t
+    errors = []
+
+    def search():
+        best, best_t = None, float("inf")
+        for cand in candidates:
+            try:
+                t = min(float(time_fn(cand)) for _ in range(max(1, trials)))
+            except Exception as e:  # noqa: BLE001 — reported below
+                _TUNE_STATS["candidates_failed"] += 1
+                errors.append(f"  {tuple(cand)}: {type(e).__name__}: "
+                              f"{str(e).splitlines()[0] if str(e) else ''}")
+                continue
+            _TUNE_STATS["candidates_timed"] += 1
+            if t < best_t:
+                best, best_t = tuple(cand), t
+        return best
+
+    # a fresh thread has no trace open: there each candidate compiles and
+    # runs on the device even when this call sits inside jit/vmap/scan
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        best = pool.submit(search).result()
     _TUNE_STATS["search_seconds"] += time.perf_counter() - search_t0
     if best is None:
-        best = tuple(candidates[0])
+        raise RuntimeError(
+            f"autotune: every candidate tiling failed for {kernel_name} at "
+            f"shape {key[1]} ({key[2]}, backend={backend}):\n"
+            + "\n".join(errors))
     _TUNE_CACHE[key] = best
     return best
 
 
 def _timed(fn, *args) -> float:
-    jax.block_until_ready(fn(*args))  # compile / warm up
+    """Device seconds of one call of ``fn`` (after a warm-up call that
+    compiles it). Refuses to time a traced call: that would measure staging."""
+    out = fn(*args)
+    if any(isinstance(leaf, jax.core.Tracer)
+           for leaf in jax.tree_util.tree_leaves(out)):
+        raise RuntimeError("autotune candidate was staged into a trace, "
+                           "not executed")
+    jax.block_until_ready(out)
     t0 = time.perf_counter()
     jax.block_until_ready(fn(*args))
     return time.perf_counter() - t0
@@ -270,7 +326,7 @@ def clip_accumulate(flat, clip: float, *, denom: float = 1.0,
     Reads (B, D) at most twice on every backend (norm pass +
     scale-accumulate pass with the mean folded into the scales)."""
     cfg = _cfg(kernels)
-    backend = resolve_backend(cfg.backend)
+    backend = _resolve_for("dp_clip", cfg)
     if backend == "ref":
         return dp_ref.clip_accumulate(flat, clip, denom=denom)
     tb, td = dp_clip_tiles(tuple(flat.shape), flat.dtype, cfg, backend)
@@ -321,7 +377,7 @@ def dp_round(loss_fn, params, x, y, key=None, *, clip: float,
     if not dp_ref.static_zero_sigma(sigma) and key is None:
         raise ValueError("sigma > 0 requires a PRNG key (privacy guard)")
     cfg = _cfg(kernels)
-    backend = resolve_backend(cfg.backend)
+    backend = _resolve_for("dp_round", cfg)
     if backend == "ref":
         return dpr_ref.dp_round_reference(loss_fn, params, x, y, key,
                                           clip=clip, sigma=sigma)
@@ -336,7 +392,7 @@ def dp_round(loss_fn, params, x, y, key=None, *, clip: float,
 def pairwise_l1(weights, kernels: Optional[KernelConfig] = None):
     """weights: (M, D) -> (M, M) ℓ1 distances (paper Eq. 3)."""
     cfg = _cfg(kernels)
-    backend = resolve_backend(cfg.backend)
+    backend = _resolve_for("l1_distance", cfg)
     if backend == "ref":
         return l1_ref.pairwise_l1(weights)
     tm, td = l1_tiles(tuple(weights.shape), weights.dtype, cfg, backend)

@@ -3,12 +3,18 @@
 Two passes over the (B, D) per-example flat-gradient matrix:
 
   1. ``sq_norms``        — per-example Σ g², tiled over D (VMEM-resident
-                           (TB, TD) tiles; fp32 accumulation into (B,) out).
+                           (TB, TD) tiles; fp32 accumulation into a (B, 1)
+                           column).
   2. ``scale_accumulate``— Σ_b scale_b · g_b, tiled over (B, D); the B grid
-                           axis accumulates into the (TD,) output tile.
+                           axis accumulates into the (1, TD) output row.
 
 Tiling: TD = 16k lanes (128-aligned; 8·16k·4 B ≈ 0.5 MB per tile, well under
 the ~16 MB v5e VMEM even with double buffering), TB = 8 sublanes.
+
+Per-example vectors travel as 2-D (B, 1) columns and the accumulator as a
+(1, D) row: Mosaic accepts a block whose last two dims are (8k, 128k) or
+equal to the array's, and a rank-1 (TB,) block is neither — nor is the
+(Squeezed, TB) block it becomes when the engine vmaps the call over clients.
 """
 from __future__ import annotations
 
@@ -31,7 +37,7 @@ def _sq_norm_kernel(x_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    out_ref[...] += jnp.sum(x * x, axis=1)
+    out_ref[...] += jnp.sum(x * x, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "td", "interpret"))
@@ -45,10 +51,10 @@ def sq_norms(x, tb: int = DEFAULT_TB, td: int = DEFAULT_TD, interpret: bool = Tr
         _sq_norm_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((tb, td), lambda b, d: (b, d))],
-        out_specs=pl.BlockSpec((tb,), lambda b, d: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
+        out_specs=pl.BlockSpec((tb, 1), lambda b, d: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
         interpret=interpret,
-    )(x)
+    )(x)[:, 0]
 
 
 def _scale_acc_kernel(x_ref, s_ref, out_ref):
@@ -59,8 +65,8 @@ def _scale_acc_kernel(x_ref, s_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (TB, TD)
-    s = s_ref[...].astype(jnp.float32)          # (TB,)
-    out_ref[...] += jnp.einsum("bd,b->d", x, s)
+    s = s_ref[...].astype(jnp.float32)          # (TB, 1)
+    out_ref[...] += jnp.sum(x * s, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "td", "interpret"))
@@ -76,9 +82,9 @@ def scale_accumulate(x, scales, tb: int = DEFAULT_TB, td: int = DEFAULT_TD,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tb, td), lambda d, b: (b, d)),
-            pl.BlockSpec((tb,), lambda d, b: (b,)),
+            pl.BlockSpec((tb, 1), lambda d, b: (b, 0)),
         ],
-        out_specs=pl.BlockSpec((td,), lambda d, b: (d,)),
-        out_shape=jax.ShapeDtypeStruct((D,), jnp.float32),
+        out_specs=pl.BlockSpec((1, td), lambda d, b: (0, d)),
+        out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
-    )(x, scales)
+    )(x, scales.reshape(B, 1))[0]

@@ -12,6 +12,10 @@ axis that grows with model size; B and C are round-constants):
 Between the passes the host-side op computes softmax−onehot, the factored
 per-example clip scales, and the bias gradient — O(B·C) work that stays in
 jnp. MXU matmuls accumulate in f32 via ``preferred_element_type``.
+
+The bias enters as a (1, C) row and ‖x‖² leaves as a (B, 1) column: under
+the engine's vmap over clients a rank-1 block becomes (Squeezed, n), which
+Mosaic refuses unless n spans the whole client axis.
 """
 from __future__ import annotations
 
@@ -37,7 +41,7 @@ def _logits_xsq_kernel(x_ref, w_ref, b_ref, logits_ref, xsq_ref):
     x = x_ref[...].astype(jnp.float32)              # (B, TF)
     logits_ref[...] += jnp.dot(x, w_ref[...].astype(jnp.float32),
                                preferred_element_type=jnp.float32)
-    xsq_ref[...] += jnp.sum(x * x, axis=1)
+    xsq_ref[...] += jnp.sum(x * x, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tf", "interpret"))
@@ -48,24 +52,25 @@ def logits_xsq(x, w, b, tf: int = DEFAULT_TF, interpret: bool = True):
     C = w.shape[1]
     tf = min(tf, F)
     assert F % tf == 0, (F, tf)
-    return pl.pallas_call(
+    logits, xsq = pl.pallas_call(
         _logits_xsq_kernel,
         grid=(F // tf,),
         in_specs=[
             pl.BlockSpec((B, tf), lambda f: (0, f)),
             pl.BlockSpec((tf, C), lambda f: (f, 0)),
-            pl.BlockSpec((C,), lambda f: (0,)),
+            pl.BlockSpec((1, C), lambda f: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((B, C), lambda f: (0, 0)),
-            pl.BlockSpec((B,), lambda f: (0,)),
+            pl.BlockSpec((B, 1), lambda f: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, C), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, w, b)
+    )(x, w, b.reshape(1, C))
+    return logits, xsq[:, 0]
 
 
 def _wgrad_kernel(x_ref, sdl_ref, out_ref):

@@ -11,6 +11,11 @@ Lower-triangle tiles are never written — the ops wrapper mirrors the upper
 triangle back (``tri + strict_tri.T``). VPU-only (abs/add) — no MXU use,
 which is why this beats an einsum-based |a−b| formulation that would
 materialize (M, M, D).
+
+The pair → (row, col) table is decoded once in jnp and handed to the grid
+as scalar prefetch, so the index maps are plain SMEM loads (index maps run
+on the scalar unit). The output is a (T, T, TM, TM) tile array: a (TM, TM)
+block of an (M, M) matrix is not (8, 128)-aligned, a whole tile is.
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TM = 8
 DEFAULT_TD = 8192
@@ -38,7 +44,7 @@ def tri_decode(p):
     return r, c
 
 
-def _l1_kernel(xi_ref, xj_ref, out_ref):
+def _l1_kernel(rows_ref, cols_ref, xi_ref, xj_ref, out_ref):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
@@ -58,15 +64,22 @@ def pairwise_l1(x, tm: int = DEFAULT_TM, td: int = DEFAULT_TD, interpret: bool =
     tm, td = min(tm, M), min(td, D)
     assert M % tm == 0 and D % td == 0, (M, tm, D, td)
     T = M // tm
-    grid = (T * (T + 1) // 2, D // td)          # D innermost: reduction axis
-    return pl.pallas_call(
-        _l1_kernel,
-        grid=grid,
+    P = T * (T + 1) // 2
+    rows, cols = tri_decode(jnp.arange(P, dtype=jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(P, D // td),                      # D innermost: reduction axis
         in_specs=[
-            pl.BlockSpec((tm, td), lambda p, k: (tri_decode(p)[0], k)),
-            pl.BlockSpec((tm, td), lambda p, k: (tri_decode(p)[1], k)),
+            pl.BlockSpec((tm, td), lambda p, k, r, c: (r[p], k)),
+            pl.BlockSpec((tm, td), lambda p, k, r, c: (c[p], k)),
         ],
-        out_specs=pl.BlockSpec((tm, tm), lambda p, k: tri_decode(p)),
-        out_shape=jax.ShapeDtypeStruct((M, M), jnp.float32),
+        out_specs=pl.BlockSpec((None, None, tm, tm),
+                               lambda p, k, r, c: (r[p], c[p], 0, 0)),
+    )
+    tiles = pl.pallas_call(
+        _l1_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, T, tm, tm), jnp.float32),
         interpret=interpret,
-    )(x, x)
+    )(rows, cols, x, x)
+    return tiles.transpose(0, 2, 1, 3).reshape(M, M)

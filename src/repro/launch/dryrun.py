@@ -165,7 +165,7 @@ def _outer_count(cfg) -> int:
 def _measure(cfg, shape, mesh, mesh_cfg, rules, *, p4, fsdp):
     compiled = _lower_for(cfg, shape, mesh, mesh_cfg, rules,
                           p4=p4, fsdp=fsdp).compile()
-    c = roofline.cost_analysis_dict(compiled)
+    c = compiled.cost_analysis()
     hlo = compiled.as_text()
     hb = roofline.hbm_bytes(hlo)
     return {"flops": float(c.get("flops", 0.0)),
@@ -293,7 +293,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
             meas = m1
     except Exception as e:  # fall back to the scan artifact, flagged
         cost_src = f"scan-fallback ({type(e).__name__}: {e})"
-        c = roofline.cost_analysis_dict(compiled)
+        c = compiled.cost_analysis()
         hlo0 = compiled.as_text()
         meas = {"flops": float(c.get("flops", 0.0)),
                 "bytes_unfused": float(c.get("bytes accessed", 0.0)),

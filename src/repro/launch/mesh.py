@@ -2,15 +2,27 @@
 
 Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh is built with ``Auto`` axis types. ``jax.make_mesh`` defaults to
+``Explicit`` axes, which put the mesh axis into each placed array's type: a
+client stack moved off the mesh onto one device would still carry
+``@clients``, and vmapping it beside an unsharded array then fails.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
 from repro.sharding.rules import CLIENT_AXIS
+
+
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,11 +32,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     ``model`` (tensor parallel, ICI)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return _auto_mesh(cfg.shape, cfg.axis_names)
 
 
 def host_mesh_shape(data: int, model: int, num_devices: int) -> Tuple[int, int]:
@@ -50,8 +62,8 @@ def make_host_mesh(data: int = 1, model: int = 1, *,
     it uses (the product may be smaller than the device count)."""
     n = num_devices if num_devices is not None else len(jax.devices())
     data, model = host_mesh_shape(data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+    return _auto_mesh((data, model), ("data", "model"),
+                      devices=jax.devices()[: data * model])
 
 
 def make_client_mesh(clients: Optional[int] = None, *, axis: str = CLIENT_AXIS):
@@ -60,4 +72,4 @@ def make_client_mesh(clients: Optional[int] = None, *, axis: str = CLIENT_AXIS):
     shard of the (M, ...) state/data stacks. Default: every host device."""
     n = len(jax.devices())
     clients = n if clients is None else max(1, min(int(clients), n))
-    return jax.make_mesh((clients,), (axis,), devices=jax.devices()[:clients])
+    return _auto_mesh((clients,), (axis,), devices=jax.devices()[:clients])

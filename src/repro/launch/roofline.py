@@ -39,17 +39,6 @@ _OP_RE = re.compile(
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` returns a dict in some JAX versions and a
-    one-element list of dicts in others — normalize to a dict. Lives here
-    (not dryrun.py) so test subprocesses can import it without dryrun's
-    import-time XLA_FLAGS mutation."""
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return c
-
-
 def _shape_bytes(type_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(type_str):

@@ -97,10 +97,6 @@ def _moe_local(params, xf, cfg: ModelConfig, C: int):
     if S == 1 or T % S or C % S or f % msz:
         return None, None
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map  # noqa: F811
-
     w_specs = {
         "router": P(),                        # (d, E) small — replicate
         "w_gate": P(None, None, "model"),     # ff tensor-parallel
@@ -116,7 +112,7 @@ def _moe_local(params, xf, cfg: ModelConfig, C: int):
         out, aux = _moe_tokens_tp(p, x_local, cfg, C // S, model_axis="model")
         return out, jax.lax.pmean(aux, data_axes)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(w_specs, P(data_axes, None)),
         out_specs=(P(data_axes, None), P()),

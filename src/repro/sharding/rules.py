@@ -135,27 +135,6 @@ def client_specs(tree, stacked: int, axis: str = CLIENT_AXIS):
         spec, tree, is_leaf=lambda x: hasattr(x, "shape"))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, *, manual_axes=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=...)`` where partial-manual regions are expressed as the
-    complement (``auto`` = axes NOT under manual control). ``manual_axes``
-    None means fully manual over every mesh axis."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    kw = {"check_rep": False}
-    if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def batch_axes(mesh_cfg: MeshConfig):
     """Mesh axes that shard the global batch (pod joins data in multi-pod)."""
     return ("pod", "data") if mesh_cfg.multi_pod else ("data",)

@@ -473,6 +473,7 @@ def main() -> None:
         "rounds_equal": h1.rounds == h2.rounds,
         "accuracy_bit_equal": h1.accuracy == h2.accuracy,
         "state_bit_equal": tree_bit_equal(st1, st2),
+        "state_maxdiff": tree_maxdiff(st1, st2),
         "metrics_maxdiff": float(max(
             max(abs(p - q) for p, q in zip(v, h2.metrics[k]))
             for k, v in h1.metrics.items())),

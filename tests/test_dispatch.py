@@ -80,6 +80,65 @@ def test_autotune_skips_failing_candidates():
     got = dispatch.autotune("l1_distance", (8, 8192), jnp.float32, "pallas",
                             [(8, 2048), (16, 2048)], time_fn, trials=1)
     assert got == (16, 2048)
+    stats = dispatch.autotune_cache_stats()
+    assert stats["candidates_failed"] == 1 and stats["candidates_timed"] == 1
+
+
+def test_autotune_all_candidates_failing_raises():
+    """No silent fallback to candidates[0]: a search in which every tiling
+    fails names the kernel and shape, and caches nothing."""
+    dispatch.clear_autotune_cache()
+
+    def time_fn(cand):
+        raise RuntimeError(f"refused {cand}")
+
+    with pytest.raises(RuntimeError, match=r"l1_distance at shape \(64, 8192\)"):
+        dispatch.autotune("l1_distance", (64, 8192), jnp.float32, "pallas",
+                          [(8, 2048), (16, 2048)], time_fn, trials=1)
+    stats = dispatch.autotune_cache_stats()
+    assert stats["candidates_failed"] == 2 and stats["entries"] == 0
+
+
+def test_autotune_under_jit_records_no_trace_time_timings():
+    """A search first reached inside a jit/scan/vmap trace still executes
+    each candidate (here a Pallas kernel) eagerly: what is timed is a
+    concrete array, never a tracer."""
+    dispatch.clear_autotune_cache()
+    traced = []
+
+    def time_fn(cand):
+        x = jnp.ones((16, 256))
+
+        def run(a):
+            out = l1_ops.pairwise_l1(a, tm=cand[0], td=128)
+            traced.append(isinstance(out, jax.core.Tracer))
+            return out
+        return dispatch._timed(run, x)
+
+    def client(y):
+        (t,) = dispatch.autotune("probe", (16, 256), jnp.float32, "pallas",
+                                 [(8,), (16,)], time_fn, trials=1)
+        return y * t
+
+    jax.jit(lambda ys: jax.lax.scan(
+        lambda c, y: (c, jax.vmap(client)(y)), 0, ys))(jnp.ones((2, 3)))
+    assert traced and not any(traced)
+    stats = dispatch.autotune_cache_stats()
+    assert stats["candidates_timed"] == 2 and stats["candidates_failed"] == 0
+    # and the timer itself refuses a call that was only staged
+    with pytest.raises(RuntimeError, match="staged"):
+        jax.jit(lambda y: dispatch._timed(lambda a: a + y, jnp.ones(3)))(1.0)
+
+
+def test_backend_probe_records_resolved_backend(key):
+    from repro.obs import probe_deltas
+    w = jax.random.normal(key, (8, 256))
+    with probe_deltas("kernels.backend") as d:
+        dispatch.pairwise_l1(w, kernels=KernelConfig(backend="ref"))
+        dispatch.clip_accumulate(w, 1.0, kernels=KernelConfig(
+            backend="interpret", dp_clip_tile=(8, 128)))
+    got = {k: v for k, v in d["kernels.backend"].items() if v}
+    assert got == {"l1_distance:ref": 1, "dp_clip:interpret": 1}
 
 
 def test_explicit_tile_override_bypasses_autotune():

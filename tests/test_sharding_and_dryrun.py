@@ -67,7 +67,8 @@ def test_mini_dryrun_subprocess():
 
         cfg = get_reduced_config("llama3.2-1b")
         mesh_cfg = MeshConfig(data=4, model=2)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         rules = make_rules(cfg, mesh_cfg, kind="train")
         api = build_model(cfg)
         shape = InputShape("mini", 64, 8, "train")
@@ -86,8 +87,7 @@ def test_mini_dryrun_subprocess():
                               out_shardings=(p_shard, o_shard, None)).lower(
                 params_abs, opt_abs, input_specs(cfg, shape))
             compiled = lowered.compile()
-        from repro.launch.roofline import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         print(json.dumps({"flops": cost.get("flops", 0.0),
                           "ok": True}))
     """)
