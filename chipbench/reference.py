@@ -1,0 +1,392 @@
+"""Plain reference of P4 co-training (arXiv:2405.17697, Eqs. 3-12).
+
+Written from the paper and the configuration alone; it imports nothing of
+the program under test. Each step is the literal definition: per-example
+gradients by ``vmap(grad)``, per-example clipping to the global norm C
+(Eq. 10), the clipped mean plus Gaussian noise (2C/n)·σ·N(0, I) (Eq. 11),
+σ from Eq. 12, plain SGD, and the group mean of the proxy models.
+
+Two conventions are shared with the system under test because they are part
+of the run's inputs, not of its arithmetic:
+
+* random streams: round r uses ``rk = fold_in(phase_key, r)``; stream 0
+  draws the batch indices (``randint`` over the local rows, with
+  replacement), stream 1 is split into one key per client, and local step k
+  of a client uses ``fold_in(client_key, k)`` as its noise key; a sampled
+  schedule draws its cohort from stream 3;
+* the noise vector is drawn once per client step as a flat (D,) float32
+  normal and laid over the parameters in sorted key order, each leaf
+  raveled row-major.
+
+``dtype=jnp.bfloat16`` runs the whole reference in bfloat16 (parameters,
+data, activations, updates): the lower-precision control.
+"""
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------- models
+
+def param_shapes(cfg):
+    """Parameter shapes of one model, by key, from the configuration."""
+    F, C = cfg["feat_dim"], cfg["num_classes"]
+    if cfg["model"] == "linear":
+        return {"w": (F, C), "b": (C,)}
+    ch, h, w = cfg["cnn_shape"]
+    width = cfg["cnn_width"]
+    feat = 2 * width * max(h // 4, 1) * max(w // 4, 1)
+    return {"c1": (width, ch, 3, 3), "c2": (2 * width, width, 3, 3),
+            "w": (feat, C), "b": (C,)}
+
+
+def param_count(cfg) -> int:
+    return sum(math.prod(s) for s in param_shapes(cfg).values())
+
+
+def _init_model(cfg, key):
+    out = {}
+    for i, (name, shape) in enumerate(sorted(param_shapes(cfg).items())):
+        if name == "b":
+            out[name] = jnp.zeros(shape, jnp.float32)
+            continue
+        fan_in = math.prod(shape[1:]) if len(shape) == 4 else shape[0]
+        out[name] = (jax.random.normal(jax.random.fold_in(key, i), shape,
+                                       jnp.float32) / math.sqrt(fan_in))
+    return out
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _init_state(cfg_items, M, key):
+    cfg = dict(cfg_items)
+
+    def stack(p):
+        return jax.tree_util.tree_map(
+            lambda t: jnp.broadcast_to(t[None], (M,) + t.shape), p)
+    return {"private": stack(_init_model(cfg, jax.random.fold_in(key, 0))),
+            "proxy": stack(_init_model(cfg, jax.random.fold_in(key, 1)))}
+
+
+def init_state(cfg, M: int, key):
+    """One initialization shared by every client (private and proxy models
+    drawn apart), stacked over M clients, made on the device in one call."""
+    return _init_state(_hashable(cfg), M, key)
+
+
+def _hashable(cfg):
+    keys = ("model", "feat_dim", "num_classes", "cnn_shape", "cnn_width")
+    return tuple((k, tuple(cfg[k]) if isinstance(cfg.get(k), list)
+                  else cfg.get(k)) for k in keys)
+
+
+def apply(cfg, params, x, prec):
+    """Logits of one model on a batch x (B, F)."""
+    dt = x.dtype
+    if cfg["model"] == "linear":
+        return jnp.dot(x, params["w"], precision=prec) + params["b"]
+    ch, h, w = cfg["cnn_shape"]
+    t = x.reshape(x.shape[0], ch, h, w)
+
+    def conv(t, k):
+        return jax.lax.conv_general_dilated(
+            t, k, (1, 1), "SAME", dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            precision=prec)
+
+    def pool(t):
+        return jax.lax.reduce_window(t, -jnp.inf, jax.lax.max,
+                                     (1, 1, 2, 2), (1, 1, 2, 2), "VALID")
+    t = pool(jax.nn.relu(conv(t, params["c1"])))
+    t = pool(jax.nn.relu(conv(t, params["c2"])))
+    t = t.reshape(t.shape[0], -1)
+    return jnp.dot(t, params["w"], precision=prec) + params["b"]
+
+
+def _ce(logits, y):
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=-1))
+
+
+def _kl(p_logits, q_logits):
+    p = jax.nn.log_softmax(p_logits, axis=-1)
+    q = jax.nn.log_softmax(q_logits, axis=-1)
+    return jnp.mean(jnp.sum(jnp.exp(p) * (p - q), axis=-1))
+
+
+def mutual_loss(logits, other_logits, y, weight):
+    """Eqs. 8-9: (1 - a)·CE(f, y) + a·KL(f ‖ g), g held constant."""
+    other = jax.lax.stop_gradient(other_logits)
+    return (1.0 - weight) * _ce(logits, y) + weight * _kl(logits, other)
+
+
+def noble_sigma(epsilon, delta, sample_rate, rounds, local_steps):
+    """Eq. 12 with l = M' = 1 (the P2P setting)."""
+    s, T, K = sample_rate, rounds, local_steps
+    return float(s * math.sqrt(T * K * math.log(2 * T / delta)
+                               * math.log(2 / delta)) / epsilon)
+
+
+# ---------------------------------------------------------------- one client
+
+def _flat(tree):
+    return jnp.concatenate([jnp.ravel(tree[k]) for k in sorted(tree)])
+
+
+def _unflat(vec, like):
+    out, off = {}, 0
+    for k in sorted(like):
+        n = like[k].size
+        out[k] = vec[off:off + n].reshape(like[k].shape)
+        off += n
+    return out
+
+
+def _client_steps(cfg, hp, private, proxy, x, y, ckey, sigma, prec,
+                  half_batch=False):
+    """K local steps of one client, then its losses at the updated models.
+    ``half_batch`` plants a fault: the step uses half of the batch and takes
+    the mean over that half."""
+    dt = x.dtype
+    lr, clip = hp["lr"], hp["clip"]
+    if half_batch:
+        x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
+    n = x.shape[0]
+    noise_scale = (jnp.float32(2.0 * clip / n) * jnp.float32(sigma))
+
+    def step(carry, k):
+        pr, px = carry
+        px_logits = apply(cfg, px, x, prec)
+        g_pr = jax.grad(lambda th: mutual_loss(apply(cfg, th, x, prec),
+                                               px_logits, y, hp["beta"]))(pr)
+
+        def one(xi, yi):
+            tgt = apply(cfg, pr, xi[None], prec)
+            g = jax.grad(lambda w: mutual_loss(apply(cfg, w, xi[None], prec),
+                                               tgt, yi[None],
+                                               hp["alpha"]))(px)
+            norm = jnp.sqrt(sum(jnp.sum(jnp.square(v)) for v in g.values()))
+            scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
+            return _flat(g) * scale.astype(dt)
+
+        per_ex = jax.vmap(one)(x, y)                       # (n, D)
+        mean = jnp.sum(per_ex, axis=0) / jnp.asarray(n, dt)
+        noise = jax.random.normal(jax.random.fold_in(ckey, k), mean.shape,
+                                  jnp.float32)
+        g_px = _unflat(mean + (noise_scale * noise).astype(dt), px)
+        pr = jax.tree_util.tree_map(lambda p, g: p - (lr * g).astype(dt),
+                                    pr, g_pr)
+        px = jax.tree_util.tree_map(lambda p, g: p - (lr * g).astype(dt),
+                                    px, g_px)
+        return (pr, px), None
+
+    (private, proxy), _ = jax.lax.scan(step, (private, proxy),
+                                       jnp.arange(hp["local_steps"]))
+    pr_logits = apply(cfg, private, x, prec)
+    px_logits = apply(cfg, proxy, x, prec)
+    losses = jnp.stack([mutual_loss(pr_logits, px_logits, y, hp["beta"]),
+                        mutual_loss(px_logits, pr_logits, y, hp["alpha"])])
+    return private, proxy, losses.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------- one round
+
+def _group_mean(tree, ids, num_groups, mask):
+    counts = jax.ops.segment_sum(mask, ids, num_groups)
+
+    def f(x):
+        w = mask.reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
+        sums = jax.ops.segment_sum(x * w, ids, num_groups)
+        denom = jnp.maximum(counts, 1.0).reshape((-1,) + (1,) * (x.ndim - 1))
+        mean = (sums / denom.astype(x.dtype))[ids]
+        return jnp.where(w > 0, mean, x)
+    return jax.tree_util.tree_map(f, tree)
+
+
+def _cohort(schedule, key, M):
+    kind = schedule.get("kind", "full")
+    if kind == "full":
+        return jnp.ones((M,), jnp.float32)
+    if kind != "sampling":
+        raise ValueError(f"reference has no schedule {kind!r}")
+    k1, _ = jax.random.split(key)
+    q = schedule["client_rate"]
+    u = jax.random.uniform(k1, (M,))
+    if schedule.get("mode", "bernoulli") == "fixed":
+        _, idx = jax.lax.top_k(-u, max(1, int(round(q * M))))
+        return jnp.zeros((M,), jnp.float32).at[idx].set(1.0)
+    return (u < q).astype(jnp.float32)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _round(cfg_items, hp_items, schedule_items, batch, block, fault, state,
+           train_x, train_y, phase_key, r, sigma, ids, num_groups_arr):
+    cfg, hp, schedule = dict(cfg_items), dict(hp_items), dict(schedule_items)
+    dt = jax.tree_util.tree_leaves(state)[0].dtype
+    prec = HIGHEST if dt == jnp.float32 else None
+    M, R = train_y.shape
+    rk = jax.random.fold_in(phase_key, r)
+    if batch:
+        idx = jax.random.randint(jax.random.fold_in(rk, 0), (M, batch), 0, R)
+    else:
+        idx = jnp.broadcast_to(jnp.arange(R), (M, R))
+    keys = jax.random.split(jax.random.fold_in(rk, 1), M)
+
+    def one_block(i):
+        def rows(t):
+            return jax.lax.dynamic_slice_in_dim(t, i * block, block)
+        pr, px = (jax.tree_util.tree_map(rows, state[m])
+                  for m in ("private", "proxy"))
+        ib, kb = rows(idx), rows(keys)
+        xs = jnp.take_along_axis(rows(train_x), ib[:, :, None],
+                                 axis=1).astype(dt)
+        ys = jnp.take_along_axis(rows(train_y), ib, axis=1)
+        return jax.vmap(lambda p, q, x, y, k: _client_steps(
+            cfg, hp, p, q, x, y, k, sigma, prec,
+            half_batch=(fault == "half_batch")))(pr, px, xs, ys, kb)
+
+    pr, px, losses = jax.lax.map(one_block, jnp.arange(M // block))
+    unblock = partial(jax.tree_util.tree_map,
+                      lambda t: t.reshape((M,) + t.shape[2:]))
+    new = {"private": unblock(pr), "proxy": unblock(px)}
+    losses = losses.reshape(M, 2)
+    mask = _cohort(schedule, jax.random.fold_in(rk, 3), M)
+
+    def merge(a, b):
+        return jax.tree_util.tree_map(
+            lambda n, o: jnp.where(mask.reshape((-1,) + (1,) * (n.ndim - 1))
+                                   > 0, n, o), a, b)
+    new = merge(new, state)
+    if ids is not None:
+        new = {"private": new["private"],
+               "proxy": _group_mean(new["proxy"], ids,
+                                    num_groups_arr.shape[0], mask)}
+        new = merge(new, state)
+    return new, jnp.mean(losses, axis=0)
+
+
+def run_rounds(cfg, hp, schedule, state, data, phase_key, start, stop,
+               batch, sigma, groups=None, block=8, fault=None):
+    """Rounds [start, stop) of the reference. Returns (state, losses) with
+    losses (rounds, 2): the mean private and proxy loss of each round."""
+    ids = G = None
+    if groups is not None:
+        ids_np = np.zeros((data["train_y"].shape[0],), np.int32)
+        for gi, g in enumerate(groups):
+            ids_np[list(g)] = gi
+        ids, G = jnp.asarray(ids_np), jnp.zeros((len(groups),))
+    out = []
+    for r in range(start, stop):
+        state, losses = _round(
+            _hashable(cfg), tuple(sorted(hp.items())),
+            tuple(sorted(schedule.items())), batch, block, fault, state,
+            data["train_x"], data["train_y"], phase_key, r,
+            jnp.float32(sigma), ids, G)
+        out.append(losses)
+    return state, np.asarray(jnp.stack(out), np.float64)
+
+
+@partial(jax.jit, static_argnums=0)
+def _evaluate(cfg_items, private, test_x, test_y):
+    cfg = dict(cfg_items)
+    dt = jax.tree_util.tree_leaves(private)[0].dtype
+    prec = HIGHEST if dt == jnp.float32 else None
+
+    def one(p, x, y):
+        pred = jnp.argmax(apply(cfg, p, x.astype(dt), prec), axis=-1)
+        return jnp.sum(pred == y)
+    return jax.lax.map(lambda a: one(*a), (private, test_x, test_y))
+
+
+def correct_counts(cfg, state, data):
+    """Per-client count of test examples the private model gets right."""
+    return np.asarray(_evaluate(_hashable(cfg), state["private"],
+                                data["test_x"], data["test_y"]))
+
+
+# ---------------------------------------------------------------- Phase 1
+
+@jax.jit
+def _l1(proxy):
+    w = jax.vmap(_flat)(proxy)
+    return jax.lax.map(lambda row: jnp.sum(jnp.abs(w - row[None]), axis=-1), w)
+
+
+def l1_distances(proxy):
+    """Eq. 3: pairwise ℓ1 distance of the flattened proxy models (M, M), in
+    the models' own precision."""
+    return np.asarray(_l1(proxy).astype(jnp.float32), np.float64)
+
+
+def _known_peers(M, H, seed):
+    rng = np.random.default_rng(seed)
+    H = min(H, M - 1)
+    known = np.zeros((M, M), bool)
+    for i in range(M):
+        cands = [j for j in range(M) if j != i]
+        known[i, rng.choice(cands, H, replace=False)] = True
+    return known | known.T, rng
+
+
+def greedy_groups(dist, group_size, sample_peers, seed):
+    """The paper's greedy Phase-1 procedure (§3.3) on a distance matrix:
+    each client sees H sampled peers (symmetric), mutual nearest pairs form
+    first, every other client pairs with its nearest ungrouped peer, an odd
+    leftover joins a random pair, then groups merge with their nearest
+    partner group (closest member pair) while they fit in ``group_size``."""
+    M = dist.shape[0]
+    known, rng = _known_peers(M, sample_peers, seed)
+    masked = np.where(known, dist, np.inf)
+    np.fill_diagonal(masked, np.inf)
+
+    best = np.argmin(masked, axis=1)
+    ungrouped, out = set(range(M)), []
+    for i in range(M):
+        j = int(best[i])
+        if i < j and best[j] == i and i in ungrouped and j in ungrouped:
+            out.append([i, j])
+            ungrouped -= {i, j}
+    for i in sorted(ungrouped):
+        if i not in ungrouped:
+            continue
+        cands = [j for j in sorted(ungrouped) if j != i]
+        if not cands:
+            break
+        j = cands[int(np.argmin([masked[i, j] for j in cands]))]
+        if not np.isfinite(masked[i, j]):
+            j = int(rng.choice(cands))
+        out.append([i, j])
+        ungrouped -= {i, j}
+    for i in sorted(ungrouped):
+        out[rng.integers(len(out))].append(i)
+
+    def gdist(a, b):
+        vals = [masked[i, j] for i in a for j in b if np.isfinite(masked[i, j])]
+        return min(vals) if vals else np.inf
+
+    while True:
+        merged = False
+        for g in [g for g in out if len(g) < group_size]:
+            if g not in out:
+                continue
+            partners = [h for h in out
+                        if h is not g and len(h) + len(g) <= group_size]
+            if not partners:
+                continue
+            finite = [h for h in partners if np.isfinite(gdist(g, h))]
+            if finite:
+                h = finite[int(np.argmin([gdist(g, h) for h in finite]))]
+            else:
+                h = partners[rng.integers(len(partners))]
+            out.remove(g)
+            out.remove(h)
+            out.append(sorted(g + h))
+            merged = True
+        if not merged:
+            break
+    return [sorted(g) for g in out]
