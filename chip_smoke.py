@@ -38,9 +38,9 @@ the groups are identical and the accuracy histories bit-equal (as the CPU
 equivalence tier asserts for P4 end to end); it prints the final states'
 max difference and each device's memory.
 
-Informative lines: compile seconds (backend compiles or persistent-cache
-loads), steady co-train rounds/s timed around ``block_until_ready``,
-``peak_bytes_in_use`` and the tuned tiles. The compile cache lives where
+Informative lines: compile seconds from the ``jax.compile`` probe (backend
+compiles or persistent-cache loads), steady co-train rounds/s timed around
+``block_until_ready``, ``peak_bytes_in_use`` and the tuned tiles. The compile cache lives where
 ``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 
 The last line of stdout is ``{"ok": true, "device": {...}}`` only when every
@@ -85,7 +85,7 @@ from repro.kernels.dp_clip.ref import add_flat_noise  # noqa: E402
 from repro.kernels.dp_round.ref import dp_round_closed  # noqa: E402
 from repro.kernels.l1_distance import ref as l1_ref  # noqa: E402
 from repro.launch.mesh import make_client_mesh  # noqa: E402
-from repro.obs import probe_deltas  # noqa: E402
+from repro.obs import COMPILE_STATS as COMPILES, probe_deltas  # noqa: E402
 from repro.utils.pytree import tree_flatten_concat  # noqa: E402
 
 L1_RTOL = 1e-4      # Phase-1 distance, max |pallas - ref| / ref off-diagonal
@@ -102,31 +102,6 @@ def _log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileClock:
-    """Seconds XLA spent producing executables (a backend compile, or a
-    persistent-cache load when the entry is there), and cache hits/misses."""
-
-    def __init__(self):
-        self.seconds, self.count, self.hits, self.misses = 0.0, 0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.count += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return {"seconds": self.seconds, "compiles": self.count,
-                "cache_hits": self.hits, "cache_misses": self.misses}
-
-
 @dataclass
 class Setup:
     """One smoke run: configuration, the clients' data and the checks."""
@@ -141,7 +116,6 @@ class Setup:
     eval_every: int
     seed: int
     data: FederatedData
-    clock: CompileClock
     checks: list = field(default_factory=list)
 
     def check(self, name: str, passed, detail: str) -> None:
@@ -229,7 +203,6 @@ def main() -> None:
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices)}
     _log(f"device: {json.dumps(device)}")
-    clock = CompileClock()
 
     if args.rehearse:
         M, R, rounds, boot, eval_every = 16, 40, 6, 2, 3
@@ -258,14 +231,16 @@ def main() -> None:
                   dp=replace(run.dp, rounds=rounds, per_example_chunk=chunk))
     s = Setup(cfg=cfg, backend=backend, F=F, C=C, M=M, n_tr=n_tr,
               rounds=rounds, boot=boot, eval_every=eval_every, seed=args.seed,
-              data=data, clock=clock)
+              data=data)
 
     (_four_chip if args.chips == 4 else _one_chip)(s)
 
     stats = dev.memory_stats() or {}
     _log(f"memory: peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
          f"bytes_limit={stats.get('bytes_limit')}")
-    _log(f"compile: {json.dumps(clock.snapshot())}")
+    _log("compile: " + json.dumps(
+        {k: COMPILES[k] for k in ("compile_s", "compiles", "cache_hits",
+                                  "cache_misses")}))
     if not all(s.checks):
         _fail(f"{s.checks.count(False)} check(s) failed")
     if args.rehearse:
@@ -280,22 +255,22 @@ def _one_chip(s: Setup) -> None:
     # ---------------- P4: bootstrap -> grouping -> DP co-training ----------
     trainer = P4Trainer(feat_dim=s.F, num_classes=s.C, cfg=s.cfg)
     boot = {}
-    c0, t0 = s.clock.snapshot(), time.perf_counter()
+    c0, t0 = COMPILES.snapshot(), time.perf_counter()
     with probe_deltas("kernels.backend", "kernels.autotune") as probes:
         states, groups, hist = s.fit(trainer, capture=boot)
         jax.block_until_ready(states)
         p4_s = time.perf_counter() - t0
-        p4_compile = s.clock.snapshot()["seconds"] - c0["seconds"]
+        p4_compile = COMPILES["compile_s"] - c0["compile_s"]
 
         # ---------------- DP local-only baseline (fused dp_round) ----------
-        c1, t1 = s.clock.snapshot(), time.perf_counter()
+        c1, t1 = COMPILES.snapshot(), time.perf_counter()
         _, lh = local.train(d.train_x, d.train_y, d.test_x, d.test_y,
                             rounds=s.rounds, lr=0.5, batch_size=s.n_tr,
                             eval_every=s.eval_every, seed=s.seed,
                             dp_cfg=DPConfig(clip_norm=1.0),
                             sigma=trainer.sigma, kernels=kcfg)
         local_s = time.perf_counter() - t1
-        local_compile = s.clock.snapshot()["seconds"] - c1["seconds"]
+        local_compile = COMPILES["compile_s"] - c1["compile_s"]
     used = {k: v for k, v in probes["kernels.backend"].items() if v}
     _log(f"p4: sigma={trainer.sigma:.6f} groups={len(groups)} "
          f"history={list(zip(hist.rounds, hist.accuracy))} "
@@ -329,14 +304,14 @@ def _one_chip(s: Setup) -> None:
         return time.perf_counter() - t
 
     cotrain()                                  # warm: compiles this chunk
-    c2 = s.clock.snapshot()
+    c2 = COMPILES.snapshot()
     with probe_deltas("engine.chunk_cache") as dc:
         times = [cotrain() for _ in range(3)]
     win = s.rounds - s.boot
     _log(f"steady: co-train {win} rounds x3 in {times} s -> "
          f"{win / min(times):.4f} rounds/s (best), "
          f"{win / sorted(times)[1]:.4f} rounds/s (median); compiles in "
-         f"window={s.clock.snapshot()['compiles'] - c2['compiles']} "
+         f"window={COMPILES['compiles'] - c2['compiles']} "
          f"chunk_cache={json.dumps(dc['engine.chunk_cache'])}")
 
     # ---------------- Pallas vs reference checks ---------------------------
@@ -404,7 +379,7 @@ def _four_chip(s: Setup) -> None:
     runs = {}
     for name, mesh in (("one_chip", None), ("mesh4", make_client_mesh(4))):
         trainer = P4Trainer(feat_dim=s.F, num_classes=s.C, cfg=s.cfg)
-        c0, t0 = s.clock.snapshot(), time.perf_counter()
+        c0, t0 = COMPILES.snapshot(), time.perf_counter()
         st, groups, hist = s.fit(trainer, mesh=mesh)
         jax.block_until_ready(st)
         wall = time.perf_counter() - t0
@@ -413,7 +388,7 @@ def _four_chip(s: Setup) -> None:
                for dv in jax.devices()]
         _log(f"{name}: history={list(zip(hist.rounds, hist.accuracy))} "
              f"wall={wall:.2f}s "
-             f"compile={s.clock.snapshot()['seconds'] - c0['seconds']:.2f}s "
+             f"compile={COMPILES['compile_s'] - c0['compile_s']:.2f}s "
              f"peak_bytes_in_use per device={mem}")
     (s1, g1, h1), (s2, g2, h2) = runs["one_chip"], runs["mesh4"]
     s.check("groups_identical", g1 == g2, f"groups={len(g1)}")

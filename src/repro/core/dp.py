@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import KernelConfig
+from repro.obs.layers import layer
 from repro.utils.pytree import (global_norm, param_count, tree_flatten_concat,
                                 tree_unflatten_concat)
 
@@ -206,8 +207,9 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
                 lambda t: t.reshape((n // c, c) + t.shape[1:]), batch)
 
             def body(acc, bchunk):
-                per_ex = jax.vmap(one, in_axes=(None, 0))(params, bchunk)
-                flat = jax.vmap(tree_flatten_concat)(per_ex)     # (c, D)
+                with layer("per_example_grads"):
+                    per_ex = jax.vmap(one, in_axes=(None, 0))(params, bchunk)
+                    flat = jax.vmap(tree_flatten_concat)(per_ex)  # (c, D)
                 # denom folded into the per-example scales: chunk sums are
                 # already /n, so their total is the mean — no extra (D,) pass
                 return acc + dispatch.clip_accumulate(flat, clip,
@@ -216,11 +218,15 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
 
             D = param_count(params)
             mean, _ = jax.lax.scan(body, jnp.zeros((D,), jnp.float32), chunks)
-            out = add_flat_noise(mean, key, sigma, clip, float(n))
+            with layer("dp_noise"):
+                out = add_flat_noise(mean, key, sigma, clip, float(n))
             return tree_unflatten_concat(out, params)
-        per_ex = jax.vmap(one, in_axes=(None, 0))(params, batch)
-        return dispatch.dp_clip(per_ex, clip, key, sigma=sigma,
-                                denom=float(n), kernels=kernels)
+        with layer("per_example_grads"):
+            per_ex = jax.vmap(one, in_axes=(None, 0))(params, batch)
+            flat = jax.vmap(tree_flatten_concat)(per_ex)          # (B, D)
+        out = dispatch.dp_clip_flat(flat, clip, key, sigma=sigma,
+                                    denom=float(n), kernels=kernels)
+        return tree_unflatten_concat(out, params)
 
     k = microbatches
     assert n % k == 0, (n, k)

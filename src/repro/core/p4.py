@@ -30,6 +30,7 @@ from repro.engine import (Engine, FederatedData, PrivacyLedger, ShardedEngine,
                           Strategy, make_schedule, register_strategy,
                           runtime_sigma)
 from repro.models.module import init_params
+from repro.obs import layer, span
 
 
 def group_mean(stacked_tree, ids: jnp.ndarray, num_groups: int):
@@ -111,7 +112,8 @@ class P4Trainer:
             lg = self.apply_fn(theta, x)
             return distill.private_loss(lg, proxy_logits, y, p4c.beta,
                                         p4c.distill_temperature)
-        g_priv = jax.grad(private_obj)(private)
+        with layer("private_grad"):
+            g_priv = jax.grad(private_obj)(private)
 
         # proxy model: DP gradient of Eq. 8
         def proxy_obj(w, batch):
@@ -119,15 +121,17 @@ class P4Trainer:
             tgt = self.apply_fn(jax.lax.stop_gradient(private), batch["x"])
             return distill.proxy_loss(lg, tgt, batch["y"], p4c.alpha,
                                       p4c.distill_temperature)
-        if dpc.enabled:
-            g_prox = dp_lib.dp_gradients(
-                proxy_obj, proxy, {"x": x, "y": y}, key,
-                clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
-                microbatches=dpc.microbatches,
-                per_example_chunk=dpc.per_example_chunk,
-                kernels=self.cfg.kernels)
-        else:
-            g_prox = jax.grad(lambda w: proxy_obj(w, {"x": x, "y": y}))(proxy)
+        with layer("proxy_dp_grad"):
+            if dpc.enabled:
+                g_prox = dp_lib.dp_gradients(
+                    proxy_obj, proxy, {"x": x, "y": y}, key,
+                    clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
+                    microbatches=dpc.microbatches,
+                    per_example_chunk=dpc.per_example_chunk,
+                    kernels=self.cfg.kernels)
+            else:
+                g_prox = jax.grad(
+                    lambda w: proxy_obj(w, {"x": x, "y": y}))(proxy)
 
         new_private = jax.tree_util.tree_map(lambda p, g: p - lr * g, private, g_priv)
         new_proxy = jax.tree_util.tree_map(lambda p, g: p - lr * g, proxy, g_prox)
@@ -154,8 +158,11 @@ class P4Trainer:
                                               jax.random.fold_in(ckey, k), lr)
                 return (pr, px), None
             (pr, px), _ = jax.lax.scan(body, (private, proxy), jnp.arange(K))
-            _, _, metrics = self._client_step(pr, px, x, y,
-                                              jax.random.fold_in(ckey, K), 0.0)
+            # the round's metrics: one more step at lr = 0 (XLA drops its
+            # unused gradients; the forwards remain)
+            with layer("metrics_step"):
+                _, _, metrics = self._client_step(
+                    pr, px, x, y, jax.random.fold_in(ckey, K), 0.0)
             return pr, px, metrics
 
         priv, prox, metrics = jax.vmap(one_client)(
@@ -192,24 +199,27 @@ class P4Trainer:
         M = jax.tree_util.tree_leaves(states["proxy"])[0].shape[0]
         if p4c.similarity == "random":
             return random_groups(M, p4c.group_size, seed)
-        weights = flatten_clients(states["proxy"])
-        dist = np.asarray(pairwise_l1(weights, kernels=self.cfg.kernels))
-        nbhd = None
-        if topology is not None:
-            nbhd = (topology.union_adjacency()
-                    if hasattr(topology, "union_adjacency")
-                    else topology.adjacency)
-        return greedy_group_formation(dist, p4c.group_size,
-                                      p4c.sample_peers, seed,
-                                      neighborhoods=nbhd)
+        with span("p4.form_groups"):
+            with span("p4.l1_distance"):
+                weights = flatten_clients(states["proxy"])
+                dist = np.asarray(pairwise_l1(weights,
+                                              kernels=self.cfg.kernels))
+            nbhd = None
+            if topology is not None:
+                nbhd = (topology.union_adjacency()
+                        if hasattr(topology, "union_adjacency")
+                        else topology.adjacency)
+            with span("p4.greedy"):
+                return greedy_group_formation(dist, p4c.group_size,
+                                              p4c.sample_peers, seed,
+                                              neighborhoods=nbhd)
 
     # ------------------------------------------------------------------
-    @functools.partial(jax.jit, static_argnums=0)
     def evaluate(self, states, xs, ys):
-        """Per-client test accuracy of the PERSONALIZED (private) model."""
-        def one(private, x, y):
-            return accuracy(self.apply_fn(private, x), y)
-        return jax.vmap(one)(states["private"], xs, ys)
+        """Per-client test accuracy of the PERSONALIZED (private) model:
+        the ``p4.evaluate`` span around its dispatch."""
+        with span("p4.evaluate"):
+            return _evaluate(self, states, xs, ys)
 
     # ------------------------------------------------------------------
     def fit(self, train_x, train_y, test_x, test_y, *, rounds: Optional[int] = None,
@@ -319,6 +329,13 @@ class P4Trainer:
                                      batch_size=bs, start_round=nb,
                                      state=states, resume=resume)
         return states, groups, history
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _evaluate(trainer: P4Trainer, states, xs, ys):
+    def one(private, x, y):
+        return accuracy(trainer.apply_fn(private, x), y)
+    return jax.vmap(one)(states["private"], xs, ys)
 
 
 # ---------------------------------------------------------------------------

@@ -36,9 +36,11 @@ import numpy as np
 
 from repro.engine.accounting import PrivacyLedger
 from repro.engine.schedule import (FullParticipation, RoundSchedule,
-                                   sample_client_batches)
+                                   Scoped, sample_client_batches)
 from repro.engine.strategy import (FederatedData, Strategy, runtime_params)
+from repro.obs.layers import note_dispatch
 from repro.obs.probes import Probe
+from repro.obs.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -227,7 +229,7 @@ class Engine:
         fn = _cache_get(key_)
         if fn is not None:
             return fn
-        body = self.schedule.round_body(self.strategy, batch_size)
+        body = self.schedule.round_body(Scoped(self.strategy), batch_size)
         if self.faults is not None:
             from repro.resilience import wrap_round_body
             body = wrap_round_body(body, self.faults)
@@ -256,9 +258,16 @@ class Engine:
         """Run rounds [start, stop) as one scanned chunk. Returns
         (state, metrics, aux) with metrics/aux stacked over the chunk's
         rounds; aux carries the (chunk, M) participation masks under a
-        sampling schedule (empty dict otherwise)."""
+        sampling schedule (empty dict otherwise). The chunk is one
+        ``engine.run_rounds`` span."""
         if stop <= start:
             return state, {}, {}
+        with self._chunk_span(start, stop):
+            return self._run_chunk(state, data, phase_key, start, stop,
+                                   batch_size)
+
+    def _run_chunk(self, state, data: FederatedData, phase_key, start: int,
+                   stop: int, batch_size: Optional[int]):
         fn = self._build_chunk(self._chunk_fn, stop - start, batch_size, data)
         train_x, train_y = self._train_arrays(data)
         rt = {k: jnp.asarray(v, jnp.float32)
@@ -274,28 +283,39 @@ class Engine:
             state, self._fault_state = carry
         return state, metrics, aux
 
-    # ----------------------------------------------------- telemetry dispatch
-    def _build_chunk(self, builder, *args):
-        """Chunk lookup/build, spanned when telemetry is on (cache hits show
-        up as ~0-cost build spans; the trace itself lands in the execute
-        span of the first dispatch)."""
+    # ----------------------------------------------------- spans and dispatch
+    def _chunk_span(self, start: int, stop: int):
+        """The chunk's ``engine.run_rounds`` span: with telemetry on, the
+        span event carries the trace-vs-execute split, the chunk's compiles
+        and the optional Nth-chunk profiler capture; off, it is one
+        inactive TraceMe unless a profiler is running."""
         tel = self._telemetry_on()
         if tel is None:
-            return builder(*args)
-        with tel.span("chunk/build"):
+            return span("engine.run_rounds", chunk=True)
+        return tel.chunk_span(start=int(start), stop=int(stop))
+
+    def _build_chunk(self, builder, *args):
+        """Chunk-cache key and lookup (or build): the
+        ``engine.chunk_lookup`` span. A build traces nothing yet; the trace
+        lands in the first dispatch."""
+        with span("engine.chunk_lookup"):
             return builder(*args)
 
     def _dispatch_chunk(self, fn, args, start: int, stop: int, rt=None):
-        """Execute one compiled chunk. Telemetry off: a bare call, nothing
-        added. Telemetry on: the chunk span (trace-vs-execute split via the
-        chunk-cache probe, optional Nth-chunk profiler capture) wraps the
-        call, the tap's io_callbacks route to this run's sink, and engines
-        whose trace is tap-free (sharded) stream the per-round events from
-        the stacked outputs instead."""
+        """Execute one compiled chunk: the ``engine.dispatch`` span. Its
+        first dispatch records the chunk's traced signature for the op ->
+        layer table (``repro.obs.layers``); later ones cost a dict hit.
+        Telemetry on: the call blocks inside the span, the tap's
+        io_callbacks route to this run's sink, and engines whose trace is
+        tap-free (sharded) stream the per-round events from the stacked
+        outputs instead."""
         tel = self._telemetry_on()
         if tel is None:
-            return fn(*args)
-        with tel.activate(), tel.chunk_span(start=int(start), stop=int(stop)):
+            with span("engine.dispatch"):
+                note_dispatch(fn, args)
+                return fn(*args)
+        with tel.activate(), span("engine.dispatch", tel):
+            note_dispatch(fn, args)
             out = fn(*args)
             jax.block_until_ready(out)
             if tel.tap and self._tap_in_jit:

@@ -49,8 +49,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.loop import CHUNK_STATS, Engine, _cache_get, _cache_put
-from repro.engine.schedule import ClientSampling
+from repro.engine.schedule import ClientSampling, Scoped
 from repro.engine.strategy import FederatedData, runtime_params
+from repro.obs.layers import layer
 from repro.obs.probes import Probe
 
 # process-wide twin of the per-instance ``CohortPrefetcher.stats`` dicts:
@@ -218,13 +219,14 @@ class PagedCtx:
         ids, then gathered from the compact data stacks."""
         if batch_size is None:
             return train_x, train_y
-        R = train_y.shape[1]
-        idx = jax.random.randint(key, (self.M, batch_size), 0,
-                                 R)[self.ids_clip]
-        xs = jnp.take_along_axis(
-            train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
-            axis=1)
-        ys = jnp.take_along_axis(train_y, idx, axis=1)
+        with layer("sample_batches"):
+            R = train_y.shape[1]
+            idx = jax.random.randint(key, (self.M, batch_size), 0,
+                                     R)[self.ids_clip]
+            xs = jnp.take_along_axis(
+                train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
+                axis=1)
+            ys = jnp.take_along_axis(train_y, idx, axis=1)
         return xs, ys
 
     # --------------------------------------------------------------- metrics
@@ -454,7 +456,8 @@ class PagedEngine(Engine):
         if fn is not None:
             return fn
         pctx = PagedCtx(self._M, cohort)
-        body = self.schedule.paged_round_body(self.strategy, batch_size, pctx)
+        body = self.schedule.paged_round_body(Scoped(self.strategy),
+                                              batch_size, pctx)
         if self.faults is not None:
             from repro.resilience import wrap_round_body
             body = wrap_round_body(body, self.faults)
@@ -479,10 +482,8 @@ class PagedEngine(Engine):
         return fn
 
     # -------------------------------------------------------------- the loop
-    def run_rounds(self, state, data, phase_key, start: int, stop: int,
+    def _run_chunk(self, state, data, phase_key, start: int, stop: int,
                    batch_size: Optional[int]):
-        if stop <= start:
-            return state, {}, {}
         M = self._M
         paged = self._paged_sampling()
         if paged:

@@ -40,6 +40,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.layers import layer
 from repro.resilience import current_faults
 
 
@@ -64,12 +65,47 @@ def sample_client_batches(train_x, train_y, key, batch_size: Optional[int]):
     """
     if batch_size is None:
         return train_x, train_y
-    M, R = train_y.shape
-    idx = jax.random.randint(key, (M, batch_size), 0, R)
-    xs = jnp.take_along_axis(
-        train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)), axis=1)
-    ys = jnp.take_along_axis(train_y, idx, axis=1)
+    with layer("sample_batches"):
+        M, R = train_y.shape
+        idx = jax.random.randint(key, (M, batch_size), 0, R)
+        xs = jnp.take_along_axis(
+            train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
+            axis=1)
+        ys = jnp.take_along_axis(train_y, idx, axis=1)
     return xs, ys
+
+
+class Scoped:
+    """The strategy as every round body sees it: the engines hand
+    ``Scoped(strategy)`` to the schedule, so each body's local update runs
+    under the ``local_update`` layer and its aggregation, with the
+    ``merge_participation`` calls around it, under ``aggregate``
+    (``repro.obs.layers``). Everything else is the strategy's own."""
+
+    def __init__(self, strategy):
+        self._strategy = strategy
+
+    def __getattr__(self, name):
+        return getattr(self._strategy, name)
+
+    def _scoped(name, layer_name):
+        def call(self, *args, **kwargs):
+            with layer(layer_name):
+                return getattr(self._strategy, name)(*args, **kwargs)
+        call.__name__ = name
+        return call
+
+    local_update = _scoped("local_update", "local_update")
+    sharded_local_update = _scoped("sharded_local_update", "local_update")
+    paged_local_update = _scoped("paged_local_update", "local_update")
+    aggregate = _scoped("aggregate", "aggregate")
+    aggregate_masked = _scoped("aggregate_masked", "aggregate")
+    sharded_aggregate = _scoped("sharded_aggregate", "aggregate")
+    sharded_aggregate_masked = _scoped("sharded_aggregate_masked",
+                                       "aggregate")
+    paged_aggregate_masked = _scoped("paged_aggregate_masked", "aggregate")
+    merge_participation = _scoped("merge_participation", "aggregate")
+    del _scoped
 
 
 @dataclass(eq=False)  # identity hash: schedules are closed over by jitted chunks

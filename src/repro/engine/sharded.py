@@ -41,7 +41,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.engine.loop import CHUNK_STATS, Engine, _cache_get, _cache_put
+from repro.engine.schedule import Scoped
 from repro.engine.strategy import FederatedData, runtime_params
+from repro.obs.layers import layer
 from repro.sharding.rules import CLIENT_AXIS, client_specs
 
 
@@ -133,16 +135,17 @@ class ClientShardCtx:
         onto this shard's data. ``batch_size=None`` = full local batch."""
         if batch_size is None:
             return train_x, train_y
-        if self._pf_idx is not None:
-            idx, self._pf_idx = self._pf_idx, None
-        else:
-            R = train_y.shape[1]
-            idx = jax.random.randint(key, (self.M, batch_size), 0, R)
-            idx = self.shard_rows(idx)
-        xs = jnp.take_along_axis(
-            train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
-            axis=1)
-        ys = jnp.take_along_axis(train_y, idx, axis=1)
+        with layer("sample_batches"):
+            if self._pf_idx is not None:
+                idx, self._pf_idx = self._pf_idx, None
+            else:
+                R = train_y.shape[1]
+                idx = jax.random.randint(key, (self.M, batch_size), 0, R)
+                idx = self.shard_rows(idx)
+            xs = jnp.take_along_axis(
+                train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
+                axis=1)
+            ys = jnp.take_along_axis(train_y, idx, axis=1)
         return xs, ys
 
     # ----------------------------------------------------------- collectives
@@ -233,7 +236,8 @@ class ShardedEngine(Engine):
             return fn
         ctx = ClientShardCtx(self.mesh, self.client_axis, data.num_clients)
         from repro.engine.schedule import wrap_overlap
-        body = self.schedule.sharded_round_body(self.strategy, batch_size, ctx)
+        body = self.schedule.sharded_round_body(Scoped(self.strategy),
+                                                batch_size, ctx)
         body = wrap_overlap(body, self.strategy, ctx)
         faulted = self.faults is not None
         if faulted:

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import KernelConfig
+from repro.obs.layers import layer
 from repro.obs.probes import Probe
 from repro.kernels.dp_clip import kernel as dp_kernel, ops as dp_ops, ref as dp_ref
 from repro.kernels.dp_round import (kernel as dpr_kernel, ops as dpr_ops,
@@ -327,12 +328,13 @@ def clip_accumulate(flat, clip: float, *, denom: float = 1.0,
     scale-accumulate pass with the mean folded into the scales)."""
     cfg = _cfg(kernels)
     backend = _resolve_for("dp_clip", cfg)
-    if backend == "ref":
-        return dp_ref.clip_accumulate(flat, clip, denom=denom)
-    tb, td = dp_clip_tiles(tuple(flat.shape), flat.dtype, cfg, backend)
-    return dp_ops.clip_accumulate_flat(flat, clip, denom=denom,
-                                       interpret=(backend == "interpret"),
-                                       tb=tb, td=td)
+    with layer("dp_clip"):
+        if backend == "ref":
+            return dp_ref.clip_accumulate(flat, clip, denom=denom)
+        tb, td = dp_clip_tiles(tuple(flat.shape), flat.dtype, cfg, backend)
+        return dp_ops.clip_accumulate_flat(flat, clip, denom=denom,
+                                           interpret=(backend == "interpret"),
+                                           tb=tb, td=td)
 
 
 def dp_clip_flat(flat, clip: float, key=None, *, sigma: float = 0.0,
@@ -345,7 +347,8 @@ def dp_clip_flat(flat, clip: float, key=None, *, sigma: float = 0.0,
     if not dp_ref.static_zero_sigma(sigma) and key is None:
         raise ValueError("sigma > 0 requires a PRNG key (privacy guard)")
     out = clip_accumulate(flat, clip, denom=denom, kernels=kernels)
-    return dp_ref.add_flat_noise(out, key, sigma, clip, denom)
+    with layer("dp_noise"):
+        return dp_ref.add_flat_noise(out, key, sigma, clip, denom)
 
 
 def dp_clip(per_example_grads, clip: float, key=None, *, sigma: float = 0.0,
@@ -356,13 +359,14 @@ def dp_clip(per_example_grads, clip: float, key=None, *, sigma: float = 0.0,
 
     The (B, D) matrix is materialized once by the flatten and then read at
     most twice; noise is one flat (D,) draw, killing the per-leaf loop."""
-    flat = jax.vmap(tree_flatten_concat)(per_example_grads)      # (B, D)
-    if denom is None:
-        denom = float(flat.shape[0])
-    out = dp_clip_flat(flat, clip, key, sigma=sigma, denom=denom,
-                       kernels=kernels)
-    template = jax.tree_util.tree_map(lambda g: g[0], per_example_grads)
-    return tree_unflatten_concat(out, template)
+    with layer("dp_clip"):
+        flat = jax.vmap(tree_flatten_concat)(per_example_grads)  # (B, D)
+        if denom is None:
+            denom = float(flat.shape[0])
+        out = dp_clip_flat(flat, clip, key, sigma=sigma, denom=denom,
+                           kernels=kernels)
+        template = jax.tree_util.tree_map(lambda g: g[0], per_example_grads)
+        return tree_unflatten_concat(out, template)
 
 
 def dp_round(loss_fn, params, x, y, key=None, *, clip: float,

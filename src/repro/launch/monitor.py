@@ -20,6 +20,10 @@ import sys
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+#: the engine's per-chunk span (``repro.obs.spans``): its events carry the
+#: trace-vs-execute split, cache counts and the chunk's compiles
+CHUNK_SPAN = "engine.run_rounds"
+
 
 def load_manifest(run_dir: str) -> Optional[Dict[str, Any]]:
     path = os.path.join(run_dir, "manifest.json")
@@ -49,15 +53,19 @@ def _fmt_event(ev: Dict[str, Any]) -> str:
     t = ev.get("type", "?")
     if t == "span":
         extra = ""
-        if ev.get("name") == "chunk":
+        if ev.get("name") == CHUNK_SPAN:
             extra = (f" chunk={ev.get('chunk')} rounds=[{ev.get('start')},"
                      f"{ev.get('stop')}) "
                      f"{'traced' if ev.get('traced') else 'cached'}")
+            if ev.get("compiles"):
+                extra += (f" compiles={ev['compiles']} "
+                          f"({ev.get('compile_s', 0.0):.3f}s, "
+                          f"{ev.get('compiled', '')})")
             if ev.get("mix_path"):
                 extra += f" mix={ev['mix_path']}"
             if "profile_dir" in ev:
                 extra += " [profiled]"
-        return f"span {ev.get('name'):<12} {ev.get('dt', 0):8.4f}s{extra}"
+        return f"span {ev.get('name'):<20} {ev.get('dt', 0):8.4f}s{extra}"
     if t == "tap":
         vals = {k: v for k, v in ev.items()
                 if k not in ("type", "t", "round", "source")}
@@ -106,7 +114,7 @@ def summarize(run_dir: str) -> str:
     for name in sorted(spans):
         evs = spans[name]
         total = sum(e.get("dt", 0.0) for e in evs)
-        if name == "chunk":
+        if name == CHUNK_SPAN:
             traced = [e for e in evs if e.get("traced")]
             cached = [e for e in evs if not e.get("traced")]
 
@@ -115,9 +123,14 @@ def summarize(run_dir: str) -> str:
                         f"{(sum(e.get('dt', 0.0) for e in sub) / len(sub)):.4f}s"
                         if sub else "0x")
 
-            lines.append(f"span chunk: {len(evs)}x total {total:.3f}s — "
+            lines.append(f"span {name}: {len(evs)}x total {total:.3f}s — "
                          f"traced(+compile) {agg(traced)}, "
                          f"execute-only {agg(cached)}")
+            recompiled = [e for e in evs if e.get("compiles")]
+            if recompiled:
+                lines.append("  compiled in: " + ", ".join(
+                    f"chunk {e.get('chunk')} ({e['compiles']}x, "
+                    f"{e.get('compile_s', 0.0):.3f}s)" for e in recompiled))
             paths = sorted({e.get("mix_path") for e in evs
                             if e.get("mix_path")})
             if paths:

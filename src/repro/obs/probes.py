@@ -54,8 +54,10 @@ class Probe(dict):
 
     def delta_from(self, before: Dict[str, float]) -> Dict[str, float]:
         """Counter movement since ``before`` (a prior ``snapshot()``). Keys
-        born after the snapshot count from their typed zero."""
-        return {k: v - before.get(k, self._zeros.get(k, 0))
+        born after the snapshot count from their typed zero. A value that is
+        not a number (a last-seen name) reads as it is now."""
+        return {k: (v - before.get(k, self._zeros.get(k, 0))
+                    if isinstance(v, (int, float)) else v)
                 for k, v in self.items()}
 
     def __repr__(self) -> str:  # pragma: no cover - debugging nicety

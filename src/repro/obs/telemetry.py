@@ -10,12 +10,12 @@ A ``Telemetry`` object owns one run directory:
     per-phase records, the cumulative (ε, δ)/accuracy trajectory copied
     from ``History`` at every eval boundary, and a closing probe snapshot.
 
-The engine integrates through three narrow seams (``Engine._build_chunk`` /
+The engine integrates through narrow seams (``Engine._chunk_span`` /
 ``_dispatch_chunk`` / the eval loop in ``fit``), all of which check
 ``telemetry is None or not telemetry.enabled`` FIRST — a run without
-telemetry takes the exact pre-telemetry code path, builds byte-identical
-chunk-cache keys, and traces byte-identical chunks (locked by the
-telemetry-off equivalence scenario).
+telemetry builds byte-identical chunk-cache keys and traces byte-identical
+chunks (locked by the telemetry-off equivalence scenario); its program
+spans (``repro.obs.spans``) reach the profiler only.
 
 The tap (``tap=True``) restructures the chunk's scan into blocks of
 ``TAP_BLOCK`` rounds (identical per-round ops and outputs) with one
@@ -285,32 +285,30 @@ class Telemetry:
         finally:
             _ACTIVE = prev
 
-    @contextlib.contextmanager
     def span(self, name: str, **fields):
-        """Wall-clock span: emits {"type": "span", "name": ..., "dt": s}."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._emit(dict({"type": "span", "name": name,
-                             "dt": time.perf_counter() - t0}, **fields))
+        """A program span (``repro.obs.spans.span``) that writes
+        {"type": "span", "name", "id", "parent", "chunk", "dt", ...} here
+        and shows on the profiler's timeline under the same id."""
+        from repro.obs.spans import span
+        return span(name, self, **fields)
 
     @contextlib.contextmanager
     def chunk_span(self, **fields):
-        """Span around one dispatched chunk, with the trace-vs-execute split
-        read off the chunk-cache probe (a cache hit executes without
-        tracing) and the chunk's mixing path read off the mix probe. The
-        profiler capture of the Nth chunk rides this span."""
+        """The ``engine.run_rounds`` span of one chunk, with the
+        trace-vs-execute split and cache hits/misses read off the
+        chunk-cache probe (a cache hit executes without tracing), the
+        chunk's compile count and seconds off the ``jax.compile`` probe, and
+        its mixing path off the mix probe. The profiler capture of the Nth
+        chunk rides this span."""
+        from repro.obs.spans import span
         if not self.enabled:
-            yield
+            with span("engine.run_rounds", chunk=True):
+                yield
             return
         idx = self._chunk_idx
         self._chunk_idx += 1
-        sel = [n for n in ("engine.chunk_cache", "topology.mix")
-               if n in REGISTRY.names()]
+        sel = [n for n in ("engine.chunk_cache", "topology.mix",
+                           "jax.compile") if n in REGISTRY.names()]
         profiled = (self.profile_chunk is not None
                     and idx == self.profile_chunk)
         prof_dir = os.path.join(self.run_dir, "profile")
@@ -321,19 +319,28 @@ class Telemetry:
                 prof_cm = jax.profiler.trace(prof_dir)
             except Exception:  # profiler unavailable on this backend
                 profiled = False
-        t0 = time.perf_counter()
-        with REGISTRY.deltas(*sel) as d:
-            with prof_cm:
+        with prof_cm:
+            sp = span("engine.run_rounds", self, chunk=True, **fields)
+            with sp, REGISTRY.deltas(*sel) as d:
                 try:
                     yield
                 finally:
-                    dt = time.perf_counter() - t0
-        ev = dict({"type": "span", "name": "chunk", "chunk": idx, "dt": dt},
-                  **fields)
+                    d.finalize()
+                    sp.fields.update(self._chunk_fields(d, profiled,
+                                                        prof_dir))
+
+    @staticmethod
+    def _chunk_fields(d, profiled: bool, prof_dir: str) -> Dict[str, Any]:
         cache = d.get("engine.chunk_cache") or {}
-        ev["traced"] = bool(cache.get("traces", 0) > 0)
-        ev["cache"] = {k: int(cache.get(k, 0))
-                       for k in ("traces", "hits", "misses")}
+        ev: Dict[str, Any] = {
+            "traced": bool(cache.get("traces", 0) > 0),
+            "cache": {k: int(cache.get(k, 0))
+                      for k in ("traces", "hits", "misses")}}
+        comp = d.get("jax.compile") or {}
+        ev["compiles"] = int(comp.get("compiles", 0))
+        ev["compile_s"] = float(comp.get("compile_s", 0.0))
+        if ev["compiles"]:
+            ev["compiled"] = comp.get("last_compile", "")
         mix = d.get("topology.mix") or {}
         if mix.get("calls", 0) > 0:
             paths = {k[len("path_"):]: v for k, v in mix.items()
@@ -343,7 +350,7 @@ class Telemetry:
                                  "ppermutes": int(mix.get("ppermutes", 0))}
         if profiled:
             ev["profile_dir"] = prof_dir
-        self._emit(ev)
+        return ev
 
     # ------------------------------------------------------------ run record
     def begin_phase(self, info: Dict[str, Any]) -> None:

@@ -158,7 +158,7 @@ def test_tap_event_log_matches_returned_history(toy, tmp_path):
 
     # chunk spans carry the trace-vs-execute split read off the probe
     chunks = [e for e in events
-              if e["type"] == "span" and e["name"] == "chunk"]
+              if e["type"] == "span" and e["name"] == "engine.run_rounds"]
     assert chunks and chunks[0]["traced"] is True
 
 
@@ -219,7 +219,7 @@ def test_monitor_summarize_and_tail(toy, tmp_path):
 
     text = monitor.summarize(run_dir)
     assert "phase 0: Engine/FedAvgStrategy" in text
-    assert "span chunk:" in text
+    assert "span engine.run_rounds:" in text
     assert "tap: 8 rounds streamed [0..7]" in text
     assert "trajectory:" in text
 
