@@ -39,7 +39,8 @@ equivalence tier asserts for P4 end to end); it prints the final states'
 max difference and each device's memory.
 
 Informative lines: compile seconds from the ``jax.compile`` probe (backend
-compiles or persistent-cache loads), steady co-train rounds/s timed around
+compiles or persistent-cache loads), the DP routes traced (the ``dp.path``
+probe), steady co-train rounds/s timed around
 ``block_until_ready``, ``peak_bytes_in_use`` and the tuned tiles. The compile cache lives where
 ``JAX_COMPILATION_CACHE_DIR`` says, else in ``<repo>/.jax_cache``.
 
@@ -241,6 +242,7 @@ def main() -> None:
     _log("compile: " + json.dumps(
         {k: COMPILES[k] for k in ("compile_s", "compiles", "cache_hits",
                                   "cache_misses")}))
+    _log("dp path (traces per route): " + json.dumps(dp_lib.DP_PATH))
     if not all(s.checks):
         _fail(f"{s.checks.count(False)} check(s) failed")
     if args.rehearse:

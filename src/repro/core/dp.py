@@ -13,6 +13,10 @@
   (compiled Pallas on TPU, jnp reference on CPU, tile autotuning) as a fused
   pipeline that reads the (B, D) per-example matrix at most twice and draws
   the Eq. 11 noise once on the flat (D,) buffer.
+* ``dp_affine_gradients`` — the same clipped + noised gradient for a model
+  whose logits are an affine map of its input (z = x·w + b), in closed form
+  from the per-example logit gradients: no per-example parameter gradient
+  is built. The ``dp.path`` probe counts which route each trace took.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ import numpy as np
 
 from repro.config import KernelConfig
 from repro.obs.layers import layer
+from repro.obs.probes import Probe
 from repro.utils.pytree import (global_norm, param_count, tree_flatten_concat,
                                 tree_unflatten_concat)
 
@@ -159,6 +164,11 @@ def calibrate_sigma(target_eps: float, delta: float, q: float, steps: int,
 # DP gradients — per-example (paper-faithful) and microbatch (LM-scale)
 # ---------------------------------------------------------------------------
 
+#: Which DP route each trace took: one count per traced call of a route.
+DP_PATH = Probe("dp.path", {"affine_closed_form": 0, "per_example": 0,
+                            "microbatch": 0})
+
+
 def _per_example_grad_fn(loss_fn: Callable):
     def one(p, ex):
         ex = jax.tree_util.tree_map(lambda t: t[None], ex)
@@ -193,6 +203,7 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
     n = jax.tree_util.tree_leaves(batch)[0].shape[0]
 
     if microbatches == 0:
+        DP_PATH["per_example"] += 1
         one = _per_example_grad_fn(loss_fn)
         c = per_example_chunk
         if c:
@@ -228,6 +239,7 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
                                     denom=float(n), kernels=kernels)
         return tree_unflatten_concat(out, params)
 
+    DP_PATH["microbatch"] += 1
     k = microbatches
     assert n % k == 0, (n, k)
     from repro.sharding.rules import shard_act
@@ -245,3 +257,38 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
     summed, _ = jax.lax.scan(body, zeros, mb)
     clipped_mean = jax.tree_util.tree_map(lambda s: s / k, summed)
     return add_noise(clipped_mean, key, sigma, clip, float(k))
+
+
+def dp_affine_gradients(params, x, dl, key, *, clip: float, sigma: float):
+    """``dp_gradients``' per-example result for an affine model z = x·w + b
+    (params ``{"w": (F, C), "b": (C,)}``), from ``dl`` (B, C), each
+    example's loss gradient with respect to its own logits.
+
+    Example i's gradient is (dlᵢ, xᵢ ⊗ dlᵢ), so its squared norm is
+    ‖dlᵢ‖²·(1 + ‖xᵢ‖²) and the clipped mean is (Σ sᵢ·dlᵢ, xᵀ(s ⊙ dl)): one
+    pass over x for the norms and one f32 contraction, instead of a (B, D)
+    per-example stack read twice. The contraction runs at HIGHEST precision,
+    as the stack's exact f32 outer products do. The noise is the same
+    ``add_flat_noise`` draw on the same flat [b, w.ravel()] layout, so the
+    same key gives bit-identical noise on both routes."""
+    DP_PATH["affine_closed_form"] += 1
+    from repro.kernels.dp_clip.ref import add_flat_noise
+    n = x.shape[0]
+    x32 = x.astype(jnp.float32)
+    with layer("per_example_grads"):
+        xsq = jnp.sum(x32 * x32, axis=-1)                        # (B,)
+    with layer("dp_clip"):
+        dl = dl.astype(jnp.float32)
+        norms = jnp.sqrt(jnp.sum(dl * dl, axis=-1) * (1.0 + xsq))
+        scales = jnp.minimum(1.0, clip / jnp.maximum(norms, 1e-12)) / float(n)
+        hi = jax.lax.Precision.HIGHEST
+        # both halves as contractions over the batch, as the per-example
+        # route's scale-accumulate forms them: XLA orders a plain sum over
+        # the batch per program, so a client-sharded run would drift by ulps
+        mean = {"b": jnp.einsum("bc,b->c", dl, scales, precision=hi),
+                "w": jnp.einsum("bf,bc->fc", x32, dl * scales[:, None],
+                                precision=hi)}
+    with layer("dp_noise"):
+        out = add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
+                             float(n))
+    return tree_unflatten_concat(out, params)

@@ -122,7 +122,21 @@ class P4Trainer:
             return distill.proxy_loss(lg, tgt, batch["y"], p4c.alpha,
                                       p4c.distill_temperature)
         with layer("proxy_dp_grad"):
-            if dpc.enabled:
+            if (dpc.enabled and not dpc.microbatches
+                    and self.apply_fn is linear_apply):
+                # affine logits: the per-example clip needs only each
+                # example's logit gradient (autodiff of its own loss, on the
+                # logits already computed above)
+                def one_loss(z, t, label):
+                    return distill.proxy_loss(z[None], t[None], label[None],
+                                              p4c.alpha,
+                                              p4c.distill_temperature)
+                dl = jax.vmap(jax.grad(one_loss))(
+                    proxy_logits, jax.lax.stop_gradient(private_logits), y)
+                g_prox = dp_lib.dp_affine_gradients(
+                    proxy, x, dl, key, clip=dpc.clip_norm,
+                    sigma=runtime_sigma(self.sigma))
+            elif dpc.enabled:
                 g_prox = dp_lib.dp_gradients(
                     proxy_obj, proxy, {"x": x, "y": y}, key,
                     clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),

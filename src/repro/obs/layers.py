@@ -19,8 +19,10 @@ layer                  entered around
 ``metrics_step``       the final ``lr = 0`` ``_client_step`` of
                        ``P4Trainer._local_round_keyed``
 ``per_example_grads``  the per-example ``vmap`` of the gradient and its
-                       flatten to the (c, D) stack in ``core.dp.dp_gradients``
-``dp_clip``            ``kernels.dispatch.clip_accumulate`` / ``dp_clip``
+                       flatten to the (c, D) stack in ``core.dp.dp_gradients``;
+                       the per-row ‖x‖² in ``core.dp.dp_affine_gradients``
+``dp_clip``            ``kernels.dispatch.clip_accumulate`` / ``dp_clip``;
+                       the closed form's norms, scales and contraction
 ``dp_noise``           the flat Eq. 11 noise draw and add
 ``aggregate``          the strategy's aggregation with the
                        ``merge_participation`` calls around it

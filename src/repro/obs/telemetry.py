@@ -297,8 +297,9 @@ class Telemetry:
         """The ``engine.run_rounds`` span of one chunk, with the
         trace-vs-execute split and cache hits/misses read off the
         chunk-cache probe (a cache hit executes without tracing), the
-        chunk's compile count and seconds off the ``jax.compile`` probe, and
-        its mixing path off the mix probe. The profiler capture of the Nth
+        chunk's compile count and seconds off the ``jax.compile`` probe, the
+        DP routes its trace took off the ``dp.path`` probe, and its mixing
+        path off the mix probe. The profiler capture of the Nth
         chunk rides this span."""
         from repro.obs.spans import span
         if not self.enabled:
@@ -308,7 +309,7 @@ class Telemetry:
         idx = self._chunk_idx
         self._chunk_idx += 1
         sel = [n for n in ("engine.chunk_cache", "topology.mix",
-                           "jax.compile") if n in REGISTRY.names()]
+                           "jax.compile", "dp.path") if n in REGISTRY.names()]
         profiled = (self.profile_chunk is not None
                     and idx == self.profile_chunk)
         prof_dir = os.path.join(self.run_dir, "profile")
@@ -341,6 +342,9 @@ class Telemetry:
         ev["compile_s"] = float(comp.get("compile_s", 0.0))
         if ev["compiles"]:
             ev["compiled"] = comp.get("last_compile", "")
+        routes = {k: int(v) for k, v in (d.get("dp.path") or {}).items() if v}
+        if routes:
+            ev["dp_path"] = routes
         mix = d.get("topology.mix") or {}
         if mix.get("calls", 0) > 0:
             paths = {k[len("path_"):]: v for k, v in mix.items()

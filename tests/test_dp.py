@@ -110,3 +110,68 @@ def test_microbatch_matches_per_example_when_mb_is_1(key):
                                clip=0.3, sigma=0.0, microbatches=n)
     np.testing.assert_allclose(np.asarray(g_pe["w"]), np.asarray(g_mb["w"]),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("chunk", [0, 4])
+@pytest.mark.parametrize("temperature", [1.0, 2.0])
+@pytest.mark.parametrize("alpha", [0.0, 0.5, 1.0])
+def test_affine_closed_form_matches_per_example(key, alpha, temperature,
+                                                 chunk):
+    """The linear model's DP gradient from per-example logit gradients
+    equals the per-example route's on P4's proxy loss (Eq. 8), clipped and
+    unclipped examples alike, and draws the same noise from the same key."""
+    from repro.core import distill
+    from repro.core.small_models import linear_apply
+    n, feat, classes = 16, 12, 5
+    x = (jax.random.normal(jax.random.fold_in(key, 1), (n, feat))
+         * jnp.linspace(0.05, 3.0, n)[:, None])
+    y = jax.random.randint(jax.random.fold_in(key, 2), (n,), 0, classes)
+    proxy = {"w": 0.3 * jax.random.normal(jax.random.fold_in(key, 3),
+                                          (feat, classes)),
+             "b": 0.1 * jax.random.normal(jax.random.fold_in(key, 4),
+                                          (classes,))}
+    private = {"w": 0.3 * jax.random.normal(jax.random.fold_in(key, 5),
+                                            (feat, classes)),
+               "b": jnp.zeros((classes,))}
+
+    def proxy_obj(w, batch):
+        return distill.proxy_loss(linear_apply(w, batch["x"]),
+                                  linear_apply(private, batch["x"]),
+                                  batch["y"], alpha, temperature)
+
+    def one_loss(z, t, label):
+        return distill.proxy_loss(z[None], t[None], label[None], alpha,
+                                  temperature)
+
+    dl = jax.vmap(jax.grad(one_loss))(linear_apply(proxy, x),
+                                      linear_apply(private, x), y)
+    norms = np.sqrt(np.sum(np.square(dl), -1)
+                    * (1 + np.sum(np.square(x), -1)))
+    clip = float(np.median(norms))
+    assert (norms > 1.01 * clip).any() and (norms < 0.99 * clip).any()
+    k = jax.random.fold_in(key, 6)
+
+    def both(sigma):
+        pe = dp_lib.dp_gradients(proxy_obj, proxy, {"x": x, "y": y}, k,
+                                 clip=clip, sigma=sigma,
+                                 per_example_chunk=chunk)
+        cf = dp_lib.dp_affine_gradients(proxy, x, dl, k, clip=clip,
+                                        sigma=sigma)
+        return pe, cf
+
+    pe0, cf0 = both(0.0)
+    pe1, cf1 = both(1.3)
+    for name in ("b", "w"):
+        assert cf0[name].shape == pe0[name].shape
+        assert cf0[name].dtype == pe0[name].dtype
+        np.testing.assert_allclose(np.asarray(cf0[name]),
+                                   np.asarray(pe0[name]), rtol=1e-5,
+                                   atol=1e-7)
+        # the same draw: each route's noise is its noised minus its clean
+        # result; they differ only by the rounding of that one add
+        noise_pe = np.asarray(pe1[name]) - np.asarray(pe0[name])
+        noise_cf = np.asarray(cf1[name]) - np.asarray(cf0[name])
+        ulp = (np.finfo(np.float32).eps
+               * np.max(np.abs(np.asarray(pe1[name]))))
+        assert np.max(np.abs(noise_pe)) > 1e3 * ulp
+        np.testing.assert_allclose(noise_cf, noise_pe, rtol=0, atol=4 * ulp)

@@ -112,3 +112,53 @@ def test_p4_lm_step_runs_and_loss_finite(key):
     assert bool(jnp.isfinite(metrics["loss"]))
     for leaf in jax.tree_util.tree_leaves(params):
         assert bool(jnp.all(jnp.isfinite(leaf.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("model", ["linear", "cnn"])
+def test_dp_route_follows_model_structure(key, model):
+    """The affine linear model's proxy takes the closed-form DP route; the
+    CNN keeps the per-example route (read from the ``dp.path`` probe)."""
+    from repro.obs import probe_deltas
+    xs, ys = _toy_tasks(M=4, feat=16)
+    kw = {"cnn_shape": (1, 4, 4)} if model == "cnn" else {}
+    trainer = P4Trainer(feat_dim=16, num_classes=4, cfg=_run_cfg(),
+                        model=model, **kw)
+    states = trainer.init_clients(key, 4)
+    with probe_deltas("dp.path") as d:
+        states, _ = trainer.local_round(states, jnp.asarray(xs[:, :8]),
+                                        jnp.asarray(ys[:, :8]), key)
+        jax.block_until_ready(states)
+    routes = d["dp.path"]
+    assert routes["microbatch"] == 0
+    if model == "linear":
+        assert routes["affine_closed_form"] > 0 and routes["per_example"] == 0
+    else:
+        assert routes["per_example"] > 0 and routes["affine_closed_form"] == 0
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_linear_closed_form_step_matches_per_example(key, chunk):
+    """Two DP co-training rounds of the linear trainer on the closed form
+    agree with the same rounds on the per-example route (the same model
+    behind a wrapper the route test does not recognise as affine)."""
+    from repro.core.small_models import linear_apply
+    xs, ys = _toy_tasks(M=4)
+    cfg = _run_cfg(dp=DPConfig(epsilon=15.0, rounds=40, sample_rate=0.5,
+                               clip_norm=1.0, per_example_chunk=chunk))
+    closed = P4Trainer(feat_dim=20, num_classes=4, cfg=cfg)
+    per_ex = P4Trainer(feat_dim=20, num_classes=4, cfg=cfg)
+    per_ex.apply_fn = lambda p, x: linear_apply(p, x)
+    xb, yb = jnp.asarray(xs[:, :32]), jnp.asarray(ys[:, :32])
+    s_cf = s_pe = closed.init_clients(key, 4)
+    for r in range(2):
+        k = jax.random.fold_in(key, r)
+        s_cf, m_cf = closed.local_round(s_cf, xb, yb, k)
+        s_pe, m_pe = per_ex.local_round(s_pe, xb, yb, k)
+    for a, b in zip(jax.tree_util.tree_leaves((s_cf, m_cf)),
+                    jax.tree_util.tree_leaves((s_pe, m_pe))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+    start = closed.init_clients(key, 4)["proxy"]
+    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
+                                   s_cf["proxy"], start)
+    assert min(jax.tree_util.tree_leaves(moved)) > 1e-3
