@@ -42,20 +42,19 @@ def calibrate(cell, seeds, controls, *, kernels=None, emit=print):
     """Yields (seed, kind, numbers) for the program on ``seeds`` and for the
     control and the half-batch fault on ``controls``."""
     import jax.numpy as jnp
-    from chipbench import data as data_lib, harness
+    from chipbench import harness
     cfg, mix = cell["cfg"], cell["mix"]
     out = []
     for seed in sorted(set(seeds) | set(controls)):
         keys = harness.run_keys(seed)
-        data = data_lib.make_data(mix, cfg, keys["data"], seed)
+        data = cell["kind"].make_data(mix, cfg, keys["data"], seed)
         rows = []
         if seed in seeds:
             _, _, state, run_out, _, _ = harness.first_chunk(
                 cell, seed, keys, data, kernels=kernels, keep=True)
             del state
             t = time.perf_counter()
-            nums = harness.follow_reference(cfg, mix, data, keys, run_out,
-                                            seed)
+            nums = harness.follow_reference(cell, data, keys, run_out, seed)
             rows.append(("program", dict(nums, reference_s=time.perf_counter()
                                          - t)))
         if seed in controls:
@@ -63,10 +62,10 @@ def calibrate(cell, seeds, controls, *, kernels=None, emit=print):
                                        ("half_batch", jnp.float32,
                                         "half_batch")):
                 cand = harness.reference_outputs(
-                    cfg, mix, data, keys, dtype=dtype, fault=fault,
-                    seed=seed, keep=True)
+                    cell, data, keys, dtype=dtype, fault=fault, seed=seed,
+                    keep=True)
                 rows.append((kind, harness.follow_reference(
-                    cfg, mix, data, keys, cand, seed)))
+                    cell, data, keys, cand, seed)))
         for kind, nums in rows:
             rec = {"seed": seed, "kind": kind, **nums}
             emit(json.dumps(rec))

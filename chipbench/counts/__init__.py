@@ -26,9 +26,9 @@ def model(cfg, here: str = HERE):
     return mod
 
 
-def forward_flops(cfg) -> int:
-    return 2 * model(cfg).forward_macs(cfg)
+def forward_flops(cfg, here: str = HERE) -> int:
+    return 2 * model(cfg, here).forward_macs(cfg)
 
 
-def step_flops_per_example(cfg) -> int:
-    return UNITS_PER_EXAMPLE_STEP * forward_flops(cfg)
+def step_flops_per_example(cfg, here: str = HERE) -> int:
+    return UNITS_PER_EXAMPLE_STEP * forward_flops(cfg, here)

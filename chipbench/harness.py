@@ -4,15 +4,19 @@ result line.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 configuration file it names, ``traffic/<traffic>.json``,
-``limits/<cell>.json``, ``metrics/<metric>.py`` for each per-layer metric
-and ``counts/<model>.py``. Adding a cell, a configuration or a metric adds
-files and entries; no file here changes.
+``limits/<cell>.json``, ``metrics/<metric>.py`` for each per-layer metric,
+and by the configuration's ``model``, ``models/<model>.py`` (its data, the
+program's model, the reference's model and its correct count; see
+``models/__init__.py``) and ``counts/<model>.py`` (its work). Adding a cell,
+a configuration or a metric adds files and entries; a new kind of model
+adds ``models/<model>.py`` and ``counts/<model>.py`` beside them. No file
+here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
-import math
 import os
 import shutil
 import sys
@@ -25,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import compare, counts, data as data_lib, program as prog_lib
+from chipbench import compare, counts, program as prog_lib
 from chipbench import reference as ref
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -44,8 +48,8 @@ def _applies(metric, cell_name):
 
 
 def load_cell(name: str, root: str = ROOT) -> Dict[str, Any]:
-    """The cell's entry of BENCHMARK.json with its configuration, traffic,
-    limits and metric entries resolved by name."""
+    """The cell's entry of BENCHMARK.json with its configuration, model
+    kind, traffic, limits and metric entries resolved by name."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -63,11 +67,22 @@ def load_cell(name: str, root: str = ROOT) -> Dict[str, Any]:
     cell["end_to_end"] = [m for m in bench["end_to_end"]
                           if _applies(m, name)]
     cell["per_layer"] = [m for m in bench["per_layer"] if _applies(m, name)]
+    cell["root"] = root
+    cell["kind"] = model_kind(cell["cfg"], root)
     return cell
 
 
+def model_kind(cfg, root: str = ROOT):
+    """The configuration's model kind, ``models/<model>.py``."""
+    return load_module(os.path.join(root, "chipbench", "models",
+                                    cfg["model"] + ".py"))
+
+
+@functools.cache
 def load_module(path: str):
-    """A benchmark module by its file path (metric readers, work counts)."""
+    """A benchmark module by its file path (model kinds, metric readers,
+    work counts), loaded once: jitted functions that take a model kind as a
+    static argument find their compiled programs again."""
     name = "chipbench_" + os.path.relpath(path, ROOT).replace(os.sep, "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
@@ -177,7 +192,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, t0: float,
     keys = run_keys(seed)
 
     # ---------------- set-up: data, bootstrap, Phase 1, warm-up chunk ----
-    data = data_lib.make_data(mix, cfg, keys["data"], seed)
+    data = cell["kind"].make_data(mix, cfg, keys["data"], seed)
     p, fd, state, run_out, groups, grouping_s = first_chunk(
         cell, seed, keys, data, kernels=kernels, plant=plant)
     jax.block_until_ready(state)
@@ -243,12 +258,13 @@ def run(cell, seed: int, seconds: float, trace: bool, *, t0: float,
             device["window_s"] = red["window_s"]
             breakdown = {"device_ops": red["top_ops"],
                          "idle_gaps": red["idle_gaps"]}
+        work = counts.step_flops_per_example(
+            cfg, os.path.join(cell["root"], "chipbench", "counts"))
         ctx = Context(window=window, setup=setup, trace=red, peaks=peaks,
-                      chips=cell["chips"],
-                      step_flops_per_example=counts.step_flops_per_example(cfg),
+                      chips=cell["chips"], step_flops_per_example=work,
                       cfg=cfg, mix=mix)
         for m in cell["per_layer"]:
-            v = reader(m["name"])(ctx)
+            v = reader(m["name"], cell["root"])(ctx)
             if v is not None:
                 result_metrics[m["name"]] = {"value": float(v),
                                              "unit": m["unit"]}
@@ -261,7 +277,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, t0: float,
 
     # ---------------- the plain reference, after the window ---------------
     tr = time.perf_counter()
-    nums = follow_reference(cfg, mix, data, keys, run_out, seed)
+    nums = follow_reference(cell, data, keys, run_out, seed)
     ok, checks = compare.verdict(nums, cell["limits"])
     log(f"reference: {time.perf_counter() - tr:.3f} s")
     for k, v in nums.items():
@@ -288,21 +304,21 @@ def first_chunk(cell, seed, keys, data, *, kernels=None, plant=None,
     follows. Returns the program, its data, the state after the chunk (to
     hand on to the window), what the comparison reads, the groups and the
     grouping seconds."""
-    cfg, mix = cell["cfg"], cell["mix"]
+    cfg, mix, kind = cell["cfg"], cell["mix"], cell["kind"]
     M, E, B = mix["clients"], mix["eval_every"], mix["local_batch"]
     nb = cfg["p4"]["bootstrap_rounds"]
     fd = prog_lib.federated(data)
-    p = prog_lib.build(cfg, mix, kernels)
+    p = prog_lib.build(cfg, mix, kind.trainer_kwargs(cfg), kernels)
     if plant is not None:
         plant(p)
     shapes = prog_lib.state_shapes(p)
-    if shapes != ref.param_shapes(cfg):
+    if shapes != kind.param_shapes(cfg):
         raise RuntimeError(f"program model {shapes} differs from the "
-                           f"configuration's {ref.param_shapes(cfg)}")
-    state = ref.init_state(cfg, M, keys["init"])
+                           f"configuration's {kind.param_shapes(cfg)}")
+    state = ref.init_state(kind, cfg, M, keys["init"])
     state, _, _ = p.bootstrap.run_rounds(state, fd, keys["boot"], 0, nb, None)
     run_out = {"boot_change": compare.change_norms(
-        state, ref.init_state(cfg, M, keys["init"]))}
+        state, ref.init_state(kind, cfg, M, keys["init"]))}
     tg = time.perf_counter()
     with prog_lib.seen_distances(run_out):
         groups = p.trainer.form_groups(state, seed)
@@ -318,8 +334,7 @@ def first_chunk(cell, seed, keys, data, *, kernels=None, plant=None,
     if keep:
         run_out["states"] = (before, state)
     del before
-    n_test = data["test_y"].shape[1]
-    run_out["correct"] = np.rint(np.asarray(acc, np.float64) * n_test)
+    run_out["correct"] = kind.run_correct(cfg, acc, data["test_y"])
     run_out["losses"] = np.stack([np.asarray(metrics["private_loss"]),
                                   np.asarray(metrics["proxy_loss"])], axis=1)
     return p, fd, state, run_out, groups, grouping_s
@@ -337,7 +352,7 @@ def reference_sigma(cfg, mix):
                            dp["rounds"], mix["local_steps"])
 
 
-def follow_reference(cfg, mix, data, keys, run_out, seed):
+def follow_reference(cell, data, keys, run_out, seed):
     """The reference from the seed through the bootstrap, Phase 1 and the
     first co-train chunk, following the run's groups as a served model's
     reference follows its served tokens; the numbers of ``compare.numbers``
@@ -346,9 +361,9 @@ def follow_reference(cfg, mix, data, keys, run_out, seed):
     distances against the reference's, and the run's groups against those
     the paper's greedy procedure forms on the run's own distances (the same
     matrix, so a near tie cannot fall differently)."""
-    p4 = cfg["p4"]
+    p4 = cell["cfg"]["p4"]
     keep = "states" in run_out
-    refs = reference_outputs(cfg, mix, data, keys, dtype=jnp.float32,
+    refs = reference_outputs(cell, data, keys, dtype=jnp.float32,
                              fault=None, seed=seed, groups=run_out["groups"],
                              keep=keep)
     refs["groups"] = ref.greedy_groups(run_out["dist"], p4["group_size"],
@@ -359,21 +374,21 @@ def follow_reference(cfg, mix, data, keys, run_out, seed):
     return nums
 
 
-def reference_outputs(cfg, mix, data, keys, *, dtype, fault, seed,
+def reference_outputs(cell, data, keys, *, dtype, fault, seed,
                       groups=None, keep=False):
     """What the reference computes from the seed, in ``dtype``: the
     bootstrap's change, the Phase-1 distances, the first chunk's losses and
     change with ``groups`` (None: the groups it forms itself, acting as the
     program), and the test predictions it gets right."""
+    cfg, mix, kind = cell["cfg"], cell["mix"], cell["kind"]
     M, E, B = mix["clients"], mix["eval_every"], mix["local_batch"]
     nb, p4 = cfg["p4"]["bootstrap_rounds"], cfg["p4"]
     hp, sigma = reference_hp(cfg, mix), reference_sigma(cfg, mix)
     block = min(cfg["reference_block"], M)
     cast = partial_cast(dtype)
-    init = cast(ref.init_state(cfg, M, keys["init"]))
-    rdata = dict(data, train_x=data["train_x"].astype(dtype),
-                 test_x=data["test_x"].astype(dtype))
-    boot, _ = ref.run_rounds(cfg, hp, {"kind": "full"}, init, rdata,
+    init = cast(ref.init_state(kind, cfg, M, keys["init"]))
+    rdata = {k: ref.as_dtype(v, dtype) for k, v in data.items()}
+    boot, _ = ref.run_rounds(kind, cfg, hp, {"kind": "full"}, init, rdata,
                              keys["boot"], 0, nb, None, sigma, block=block,
                              fault=fault)
     out = {"boot_change": compare.change_norms(boot, init),
@@ -383,12 +398,14 @@ def reference_outputs(cfg, mix, data, keys, *, dtype, fault, seed,
         groups = ref.greedy_groups(out["dist"], p4["group_size"],
                                    p4["sample_peers"], seed)
     out["groups"] = groups
-    after, losses = ref.run_rounds(cfg, hp, mix["schedule"], boot, rdata,
-                                   keys["cotrain"], nb, nb + E, B, sigma,
-                                   groups=groups, block=block, fault=fault)
+    after, losses = ref.run_rounds(kind, cfg, hp, mix["schedule"], boot,
+                                   rdata, keys["cotrain"], nb, nb + E, B,
+                                   sigma, groups=groups, block=block,
+                                   fault=fault)
     out["change"] = compare.change_norms(after, boot)
     out["losses"] = losses
-    out["correct"] = ref.correct_counts(cfg, after, rdata)
+    out["correct"] = kind.correct_counts(cfg, after["private"],
+                                         rdata["test_x"], rdata["test_y"])
     if keep:
         out["states"] = (boot, after)
     return out

@@ -1,7 +1,9 @@
-"""Traffic generator: a federation of clients with class-conditional
-features at the model's full input width, made from a seed.
+"""What the paper's classifiers on feature rows share: the feature
+generator, the initialization, the loss and the correct count.
 
-Everything a mix varies is read from its traffic file (``traffic/<name>.json``):
+The generator makes a federation of clients with class-conditional
+features at the model's full input width from a seed. Everything a mix
+varies is read from its traffic file (``traffic/<name>.json``):
 
 * ``clients``, ``samples_per_client``, ``train_per_client``: the federation;
 * ``classes_per_client``: the shard split of ``repro/data/partition.py``
@@ -20,6 +22,7 @@ federation's size is held beside the result.
 """
 from __future__ import annotations
 
+import json
 import math
 from functools import partial
 
@@ -27,6 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------- data
 
 def client_labels(traffic, num_classes: int, seed: int):
     """(M, R) labels, train rows first: the shard split, then each client's
@@ -75,3 +82,62 @@ def make_data(traffic, cfg, key, seed: int):
            "test_x": test_x, "test_y": jnp.asarray(labels[:, n:])}
     jax.block_until_ready(out)
     return out
+
+
+# ---------------------------------------------------------------- model
+
+def init_params(shapes, key):
+    """Each weight normal / sqrt(fan-in) from its own fold of ``key`` (in
+    sorted key order), the bias ``b`` zero."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(shapes.items())):
+        if name == "b":
+            out[name] = jnp.zeros(shape, jnp.float32)
+            continue
+        fan_in = math.prod(shape[1:]) if len(shape) == 4 else shape[0]
+        out[name] = (jax.random.normal(jax.random.fold_in(key, i), shape,
+                                       jnp.float32) / math.sqrt(fan_in))
+    return out
+
+
+def _ce(logits, y):
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=-1))
+
+
+def _kl(p_logits, q_logits):
+    p = jax.nn.log_softmax(p_logits, axis=-1)
+    q = jax.nn.log_softmax(q_logits, axis=-1)
+    return jnp.mean(jnp.sum(jnp.exp(p) * (p - q), axis=-1))
+
+
+def mutual_loss(logits, other_logits, y, weight):
+    """Eqs. 8-9: (1 - a)·CE(f, y) + a·KL(f ‖ g), g held constant."""
+    other = jax.lax.stop_gradient(other_logits)
+    return (1.0 - weight) * _ce(logits, y) + weight * _kl(logits, other)
+
+
+# ---------------------------------------------------------------- evaluation
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _evaluate(apply, cfg_json, private, test_x, test_y):
+    cfg = json.loads(cfg_json)
+    dt = jax.tree_util.tree_leaves(private)[0].dtype
+    prec = HIGHEST if dt == jnp.float32 else None
+
+    def one(p, x, y):
+        pred = jnp.argmax(apply(cfg, p, x.astype(dt), prec), axis=-1)
+        return jnp.sum(pred == y)
+    return jax.lax.map(lambda a: one(*a), (private, test_x, test_y))
+
+
+def correct_counts(apply, cfg, private, test_x, test_y):
+    """Per-client count of test examples whose argmax under ``apply`` is
+    their label."""
+    return np.asarray(_evaluate(apply, json.dumps(cfg, sort_keys=True),
+                                private, test_x, test_y))
+
+
+def run_correct(cfg, acc, test_y):
+    """The program's per-client accuracy over its test rows, as a count."""
+    return np.rint(np.asarray(acc, np.float64) * test_y.shape[1])

@@ -42,15 +42,14 @@ def run_config(cfg, traffic, kernels: Optional[dict] = None):
         schedule=ScheduleConfig(accountant="none", **traffic["schedule"]))
 
 
-def build(cfg, traffic, kernels: Optional[dict] = None) -> Program:
+def build(cfg, traffic, trainer_kwargs, kernels: Optional[dict] = None
+          ) -> Program:
+    """The program for ``cfg`` under ``traffic``; ``trainer_kwargs`` (the
+    model kind's) name its model to ``P4Trainer``."""
     from repro.core.p4 import P4Strategy, P4Trainer
     from repro.engine import Engine, make_schedule
     run = run_config(cfg, traffic, kernels)
-    extra = {}
-    if cfg["model"] == "cnn":
-        extra = {"model": "cnn", "cnn_shape": tuple(cfg["cnn_shape"])}
-    trainer = P4Trainer(feat_dim=cfg["feat_dim"],
-                        num_classes=cfg["num_classes"], cfg=run, **extra)
+    trainer = P4Trainer(cfg=run, **trainer_kwargs)
     strategy = P4Strategy(trainer=trainer)
     every = traffic["eval_every"]
     return Program(trainer=trainer, strategy=strategy,

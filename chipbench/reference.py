@@ -4,7 +4,10 @@ Written from the paper and the configuration alone; it imports nothing of
 the program under test. Each step is the literal definition: per-example
 gradients by ``vmap(grad)``, per-example clipping to the global norm C
 (Eq. 10), the clipped mean plus Gaussian noise (2C/n)·σ·N(0, I) (Eq. 11),
-σ from Eq. 12, plain SGD, and the group mean of the proxy models.
+σ from Eq. 12, plain SGD, and the group mean of the proxy models. The
+model itself (its parameters, forward pass and loss) is the configuration's
+model kind, ``models/<model>.py``, which imports nothing of the program
+either.
 
 Two conventions are shared with the system under test because they are part
 of the run's inputs, not of its arithmetic:
@@ -23,6 +26,7 @@ data, activations, updates): the lower-precision control.
 """
 from __future__ import annotations
 
+import json
 import math
 from functools import partial
 
@@ -34,95 +38,36 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------- models
+#
+# ``kind`` is the configuration's model kind (``models/<model>.py``): it
+# gives one model's parameters, forward pass and loss. Jitted functions take
+# it and the configuration as static arguments, the configuration as its
+# JSON text.
 
-def param_shapes(cfg):
-    """Parameter shapes of one model, by key, from the configuration."""
-    F, C = cfg["feat_dim"], cfg["num_classes"]
-    if cfg["model"] == "linear":
-        return {"w": (F, C), "b": (C,)}
-    ch, h, w = cfg["cnn_shape"]
-    width = cfg["cnn_width"]
-    feat = 2 * width * max(h // 4, 1) * max(w // 4, 1)
-    return {"c1": (width, ch, 3, 3), "c2": (2 * width, width, 3, 3),
-            "w": (feat, C), "b": (C,)}
+def _static(cfg):
+    return json.dumps(cfg, sort_keys=True)
 
 
-def param_count(cfg) -> int:
-    return sum(math.prod(s) for s in param_shapes(cfg).values())
+def as_dtype(t, dtype):
+    """Floating inputs in ``dtype``; token ids and labels as they are."""
+    return t.astype(dtype) if jnp.issubdtype(t.dtype, jnp.floating) else t
 
 
-def _init_model(cfg, key):
-    out = {}
-    for i, (name, shape) in enumerate(sorted(param_shapes(cfg).items())):
-        if name == "b":
-            out[name] = jnp.zeros(shape, jnp.float32)
-            continue
-        fan_in = math.prod(shape[1:]) if len(shape) == 4 else shape[0]
-        out[name] = (jax.random.normal(jax.random.fold_in(key, i), shape,
-                                       jnp.float32) / math.sqrt(fan_in))
-    return out
-
-
-@partial(jax.jit, static_argnums=(0, 1))
-def _init_state(cfg_items, M, key):
-    cfg = dict(cfg_items)
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _init_state(kind, cfg_json, M, key):
+    cfg = json.loads(cfg_json)
 
     def stack(p):
         return jax.tree_util.tree_map(
             lambda t: jnp.broadcast_to(t[None], (M,) + t.shape), p)
-    return {"private": stack(_init_model(cfg, jax.random.fold_in(key, 0))),
-            "proxy": stack(_init_model(cfg, jax.random.fold_in(key, 1)))}
+    return {"private": stack(kind.init_model(cfg, jax.random.fold_in(key, 0))),
+            "proxy": stack(kind.init_model(cfg, jax.random.fold_in(key, 1)))}
 
 
-def init_state(cfg, M: int, key):
+def init_state(kind, cfg, M: int, key):
     """One initialization shared by every client (private and proxy models
     drawn apart), stacked over M clients, made on the device in one call."""
-    return _init_state(_hashable(cfg), M, key)
-
-
-def _hashable(cfg):
-    keys = ("model", "feat_dim", "num_classes", "cnn_shape", "cnn_width")
-    return tuple((k, tuple(cfg[k]) if isinstance(cfg.get(k), list)
-                  else cfg.get(k)) for k in keys)
-
-
-def apply(cfg, params, x, prec):
-    """Logits of one model on a batch x (B, F)."""
-    dt = x.dtype
-    if cfg["model"] == "linear":
-        return jnp.dot(x, params["w"], precision=prec) + params["b"]
-    ch, h, w = cfg["cnn_shape"]
-    t = x.reshape(x.shape[0], ch, h, w)
-
-    def conv(t, k):
-        return jax.lax.conv_general_dilated(
-            t, k, (1, 1), "SAME", dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            precision=prec)
-
-    def pool(t):
-        return jax.lax.reduce_window(t, -jnp.inf, jax.lax.max,
-                                     (1, 1, 2, 2), (1, 1, 2, 2), "VALID")
-    t = pool(jax.nn.relu(conv(t, params["c1"])))
-    t = pool(jax.nn.relu(conv(t, params["c2"])))
-    t = t.reshape(t.shape[0], -1)
-    return jnp.dot(t, params["w"], precision=prec) + params["b"]
-
-
-def _ce(logits, y):
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=-1))
-
-
-def _kl(p_logits, q_logits):
-    p = jax.nn.log_softmax(p_logits, axis=-1)
-    q = jax.nn.log_softmax(q_logits, axis=-1)
-    return jnp.mean(jnp.sum(jnp.exp(p) * (p - q), axis=-1))
-
-
-def mutual_loss(logits, other_logits, y, weight):
-    """Eqs. 8-9: (1 - a)·CE(f, y) + a·KL(f ‖ g), g held constant."""
-    other = jax.lax.stop_gradient(other_logits)
-    return (1.0 - weight) * _ce(logits, y) + weight * _kl(logits, other)
+    return _init_state(kind, _static(cfg), M, key)
 
 
 def noble_sigma(epsilon, delta, sample_rate, rounds, local_steps):
@@ -147,12 +92,13 @@ def _unflat(vec, like):
     return out
 
 
-def _client_steps(cfg, hp, private, proxy, x, y, ckey, sigma, prec,
+def _client_steps(kind, cfg, hp, private, proxy, x, y, ckey, sigma, prec,
                   half_batch=False):
     """K local steps of one client, then its losses at the updated models.
     ``half_batch`` plants a fault: the step uses half of the batch and takes
     the mean over that half."""
     dt = x.dtype
+    apply, mutual_loss = kind.apply, kind.mutual_loss
     lr, clip = hp["lr"], hp["clip"]
     if half_batch:
         x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
@@ -223,10 +169,18 @@ def _cohort(schedule, key, M):
     return (u < q).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
-def _round(cfg_items, hp_items, schedule_items, batch, block, fault, state,
-           train_x, train_y, phase_key, r, sigma, ids, num_groups_arr):
-    cfg, hp, schedule = dict(cfg_items), dict(hp_items), dict(schedule_items)
+def _rows_at(t, idx):
+    """Rows ``idx`` (clients, batch) of each client's ``t`` (clients, rows,
+    ...)."""
+    return jnp.take_along_axis(t, idx.reshape(idx.shape + (1,) * (t.ndim - 2)),
+                               axis=1)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
+def _round(kind, cfg_json, hp_items, schedule_items, batch, block, fault,
+           state, train_x, train_y, phase_key, r, sigma, ids, num_groups_arr):
+    cfg, hp, schedule = (json.loads(cfg_json), dict(hp_items),
+                         dict(schedule_items))
     dt = jax.tree_util.tree_leaves(state)[0].dtype
     prec = HIGHEST if dt == jnp.float32 else None
     M, R = train_y.shape
@@ -243,11 +197,10 @@ def _round(cfg_items, hp_items, schedule_items, batch, block, fault, state,
         pr, px = (jax.tree_util.tree_map(rows, state[m])
                   for m in ("private", "proxy"))
         ib, kb = rows(idx), rows(keys)
-        xs = jnp.take_along_axis(rows(train_x), ib[:, :, None],
-                                 axis=1).astype(dt)
-        ys = jnp.take_along_axis(rows(train_y), ib, axis=1)
+        xs = as_dtype(_rows_at(rows(train_x), ib), dt)
+        ys = _rows_at(rows(train_y), ib)
         return jax.vmap(lambda p, q, x, y, k: _client_steps(
-            cfg, hp, p, q, x, y, k, sigma, prec,
+            kind, cfg, hp, p, q, x, y, k, sigma, prec,
             half_batch=(fault == "half_batch")))(pr, px, xs, ys, kb)
 
     pr, px, losses = jax.lax.map(one_block, jnp.arange(M // block))
@@ -270,7 +223,7 @@ def _round(cfg_items, hp_items, schedule_items, batch, block, fault, state,
     return new, jnp.mean(losses, axis=0)
 
 
-def run_rounds(cfg, hp, schedule, state, data, phase_key, start, stop,
+def run_rounds(kind, cfg, hp, schedule, state, data, phase_key, start, stop,
                batch, sigma, groups=None, block=8, fault=None):
     """Rounds [start, stop) of the reference. Returns (state, losses) with
     losses (rounds, 2): the mean private and proxy loss of each round."""
@@ -283,30 +236,12 @@ def run_rounds(cfg, hp, schedule, state, data, phase_key, start, stop,
     out = []
     for r in range(start, stop):
         state, losses = _round(
-            _hashable(cfg), tuple(sorted(hp.items())),
+            kind, _static(cfg), tuple(sorted(hp.items())),
             tuple(sorted(schedule.items())), batch, block, fault, state,
             data["train_x"], data["train_y"], phase_key, r,
             jnp.float32(sigma), ids, G)
         out.append(losses)
     return state, np.asarray(jnp.stack(out), np.float64)
-
-
-@partial(jax.jit, static_argnums=0)
-def _evaluate(cfg_items, private, test_x, test_y):
-    cfg = dict(cfg_items)
-    dt = jax.tree_util.tree_leaves(private)[0].dtype
-    prec = HIGHEST if dt == jnp.float32 else None
-
-    def one(p, x, y):
-        pred = jnp.argmax(apply(cfg, p, x.astype(dt), prec), axis=-1)
-        return jnp.sum(pred == y)
-    return jax.lax.map(lambda a: one(*a), (private, test_x, test_y))
-
-
-def correct_counts(cfg, state, data):
-    """Per-client count of test examples the private model gets right."""
-    return np.asarray(_evaluate(_hashable(cfg), state["private"],
-                                data["test_x"], data["test_y"]))
 
 
 # ---------------------------------------------------------------- Phase 1

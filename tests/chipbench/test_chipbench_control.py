@@ -29,7 +29,7 @@ def _run(cell, plant):
 def _unchanged(p):
     zeros = {"private_loss": jnp.zeros(()), "proxy_loss": jnp.zeros(())}
     p.strategy.local_update = lambda states, xs, ys, r, key: (states, zeros)
-    p.strategy.aggregate = lambda states, r, key: states
+    _no_exchange(p)
 
 
 def _half_batch(p):
@@ -39,7 +39,9 @@ def _half_batch(p):
 
 
 def _no_exchange(p):
+    """No aggregation: over every client, or over a sampled cohort."""
     p.strategy.aggregate = lambda states, r, key: states
+    p.strategy.aggregate_masked = lambda states, r, key, mask: states
 
 
 def _swapped_groups(p):

@@ -53,7 +53,7 @@ def test_forward_against_cost_analysis(name):
                train={"learning_rate": 0.1},
                kernels={"backend": "ref", "autotune": False,
                         "dp_clip_tile": [0, 0], "l1_tile": [0, 0]})
-    p = program.build(cfg, mix)
+    p = program.build(cfg, mix, harness.model_kind(cfg).trainer_kwargs(cfg))
     params = {k: jax.ShapeDtypeStruct(v, jnp.float32)
               for k, v in program.state_shapes(p).items()}
     x = jax.ShapeDtypeStruct((1, cfg["feat_dim"]), jnp.float32)

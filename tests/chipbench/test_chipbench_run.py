@@ -75,11 +75,16 @@ def test_traced_rehearsal_reports_per_layer_metrics():
 
 
 def test_sampled_schedule_is_followed_by_the_reference():
-    """A traffic file alone makes a sampled cell: the reference draws the
-    same fixed-size cohorts (stream 3) and masks the group means alike."""
-    cell = shrink(harness.load_cell("linear-c10.full"))
-    cell["mix"]["schedule"] = {"kind": "sampling", "client_rate": 0.25,
-                               "mode": "fixed"}
+    """A traffic file alone makes the sampled cell: the full cell's mix with
+    a fixed cohort of a quarter of the clients, which the reference draws
+    alike (stream 3), masking the group means alike."""
+    cell = harness.load_cell("linear-c10.sampled-q25")
+    full = harness.load_cell("linear-c10.full")["mix"]
+    assert {k: v for k, v in cell["mix"].items()
+            if k not in ("schedule", "why")} == {
+        k: v for k, v in full.items() if k not in ("schedule", "why")}
+    assert harness.participants(cell["mix"]) == 64.0
+    cell = shrink(cell)
     out = harness.run(cell, 9, 0.1, False, t0=0.0, require_chip=False,
                       kernels={"backend": "ref"}, log=lambda m: None)
     assert out["correct"] is True
