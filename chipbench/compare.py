@@ -8,7 +8,7 @@ reference, each number held to its limit from ``limits/<cell>.json``.
   under a thousandth of the median leaf's are left out.
 * ``dist_gap``: Phase 1's distances (Eq. 3), the run's against the
   reference's, largest gap over the larger of the reference's distance
-  and its median.
+  and its median (0 for a federation of one client, which has no pair).
 * ``group_gap``: Phase 1's groups. The clients whose group differs between
   the run's grouping and the one the paper's greedy procedure forms on the
   run's own distances (0 when the two are the same partition).
@@ -29,21 +29,17 @@ import numpy as np
 
 
 @jax.jit
-def _leaf_norms(a, b):
-    return jax.tree_util.tree_map(
-        lambda x, y: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)
-                                                 - y.astype(jnp.float32)))),
-        a, b)
+def _leaf_norm(x, y):
+    return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)
+                                       - y.astype(jnp.float32))))
 
 
 def change_norms(new, old):
-    """{"private/w": ‖new − old‖, ...} over every client's rows."""
-    out = {}
-    for model in ("private", "proxy"):
-        norms = _leaf_norms(new[model], old[model])
-        for k in sorted(norms):
-            out[f"{model}/{k}"] = float(norms[k])
-    return out
+    """{"private/w": ‖new − old‖, ...} over every client's rows, one leaf at
+    a time on the device: either tree may live on the host (a run's
+    before-states do), and only one leaf of it is copied over at a time."""
+    return {f"{model}/{k}": float(_leaf_norm(new[model][k], old[model][k]))
+            for model in ("private", "proxy") for k in sorted(new[model])}
 
 
 def norm_gap(run, ref) -> float:
@@ -73,6 +69,8 @@ def dist_gap(run, ref) -> float:
     off = ~np.eye(ref.shape[0], dtype=bool)
     if run.shape != ref.shape or not np.all(np.isfinite(run[off])):
         return math.inf
+    if not off.any():           # one client: no pair to compare
+        return 0.0
     med = float(np.median(ref[off]))
     return float(np.max(np.abs(run[off] - ref[off])
                         / np.maximum(ref[off], med)))

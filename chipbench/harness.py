@@ -11,6 +11,19 @@ program's model, the reference's model and its correct count; see
 a configuration or a metric adds files and entries; a new kind of model
 adds ``models/<model>.py`` and ``counts/<model>.py`` beside them. No file
 here changes.
+
+What a run holds on the device, whatever the model's size: the program's
+state, its data and what its own steps need; the run's before-states (the
+initial weights, the state before the first chunk) live on the host, and
+the changes are read from them one leaf at a time. After the window the
+reference holds one donated state and one block of ``reference_block``
+clients, each stepping one block of ``reference_example_block`` examples
+at a time (an optional key of the configuration; without it a client's
+whole batch is one block). That bounds the states and the examples, not
+the temporaries of one model's size that a compiled round keeps beside
+them (gradients, noise, layout copies). A federation of one client
+(``clients`` 1) is a valid cell: one group, no pair of distances to
+compare.
 """
 from __future__ import annotations
 
@@ -303,7 +316,9 @@ def first_chunk(cell, seed, keys, data, *, kernels=None, plant=None,
     first co-train chunk with its evaluation: the steps the reference
     follows. Returns the program, its data, the state after the chunk (to
     hand on to the window), what the comparison reads, the groups and the
-    grouping seconds."""
+    grouping seconds. The states the changes are read from are copied to
+    the host, so the device holds one state; ``keep`` keeps both states of
+    the chunk there (``run_out["states"]``)."""
     cfg, mix, kind = cell["cfg"], cell["mix"], cell["kind"]
     M, E, B = mix["clients"], mix["eval_every"], mix["local_batch"]
     nb = cfg["p4"]["bootstrap_rounds"]
@@ -316,23 +331,24 @@ def first_chunk(cell, seed, keys, data, *, kernels=None, plant=None,
         raise RuntimeError(f"program model {shapes} differs from the "
                            f"configuration's {kind.param_shapes(cfg)}")
     state = ref.init_state(kind, cfg, M, keys["init"])
+    init = host_copy(state)
     state, _, _ = p.bootstrap.run_rounds(state, fd, keys["boot"], 0, nb, None)
-    run_out = {"boot_change": compare.change_norms(
-        state, ref.init_state(kind, cfg, M, keys["init"]))}
+    run_out = {"boot_change": compare.change_norms(state, init)}
+    del init
     tg = time.perf_counter()
     with prog_lib.seen_distances(run_out):
         groups = p.trainer.form_groups(state, seed)
     grouping_s = time.perf_counter() - tg
     run_out["groups"] = groups
     p.strategy.set_groups(groups, M)
-    before = jax.tree_util.tree_map(jnp.copy, state)
+    before = host_copy(state)
     state, metrics, _ = p.engine.run_rounds(state, fd, keys["cotrain"], nb,
                                             nb + E, B)
     acc = p.trainer.evaluate(state, fd.test_x, fd.test_y)
     float(jnp.mean(acc))
     run_out["change"] = compare.change_norms(state, before)
     if keep:
-        run_out["states"] = (before, state)
+        run_out["states"] = (before, host_copy(state))
     del before
     run_out["correct"] = kind.run_correct(cfg, acc, data["test_y"])
     run_out["losses"] = np.stack([np.asarray(metrics["private_loss"]),
@@ -379,7 +395,9 @@ def reference_outputs(cell, data, keys, *, dtype, fault, seed,
     """What the reference computes from the seed, in ``dtype``: the
     bootstrap's change, the Phase-1 distances, the first chunk's losses and
     change with ``groups`` (None: the groups it forms itself, acting as the
-    program), and the test predictions it gets right."""
+    program), and the test predictions it gets right. The rounds run on
+    one device buffer; the states the changes are read from, and those
+    ``keep`` keeps, are copied to the host first."""
     cfg, mix, kind = cell["cfg"], cell["mix"], cell["kind"]
     M, E, B = mix["clients"], mix["eval_every"], mix["local_batch"]
     nb, p4 = cfg["p4"]["bootstrap_rounds"], cfg["p4"]
@@ -387,28 +405,39 @@ def reference_outputs(cell, data, keys, *, dtype, fault, seed,
     block = min(cfg["reference_block"], M)
     cast = partial_cast(dtype)
     init = cast(ref.init_state(kind, cfg, M, keys["init"]))
+    init_host = host_copy(init)
     rdata = {k: ref.as_dtype(v, dtype) for k, v in data.items()}
     boot, _ = ref.run_rounds(kind, cfg, hp, {"kind": "full"}, init, rdata,
                              keys["boot"], 0, nb, None, sigma, block=block,
                              fault=fault)
-    out = {"boot_change": compare.change_norms(boot, init),
-           "dist": ref.l1_distances(boot["proxy"])}
     del init
+    out = {"boot_change": compare.change_norms(boot, init_host),
+           "dist": ref.l1_distances(boot["proxy"])}
+    del init_host
     if groups is None:
         groups = ref.greedy_groups(out["dist"], p4["group_size"],
                                    p4["sample_peers"], seed)
     out["groups"] = groups
+    boot_host = host_copy(boot)
     after, losses = ref.run_rounds(kind, cfg, hp, mix["schedule"], boot,
                                    rdata, keys["cotrain"], nb, nb + E, B,
                                    sigma, groups=groups, block=block,
                                    fault=fault)
-    out["change"] = compare.change_norms(after, boot)
+    del boot
+    out["change"] = compare.change_norms(after, boot_host)
     out["losses"] = losses
     out["correct"] = kind.correct_counts(cfg, after["private"],
                                          rdata["test_x"], rdata["test_y"])
     if keep:
-        out["states"] = (boot, after)
+        out["states"] = (boot_host, host_copy(after))
     return out
+
+
+def host_copy(tree):
+    """``tree`` copied to host memory: a copy, never a view of a device
+    buffer (on the CPU backend ``device_get`` may return one), since the
+    donated rounds that follow overwrite the device buffers in place."""
+    return jax.tree_util.tree_map(lambda t: np.array(t, copy=True), tree)
 
 
 def partial_cast(dtype):

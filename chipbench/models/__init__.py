@@ -21,4 +21,18 @@ build and the plain reference need to know of one kind of model:
 * ``shrink(cfg, mix)``: the model's sizes in a CPU test run.
 
 A kind imports nothing of the program under test.
+
+What the harness holds for a kind, whatever its size: one state on the
+device (the program's, then the reference's, donated from round to round;
+the states a run's changes are read from live on the host), and in the
+reference one block of ``reference_block`` clients stepping one block of
+``reference_example_block`` examples at a time (an optional key of the
+configuration; without it a client's batch is one block), besides the
+temporaries of one model's size that a compiled round keeps (gradients,
+noise, layout copies). ``mutual_loss`` is therefore a mean over
+examples, so that block means weighted by block size give the batch mean.
+``apply`` may save memory inside itself, for example by checkpointing
+attention in query blocks: that is the kind's business. A federation of
+one client (``clients`` 1) is valid: it forms one group and has no pair of
+distances to compare.
 """

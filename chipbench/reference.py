@@ -23,6 +23,17 @@ of the run's inputs, not of its arithmetic:
 
 ``dtype=jnp.bfloat16`` runs the whole reference in bfloat16 (parameters,
 data, activations, updates): the lower-precision control.
+
+Memory: a round holds one state and one block of examples. ``_round``
+takes its state donated and writes each block of ``reference_block``
+clients back into that buffer; within a client step the per-example work
+(the clipped per-example sum, the private gradient and the losses) runs in
+blocks of the configuration's ``reference_example_block`` examples, each
+sum gathered in float32 accumulators of one model's size. Without that key
+the batch is one block: the (n, D) stack of per-example gradients, with
+the arithmetic of the unblocked reference. Besides the state and the
+block, a round keeps temporaries of one model's size (the gradients, the
+flat noise, XLA's layout copies): the contract bounds the state, not them.
 """
 from __future__ import annotations
 
@@ -92,6 +103,29 @@ def _unflat(vec, like):
     return out
 
 
+def _in_blocks(f, x, y, b, weigh):
+    """``f`` over the batch (x, y) in blocks of ``b`` examples. Where one
+    block holds the batch, ``f(x, y)`` itself; else the sum of the blocks'
+    values in float32, each weighted by b / n where ``weigh`` (block means
+    of a batch mean), cast back to ``f``'s dtypes."""
+    n = x.shape[0]
+    if b >= n:
+        return f(x, y)
+    if n % b:
+        raise ValueError(f"reference_example_block {b} does not divide the "
+                         f"batch of {n} examples")
+    xs, ys = (t.reshape((n // b, b) + t.shape[1:]) for t in (x, y))
+    like = jax.eval_shape(f, xs[0], ys[0])
+    w = b / n if weigh else 1.0
+
+    def add(acc, xy):
+        return jax.tree_util.tree_map(
+            lambda a, v: a + v.astype(jnp.float32) * w, acc, f(*xy)), None
+    acc, _ = jax.lax.scan(add, jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, jnp.float32), like), (xs, ys))
+    return jax.tree_util.tree_map(lambda a, s: a.astype(s.dtype), acc, like)
+
+
 def _client_steps(kind, cfg, hp, private, proxy, x, y, ckey, sigma, prec,
                   half_batch=False):
     """K local steps of one client, then its losses at the updated models.
@@ -103,28 +137,52 @@ def _client_steps(kind, cfg, hp, private, proxy, x, y, ckey, sigma, prec,
     if half_batch:
         x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
     n = x.shape[0]
+    b = cfg.get("reference_example_block", n)
     noise_scale = (jnp.float32(2.0 * clip / n) * jnp.float32(sigma))
 
     def step(carry, k):
         pr, px = carry
-        px_logits = apply(cfg, px, x, prec)
-        g_pr = jax.grad(lambda th: mutual_loss(apply(cfg, th, x, prec),
-                                               px_logits, y, hp["beta"]))(pr)
 
-        def one(xi, yi):
+        def private_grad(xb, yb):
+            px_logits = apply(cfg, px, xb, prec)
+            return jax.grad(lambda th: mutual_loss(
+                apply(cfg, th, xb, prec), px_logits, yb, hp["beta"]))(pr)
+
+        def clipped(xi, yi):
+            """One example's proxy gradient and its clip scale (Eq. 10)."""
             tgt = apply(cfg, pr, xi[None], prec)
             g = jax.grad(lambda w: mutual_loss(apply(cfg, w, xi[None], prec),
                                                tgt, yi[None],
                                                hp["alpha"]))(px)
             norm = jnp.sqrt(sum(jnp.sum(jnp.square(v)) for v in g.values()))
             scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
-            return _flat(g) * scale.astype(dt)
+            return g, scale.astype(dt)
 
-        per_ex = jax.vmap(one)(x, y)                       # (n, D)
-        mean = jnp.sum(per_ex, axis=0) / jnp.asarray(n, dt)
-        noise = jax.random.normal(jax.random.fold_in(ckey, k), mean.shape,
+        def clipped_sum(xb, yb):
+            g, scale = jax.vmap(clipped)(xb, yb)
+            return jax.tree_util.tree_map(lambda t: jnp.sum(
+                t * scale.reshape((-1,) + (1,) * (t.ndim - 1)), axis=0), g)
+
+        g_pr = _in_blocks(private_grad, x, y, b, weigh=True)
+        noise = jax.random.normal(jax.random.fold_in(ckey, k),
+                                  (sum(v.size for v in px.values()),),
                                   jnp.float32)
-        g_px = _unflat(mean + (noise_scale * noise).astype(dt), px)
+        if b >= n:
+            # The batch in one block: the flat (n, D) stack, summed at once,
+            # as the unblocked reference did (a sum per leaf of the same
+            # values rounds differently on the CPU).
+            def one(xi, yi):
+                g, scale = clipped(xi, yi)
+                return _flat(g) * scale
+
+            per_ex = jax.vmap(one)(x, y)
+            mean = jnp.sum(per_ex, axis=0) / jnp.asarray(n, dt)
+            g_px = _unflat(mean + (noise_scale * noise).astype(dt), px)
+        else:
+            total = _in_blocks(clipped_sum, x, y, b, weigh=False)
+            g_px = jax.tree_util.tree_map(
+                lambda t, z: t / jnp.asarray(n, dt)
+                + (noise_scale * z).astype(dt), total, _unflat(noise, px))
         pr = jax.tree_util.tree_map(lambda p, g: p - (lr * g).astype(dt),
                                     pr, g_pr)
         px = jax.tree_util.tree_map(lambda p, g: p - (lr * g).astype(dt),
@@ -133,11 +191,15 @@ def _client_steps(kind, cfg, hp, private, proxy, x, y, ckey, sigma, prec,
 
     (private, proxy), _ = jax.lax.scan(step, (private, proxy),
                                        jnp.arange(hp["local_steps"]))
-    pr_logits = apply(cfg, private, x, prec)
-    px_logits = apply(cfg, proxy, x, prec)
-    losses = jnp.stack([mutual_loss(pr_logits, px_logits, y, hp["beta"]),
-                        mutual_loss(px_logits, pr_logits, y, hp["alpha"])])
-    return private, proxy, losses.astype(jnp.float32)
+
+    def losses(xb, yb):
+        pr_logits = apply(cfg, private, xb, prec)
+        px_logits = apply(cfg, proxy, xb, prec)
+        return jnp.stack([mutual_loss(pr_logits, px_logits, yb, hp["beta"]),
+                          mutual_loss(px_logits, pr_logits, yb,
+                                      hp["alpha"])])
+    return private, proxy, _in_blocks(losses, x, y, b,
+                                      weigh=True).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------- one round
@@ -176,9 +238,12 @@ def _rows_at(t, idx):
                                axis=1)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
+@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6), donate_argnums=(7,))
 def _round(kind, cfg_json, hp_items, schedule_items, batch, block, fault,
            state, train_x, train_y, phase_key, r, sigma, ids, num_groups_arr):
+    """One round on ``state``, donated: each block of ``block`` clients is
+    read from it and its new rows written back in place (a client outside
+    the cohort keeps its rows), then the group mean of the proxies."""
     cfg, hp, schedule = (json.loads(cfg_json), dict(hp_items),
                          dict(schedule_items))
     dt = jax.tree_util.tree_leaves(state)[0].dtype
@@ -190,42 +255,40 @@ def _round(kind, cfg_json, hp_items, schedule_items, batch, block, fault,
     else:
         idx = jnp.broadcast_to(jnp.arange(R), (M, R))
     keys = jax.random.split(jax.random.fold_in(rk, 1), M)
+    mask = _cohort(schedule, jax.random.fold_in(rk, 3), M)
 
-    def one_block(i):
+    def one_block(state, i):
         def rows(t):
             return jax.lax.dynamic_slice_in_dim(t, i * block, block)
+
+        def put(t, new):
+            keep = rows(mask).reshape((-1,) + (1,) * (new.ndim - 1))
+            return jax.lax.dynamic_update_slice_in_dim(
+                t, jnp.where(keep > 0, new, rows(t)), i * block, 0)
         pr, px = (jax.tree_util.tree_map(rows, state[m])
                   for m in ("private", "proxy"))
         ib, kb = rows(idx), rows(keys)
         xs = as_dtype(_rows_at(rows(train_x), ib), dt)
         ys = _rows_at(rows(train_y), ib)
-        return jax.vmap(lambda p, q, x, y, k: _client_steps(
+        pr, px, lb = jax.vmap(lambda p, q, x, y, k: _client_steps(
             kind, cfg, hp, p, q, x, y, k, sigma, prec,
             half_batch=(fault == "half_batch")))(pr, px, xs, ys, kb)
+        return {m: jax.tree_util.tree_map(put, state[m], new)
+                for m, new in (("private", pr), ("proxy", px))}, lb
 
-    pr, px, losses = jax.lax.map(one_block, jnp.arange(M // block))
-    unblock = partial(jax.tree_util.tree_map,
-                      lambda t: t.reshape((M,) + t.shape[2:]))
-    new = {"private": unblock(pr), "proxy": unblock(px)}
+    state, losses = jax.lax.scan(one_block, state, jnp.arange(M // block))
     losses = losses.reshape(M, 2)
-    mask = _cohort(schedule, jax.random.fold_in(rk, 3), M)
-
-    def merge(a, b):
-        return jax.tree_util.tree_map(
-            lambda n, o: jnp.where(mask.reshape((-1,) + (1,) * (n.ndim - 1))
-                                   > 0, n, o), a, b)
-    new = merge(new, state)
     if ids is not None:
-        new = {"private": new["private"],
-               "proxy": _group_mean(new["proxy"], ids,
-                                    num_groups_arr.shape[0], mask)}
-        new = merge(new, state)
-    return new, jnp.mean(losses, axis=0)
+        state = {"private": state["private"],
+                 "proxy": _group_mean(state["proxy"], ids,
+                                      num_groups_arr.shape[0], mask)}
+    return state, jnp.mean(losses, axis=0)
 
 
 def run_rounds(kind, cfg, hp, schedule, state, data, phase_key, start, stop,
                batch, sigma, groups=None, block=8, fault=None):
-    """Rounds [start, stop) of the reference. Returns (state, losses) with
+    """Rounds [start, stop) of the reference on one buffer: ``state`` is
+    donated (the caller's arrays are consumed). Returns (state, losses) with
     losses (rounds, 2): the mean private and proxy loss of each round."""
     ids = G = None
     if groups is not None:
@@ -262,7 +325,7 @@ def _known_peers(M, H, seed):
     rng = np.random.default_rng(seed)
     H = min(H, M - 1)
     known = np.zeros((M, M), bool)
-    for i in range(M):
+    for i in range(M if H > 0 else 0):
         cands = [j for j in range(M) if j != i]
         known[i, rng.choice(cands, H, replace=False)] = True
     return known | known.T, rng
@@ -273,7 +336,8 @@ def greedy_groups(dist, group_size, sample_peers, seed):
     each client sees H sampled peers (symmetric), mutual nearest pairs form
     first, every other client pairs with its nearest ungrouped peer, an odd
     leftover joins a random pair, then groups merge with their nearest
-    partner group (closest member pair) while they fit in ``group_size``."""
+    partner group (closest member pair) while they fit in ``group_size``.
+    A federation of one client forms one group, ``[[0]]``."""
     M = dist.shape[0]
     known, rng = _known_peers(M, sample_peers, seed)
     masked = np.where(known, dist, np.inf)
@@ -298,6 +362,9 @@ def greedy_groups(dist, group_size, sample_peers, seed):
         out.append([i, j])
         ungrouped -= {i, j}
     for i in sorted(ungrouped):
+        if not out:             # M = 1: one client forms one group
+            out.append([i])
+            continue
         out[rng.integers(len(out))].append(i)
 
     def gdist(a, b):
