@@ -13,10 +13,12 @@
   (compiled Pallas on TPU, jnp reference on CPU, tile autotuning) as a fused
   pipeline that reads the (B, D) per-example matrix at most twice and draws
   the Eq. 11 noise once on the flat (D,) buffer.
-* ``dp_affine_gradients`` — the same clipped + noised gradient for a model
+* ``dp_affine_flat`` — the same clipped + noised gradient for a model
   whose logits are an affine map of its input (z = x·w + b), in closed form
   from the per-example logit gradients: no per-example parameter gradient
-  is built. The ``dp.path`` probe counts which route each trace took.
+  is built. It is returned flat, the layout ``P4Trainer``'s stacked affine
+  step updates the proxy in. The ``dp.path`` probe counts which route each
+  trace took.
 """
 from __future__ import annotations
 
@@ -166,7 +168,7 @@ def calibrate_sigma(target_eps: float, delta: float, q: float, steps: int,
 
 #: Which DP route each trace took: one count per traced call of a route.
 DP_PATH = Probe("dp.path", {"affine_closed_form": 0, "per_example": 0,
-                            "microbatch": 0})
+                            "microbatch": 0, "affine_stacked": 0})
 
 
 def _per_example_grad_fn(loss_fn: Callable):
@@ -259,18 +261,20 @@ def dp_gradients(loss_fn: Callable, params, batch, key, *, clip: float,
     return add_noise(clipped_mean, key, sigma, clip, float(k))
 
 
-def dp_affine_gradients(params, x, dl, key, *, clip: float, sigma: float):
+def dp_affine_flat(x, dl, key, *, clip: float, sigma: float):
     """``dp_gradients``' per-example result for an affine model z = x·w + b
-    (params ``{"w": (F, C), "b": (C,)}``), from ``dl`` (B, C), each
-    example's loss gradient with respect to its own logits.
+    (params ``{"w": (F, C), "b": (C,)}``) on the batch ``x`` (B, F), from
+    ``dl`` (B, C), each example's loss gradient with respect to its own
+    logits; returned flat in the [b, w.ravel()] layout of
+    ``tree_flatten_concat``.
 
     Example i's gradient is (dlᵢ, xᵢ ⊗ dlᵢ), so its squared norm is
     ‖dlᵢ‖²·(1 + ‖xᵢ‖²) and the clipped mean is (Σ sᵢ·dlᵢ, xᵀ(s ⊙ dl)): one
     pass over x for the norms and one f32 contraction, instead of a (B, D)
     per-example stack read twice. The contraction runs at HIGHEST precision,
     as the stack's exact f32 outer products do. The noise is the same
-    ``add_flat_noise`` draw on the same flat [b, w.ravel()] layout, so the
-    same key gives bit-identical noise on both routes."""
+    ``add_flat_noise`` draw on the same flat layout, so the same key gives
+    bit-identical noise on both routes."""
     DP_PATH["affine_closed_form"] += 1
     from repro.kernels.dp_clip.ref import add_flat_noise
     n = x.shape[0]
@@ -289,6 +293,5 @@ def dp_affine_gradients(params, x, dl, key, *, clip: float, sigma: float):
                 "w": jnp.einsum("bf,bc->fc", x32, dl * scales[:, None],
                                 precision=hi)}
     with layer("dp_noise"):
-        out = add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
-                             float(n))
-    return tree_unflatten_concat(out, params)
+        return add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
+                              float(n))

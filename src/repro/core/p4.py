@@ -31,6 +31,7 @@ from repro.engine import (Engine, FederatedData, PrivacyLedger, ShardedEngine,
                           runtime_sigma)
 from repro.models.module import init_params
 from repro.obs import layer, span
+from repro.utils.pytree import tree_flatten_concat, tree_unflatten_concat
 
 
 def group_mean(stacked_tree, ids: jnp.ndarray, num_groups: int):
@@ -100,8 +101,40 @@ class P4Trainer:
         return {"private": bcast(k1), "proxy": bcast(k2)}
 
     # ------------------------------------------------------------------
+    def _affine_route(self) -> bool:
+        """True where the step takes the stacked affine route: per-example
+        DP (no microbatches) on a model whose logits are affine in x."""
+        dpc = self.cfg.dp
+        return (dpc.enabled and not dpc.microbatches
+                and self.apply_fn is linear_apply)
+
+    def _losses(self, private_logits, proxy_logits, y):
+        """The round's metrics: Eqs. 9 and 8 on the given logits."""
+        p4c = self.cfg.p4
+        return {
+            "private_loss": distill.private_loss(private_logits, proxy_logits,
+                                                 y, p4c.beta),
+            "proxy_loss": distill.proxy_loss(proxy_logits, private_logits, y,
+                                             p4c.alpha),
+        }
+
+    def _both_logits(self, private, proxy, x):
+        """Both affine models' logits from one read of x:
+        x·[w_priv | w_prox] + [b_priv | b_prox], split by columns. Formed
+        class-major: in the (B, 2C) form the CPU backend fuses the bias add
+        differently in a one-client and an eight-client program, and the
+        sharded engine's states drift from the single-device engine's by
+        more float ulps."""
+        C = self.num_classes
+        w = jnp.concatenate([private["w"], proxy["w"]], axis=1)
+        z = (jnp.einsum("bf,fc->cb", x, w.astype(jnp.float32))
+             + jnp.concatenate([private["b"], proxy["b"]])[:, None])
+        return z[:C].T, z[C:].T
+
     def _client_step(self, private, proxy, x, y, key, lr):
         """One local step for ONE client (vmapped across M)."""
+        if self._affine_route():
+            return self._affine_step(private, proxy, x, y, key, lr)
         p4c, dpc = self.cfg.p4, self.cfg.dp
 
         private_logits = self.apply_fn(private, x)
@@ -122,21 +155,7 @@ class P4Trainer:
             return distill.proxy_loss(lg, tgt, batch["y"], p4c.alpha,
                                       p4c.distill_temperature)
         with layer("proxy_dp_grad"):
-            if (dpc.enabled and not dpc.microbatches
-                    and self.apply_fn is linear_apply):
-                # affine logits: the per-example clip needs only each
-                # example's logit gradient (autodiff of its own loss, on the
-                # logits already computed above)
-                def one_loss(z, t, label):
-                    return distill.proxy_loss(z[None], t[None], label[None],
-                                              p4c.alpha,
-                                              p4c.distill_temperature)
-                dl = jax.vmap(jax.grad(one_loss))(
-                    proxy_logits, jax.lax.stop_gradient(private_logits), y)
-                g_prox = dp_lib.dp_affine_gradients(
-                    proxy, x, dl, key, clip=dpc.clip_norm,
-                    sigma=runtime_sigma(self.sigma))
-            elif dpc.enabled:
+            if dpc.enabled:
                 g_prox = dp_lib.dp_gradients(
                     proxy_obj, proxy, {"x": x, "y": y}, key,
                     clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
@@ -149,21 +168,57 @@ class P4Trainer:
 
         new_private = jax.tree_util.tree_map(lambda p, g: p - lr * g, private, g_priv)
         new_proxy = jax.tree_util.tree_map(lambda p, g: p - lr * g, proxy, g_prox)
-        metrics = {
-            "private_loss": distill.private_loss(private_logits, proxy_logits, y,
-                                                 p4c.beta),
-            "proxy_loss": distill.proxy_loss(proxy_logits, private_logits, y,
-                                             p4c.alpha),
-        }
-        return new_private, new_proxy, metrics
+        return (new_private, new_proxy,
+                self._losses(private_logits, proxy_logits, y))
+
+    def _affine_step(self, private, proxy, x, y, key, lr):
+        """``_client_step`` for affine logits z = x·w + b under per-example
+        DP, with four passes over the client batch x: one forward of both
+        models (``_both_logits``); the private gradient in closed form,
+        (Σ dl_priv, xᵀ dl_priv), from its logit gradient dl_priv, as one f32
+        contraction at HIGHEST precision; the proxy's ‖x‖² and clipped
+        contraction (``dp_lib.dp_affine_flat``). Exact for an affine model.
+        The proxy is updated in the flat [b, w.ravel()] layout of its noise,
+        the same element-wise p - lr·g; in that layout the chunk's memory
+        peak is no higher than the per-tree update's."""
+        p4c, dpc = self.cfg.p4, self.cfg.dp
+        dp_lib.DP_PATH["affine_stacked"] += 1
+        temp = p4c.distill_temperature
+        private_logits, proxy_logits = self._both_logits(private, proxy, x)
+
+        with layer("private_grad"):
+            dl_priv = jax.grad(distill.private_loss)(
+                private_logits, proxy_logits, y, p4c.beta, temp)
+            g_priv = {"b": jnp.sum(dl_priv, axis=0),
+                      "w": jnp.einsum("bf,bc->fc", x.astype(jnp.float32),
+                                      dl_priv,
+                                      precision=jax.lax.Precision.HIGHEST)}
+
+        def one_loss(z, t, label):
+            return distill.proxy_loss(z[None], t[None], label[None],
+                                      p4c.alpha, temp)
+        with layer("proxy_dp_grad"):
+            # each example's logit gradient of its own loss (Eq. 8)
+            dl_prox = jax.vmap(jax.grad(one_loss))(
+                proxy_logits, jax.lax.stop_gradient(private_logits), y)
+            g_prox = dp_lib.dp_affine_flat(x, dl_prox, key,
+                                           clip=dpc.clip_norm,
+                                           sigma=runtime_sigma(self.sigma))
+
+        new_private = jax.tree_util.tree_map(lambda p, g: p - lr * g, private, g_priv)
+        new_proxy = tree_unflatten_concat(
+            tree_flatten_concat(proxy) - lr * g_prox, proxy)
+        return (new_private, new_proxy,
+                self._losses(private_logits, proxy_logits, y))
 
     # ------------------------------------------------------------------
     def _local_round_keyed(self, states, xs, ys, keys):
         """K local steps, one PRNG key per client row (the seam the sharded
         engine drives with the global key split's shard slice). Returns
-        per-client metric vectors."""
+        per-client metric vectors: both losses at the updated models."""
         lr = self.cfg.train.learning_rate
         K = self.cfg.dp.local_steps
+        affine = self._affine_route()
 
         def one_client(private, proxy, x, y, ckey):
             def body(carry, k):
@@ -172,11 +227,15 @@ class P4Trainer:
                                               jax.random.fold_in(ckey, k), lr)
                 return (pr, px), None
             (pr, px), _ = jax.lax.scan(body, (private, proxy), jnp.arange(K))
-            # the round's metrics: one more step at lr = 0 (XLA drops its
-            # unused gradients; the forwards remain)
             with layer("metrics_step"):
-                _, _, metrics = self._client_step(
-                    pr, px, x, y, jax.random.fold_in(ckey, K), 0.0)
+                if affine:
+                    # one forward of both updated models
+                    metrics = self._losses(*self._both_logits(pr, px, x), y)
+                else:
+                    # one more step at lr = 0 (XLA drops its unused
+                    # gradients; the forwards remain)
+                    _, _, metrics = self._client_step(
+                        pr, px, x, y, jax.random.fold_in(ckey, K), 0.0)
             return pr, px, metrics
 
         priv, prox, metrics = jax.vmap(one_client)(

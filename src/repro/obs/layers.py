@@ -14,15 +14,21 @@ layer                  entered around
 ``local_update``       the strategy's local update (``Scoped`` in
                        ``engine.schedule``: every round body)
 ``private_grad``       the private model's gradient in
-                       ``P4Trainer._client_step``
+                       ``P4Trainer._client_step``; on the stacked affine
+                       step (``P4Trainer._affine_step``) its logit gradient
+                       dl_priv and the closed form (Σ dl_priv, xᵀ dl_priv),
+                       with no forward of its own
 ``proxy_dp_grad``      the proxy model's DP gradient in ``_client_step``
 ``metrics_step``       the final ``lr = 0`` ``_client_step`` of
-                       ``P4Trainer._local_round_keyed``
+                       ``P4Trainer._local_round_keyed``; on the stacked
+                       affine step, the one end-of-step forward of both
+                       updated models
 ``per_example_grads``  the per-example ``vmap`` of the gradient and its
                        flatten to the (c, D) stack in ``core.dp.dp_gradients``;
-                       the per-row ‖x‖² in ``core.dp.dp_affine_gradients``
+                       the per-row ‖x‖² in ``core.dp.dp_affine_flat``
 ``dp_clip``            ``kernels.dispatch.clip_accumulate`` / ``dp_clip``;
-                       the closed form's norms, scales and contraction
+                       the closed form's norms, scales and the proxy's
+                       contraction xᵀ(s ⊙ dl)
 ``dp_noise``           the flat Eq. 11 noise draw and add
 ``aggregate``          the strategy's aggregation with the
                        ``merge_participation`` calls around it

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import dp as dp_lib
-from repro.utils.pytree import global_norm
+from repro.utils.pytree import global_norm, tree_unflatten_concat
 
 
 def test_clip_bounds_norm(key):
@@ -155,8 +155,8 @@ def test_affine_closed_form_matches_per_example(key, alpha, temperature,
         pe = dp_lib.dp_gradients(proxy_obj, proxy, {"x": x, "y": y}, k,
                                  clip=clip, sigma=sigma,
                                  per_example_chunk=chunk)
-        cf = dp_lib.dp_affine_gradients(proxy, x, dl, k, clip=clip,
-                                        sigma=sigma)
+        cf = tree_unflatten_concat(
+            dp_lib.dp_affine_flat(x, dl, k, clip=clip, sigma=sigma), proxy)
         return pe, cf
 
     pe0, cf0 = both(0.0)

@@ -190,8 +190,10 @@ def test_chunk_span_reports_the_chunks_compiles(tmp_path):
     assert len(chunk) == 1 and chunk[0]["traced"] is True
     assert chunk[0]["compiles"] >= 1 and chunk[0]["compile_s"] > 0
     assert chunk[0]["compiled"] == "jit(run)"
-    # the linear proxy's DP step traced the closed form and no other route
-    assert set(chunk[0]["dp_path"]) == {"affine_closed_form"}
+    # the linear step traced the stacked affine step, whose proxy takes the
+    # closed form, and no other route
+    assert set(chunk[0]["dp_path"]) == {"affine_closed_form",
+                                        "affine_stacked"}
 
 
 # ------------------------------------------------------------------ spans

@@ -136,29 +136,63 @@ def test_dp_route_follows_model_structure(key, model):
         assert routes["per_example"] > 0 and routes["affine_closed_form"] == 0
 
 
+@pytest.mark.parametrize("local_steps", [1, 2])
 @pytest.mark.parametrize("chunk", [0, 8])
-def test_linear_closed_form_step_matches_per_example(key, chunk):
-    """Two DP co-training rounds of the linear trainer on the closed form
-    agree with the same rounds on the per-example route (the same model
-    behind a wrapper the route test does not recognise as affine)."""
+def test_linear_closed_form_step_matches_per_example(key, chunk, local_steps):
+    """Two DP co-training rounds of the linear trainer on the stacked affine
+    step agree with the same rounds on the per-example route (the same model
+    behind a wrapper the route test does not recognise as affine): both
+    models' states, and the round's losses, which on both routes are Eqs. 9
+    and 8 at the updated models."""
+    from repro.core import distill
     from repro.core.small_models import linear_apply
+    from repro.obs import probe_deltas
     xs, ys = _toy_tasks(M=4)
     cfg = _run_cfg(dp=DPConfig(epsilon=15.0, rounds=40, sample_rate=0.5,
-                               clip_norm=1.0, per_example_chunk=chunk))
+                               clip_norm=1.0, per_example_chunk=chunk,
+                               local_steps=local_steps))
     closed = P4Trainer(feat_dim=20, num_classes=4, cfg=cfg)
     per_ex = P4Trainer(feat_dim=20, num_classes=4, cfg=cfg)
     per_ex.apply_fn = lambda p, x: linear_apply(p, x)
     xb, yb = jnp.asarray(xs[:, :32]), jnp.asarray(ys[:, :32])
     s_cf = s_pe = closed.init_clients(key, 4)
-    for r in range(2):
-        k = jax.random.fold_in(key, r)
-        s_cf, m_cf = closed.local_round(s_cf, xb, yb, k)
-        s_pe, m_pe = per_ex.local_round(s_pe, xb, yb, k)
+    with probe_deltas("dp.path") as d_cf:
+        for r in range(2):
+            s_cf, m_cf = closed.local_round(s_cf, xb, yb,
+                                            jax.random.fold_in(key, r))
+    with probe_deltas("dp.path") as d_pe:
+        for r in range(2):
+            s_pe, m_pe = per_ex.local_round(s_pe, xb, yb,
+                                            jax.random.fold_in(key, r))
+    assert d_cf["dp.path"]["affine_stacked"] > 0
+    assert d_cf["dp.path"]["affine_closed_form"] > 0
+    assert d_cf["dp.path"]["per_example"] == 0
+    assert d_pe["dp.path"]["affine_stacked"] == 0
+    assert d_pe["dp.path"]["affine_closed_form"] == 0
+    assert d_pe["dp.path"]["per_example"] > 0
     for a, b in zip(jax.tree_util.tree_leaves((s_cf, m_cf)),
                     jax.tree_util.tree_leaves((s_pe, m_pe))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-6)
-    start = closed.init_clients(key, 4)["proxy"]
-    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
-                                   s_cf["proxy"], start)
-    assert min(jax.tree_util.tree_leaves(moved)) > 1e-3
+    # the round's losses are those of the models it returns
+    p4c = cfg.p4
+    for c in range(4):
+        priv = jax.tree_util.tree_map(lambda t: t[c], s_cf["private"])
+        prox = jax.tree_util.tree_map(lambda t: t[c], s_cf["proxy"])
+        zp, zw = linear_apply(priv, xb[c]), linear_apply(prox, xb[c])
+        np.testing.assert_allclose(
+            float(m_cf["private_loss"][c]),
+            float(distill.private_loss(zp, zw, yb[c], p4c.beta)),
+            rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(
+            float(m_cf["proxy_loss"][c]),
+            float(distill.proxy_loss(zw, zp, yb[c], p4c.alpha)),
+            rtol=1e-5, atol=1e-6)
+    # both models moved: the private gradient and the proxy's DP gradient
+    # took effect
+    start = closed.init_clients(key, 4)
+    for name in ("private", "proxy"):
+        moved = jax.tree_util.tree_map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), s_cf[name],
+            start[name])
+        assert min(jax.tree_util.tree_leaves(moved)) > 1e-3
