@@ -34,6 +34,8 @@ from repro.core import dp as dp_lib
 from repro.engine import (Engine, FederatedData, FullParticipation,
                           PrivacyLedger, Strategy, register_strategy,
                           runtime_sigma, sample_client_batches)
+from repro.engine.schedule import ResidentCtx
+from repro.engine.strategy import current_halos
 
 
 @register_strategy("dp_dsgt")
@@ -74,10 +76,6 @@ class DPDSGTStrategy(Strategy):
                                       sigma=runtime_sigma(self.sigma))
         return jax.vmap(one)(params, xs, ys, keys)
 
-    def _grads(self, params, xs, ys, key):
-        M = ys.shape[0]
-        return self._grads_keyed(params, xs, ys, jax.random.split(key, M))
-
     @property
     def _push_sum(self) -> bool:
         return bool(self._mix_plan is not None and self._mix_plan.push_sum)
@@ -104,98 +102,53 @@ class DPDSGTStrategy(Strategy):
         x_params = common.init_clients(self.specs, k1, data.num_clients)
         xs0, ys0 = sample_client_batches(data.train_x, data.train_y, k2,
                                          batch_size)
-        y_track = self._grads(x_params, xs0, ys0, k3)
+        y_track = self._grads_keyed(x_params, xs0, ys0,
+                                    jax.random.split(k3, data.num_clients))
         # distinct buffers: the engine donates the carry, and XLA rejects the
         # same buffer appearing twice in a donated argument
         state = {"x": x_params, "y": y_track,
                  "g": jax.tree_util.tree_map(jnp.copy, y_track)}
         return self.align_push_sum_state(state)
 
-    def local_update(self, state, xs, ys, r, key):
-        # one communication round = one realized graph: both mixes share the
-        # round's fault realization (drawn in-jit off key's fault stream).
-        # Under a push-sum plan (directed/learned W) the weight scalar rides
-        # the x mix as a joint leaf (gradient-push): gradients are taken at
-        # the de-biased z = x/w, and the tracker mixes with the same matrix.
+    def _gossip_round(self, state, xs, ys, r, key, ctx):
+        """One communication round in the layout ``ctx``: one realized graph,
+        so both mixes share the round's fault realization (drawn in-jit off
+        key's fault stream). Under a push-sum plan (directed/learned W) the
+        weight scalar rides the x mix as a joint leaf (gradient-push):
+        gradients are taken at the de-biased z = x/w, and the tracker mixes
+        with the same matrix. Gradients are per client, keyed by the layout's
+        rows of the global key split. On a client shard, the mixes consume
+        the boundary rows ``sharded_prefetch`` exchanged at the end of the
+        previous round — valid because both read the round-START x and y."""
+        from repro.topology.mixing import push_sum_debias
+        halos = current_halos() or {}
         if self._push_sum:
-            from repro.topology.mixing import push_sum_debias
-            mixed = self.mix({"x": state["x"], "w": state["w"]}, r, key)
+            mixed = ctx.mix(self, {"x": state["x"], "w": state["w"]}, r, key,
+                            halos.get("xw"))
             x_new, w_new = mixed["x"], mixed["w"]
         else:
-            x_new = self.mix(state["x"], r, key)
+            x_new = ctx.mix(self, state["x"], r, key, halos.get("x"))
         x_new = jax.tree_util.tree_map(lambda x, y: x - self.lr * y,
                                        x_new, state["y"])
         z = push_sum_debias(x_new, w_new) if self._push_sum else x_new
-        g_new = self._grads(z, xs, ys, key)
-        y_new = self.mix(state["y"], r, key)
+        g_new = self._grads_keyed(z, xs, ys, ctx.client_keys(key))
+        y_new = ctx.mix(self, state["y"], r, key, halos.get("y"))
         y_new = jax.tree_util.tree_map(lambda y, a, b: y + a - b,
                                        y_new, g_new, state["g"])
         out = {"x": x_new, "y": y_new, "g": g_new}
         if self._push_sum:
             out["w"] = w_new
         return out, {}
+
+    def local_update(self, state, xs, ys, r, key):
+        return self._gossip_round(state, xs, ys, r, key,
+                                  ResidentCtx(ys.shape[0]))
 
     def sharded_local_update(self, state, xs, ys, r, key, ctx):
-        """The gossip crosses client-shard boundaries, so it runs as a
-        ppermute halo exchange of just the boundary rows (bounded-bandwidth
-        graphs), a slice-local gather (shard-resident edges) or a gather
-        round-trip (anything else); gradients are per-client with the global
-        key split's shard slice. Same mixing arithmetic on the same neighbor
-        values as ``local_update`` — see ``repro.topology.mixing``. When the
-        engine carried prefetched halos (``sharded_prefetch``), both mixes
-        consume boundary rows whose ppermute was issued at the end of the
-        previous round, overlapping the transfer with that round's compute —
-        valid because both mixes read the round-START x and y, which is
-        exactly what was prefetched."""
-        from repro.engine.strategy import current_halos
-        halos = current_halos()
-        if self._push_sum:
-            from repro.topology.mixing import push_sum_debias
-            mixed = self.mix_sharded(
-                {"x": state["x"], "w": state["w"]}, r, key, ctx,
-                halo=None if halos is None else halos["xw"])
-            x_new, w_new = mixed["x"], mixed["w"]
-        else:
-            x_new = self.mix_sharded(
-                state["x"], r, key, ctx,
-                halo=None if halos is None else halos["x"])
-        x_new = jax.tree_util.tree_map(lambda x, y: x - self.lr * y,
-                                       x_new, state["y"])
-        z = push_sum_debias(x_new, w_new) if self._push_sum else x_new
-        g_new = self._grads_keyed(z, xs, ys, ctx.shard_keys(key))
-        y_new = self.mix_sharded(state["y"], r, key, ctx,
-                                 halo=None if halos is None else halos["y"])
-        y_new = jax.tree_util.tree_map(lambda y, a, b: y + a - b,
-                                       y_new, g_new, state["g"])
-        out = {"x": x_new, "y": y_new, "g": g_new}
-        if self._push_sum:
-            out["w"] = w_new
-        return out, {}
+        return self._gossip_round(state, xs, ys, r, key, ctx)
 
     def paged_local_update(self, state, xs, ys, r, key, pctx):
-        """Cohort-paged gossip round: the same call sequence as
-        ``local_update`` with the mixes resolving neighbor reads through the
-        cohort slot map (the planner paged in every participant's
-        in-neighbors) and gradients keyed by the global key split's cohort
-        slice — participant rows are bit-identical to the resident step."""
-        if self._push_sum:
-            from repro.topology.mixing import push_sum_debias
-            mixed = self.mix_paged({"x": state["x"], "w": state["w"]}, r,
-                                   key, pctx)
-            x_new, w_new = mixed["x"], mixed["w"]
-        else:
-            x_new = self.mix_paged(state["x"], r, key, pctx)
-        x_new = jax.tree_util.tree_map(lambda x, y: x - self.lr * y,
-                                       x_new, state["y"])
-        z = push_sum_debias(x_new, w_new) if self._push_sum else x_new
-        g_new = self._grads_keyed(z, xs, ys, pctx.cohort_keys(key))
-        y_new = self.mix_paged(state["y"], r, key, pctx)
-        y_new = jax.tree_util.tree_map(lambda y, a, b: y + a - b,
-                                       y_new, g_new, state["g"])
-        out = {"x": x_new, "y": y_new, "g": g_new}
-        if self._push_sum:
-            out["w"] = w_new
-        return out, {}
+        return self._gossip_round(state, xs, ys, r, key, pctx)
 
     def sharded_prefetch(self, state, ctx):
         """Issue the next round's boundary-row ppermutes from the end-of-
@@ -203,7 +156,7 @@ class DPDSGTStrategy(Strategy):
         will need are known as soon as the round's update lands). Only the
         halo path prefetches — local/gather/identity paths have nothing to
         overlap. Under push-sum the x halo carries the weight scalar too
-        (``sharded_local_update`` mixes them jointly)."""
+        (``_gossip_round`` mixes them jointly)."""
         from repro.topology.mixing import select_mix_path, halo_start
         if self._mix_plan is None:
             return None
@@ -228,7 +181,7 @@ class DPDSGTStrategy(Strategy):
         """§4.5-style gossip accounting: every alive directed edge carries
         the sender's BOTH shared quantities — the noised model x̃ and the
         gradient tracker ỹ (one exchange per round mixes both, see
-        ``local_update``). Absent cohort members (sampling schedule) and
+        ``_gossip_round``). Absent cohort members (sampling schedule) and
         dropped links / churned nodes (the round's fault realization,
         re-derived from ``phase_key``) contribute zero bytes. Under a
         correlated fault process (``faults`` — the engine's replayed

@@ -61,11 +61,6 @@ class FedAvgStrategy(Strategy):
 
         return jax.vmap(one)(params, xs, ys, keys), {}
 
-    def local_update(self, gp, xs, ys, r, key):
-        M = ys.shape[0]
-        return self.local_update_keyed(gp, xs, ys, r,
-                                       jax.random.split(key, M))
-
     def state_client_stacked(self, state) -> bool:
         # server-style carry: ONE global model, replicated across the client
         # mesh; only the mid-round (M, ...) local-update stacks are sharded
@@ -108,7 +103,7 @@ class FedAvgStrategy(Strategy):
         (padded slots carry weight 0), then one lax.psum combines the
         partials. The (M, ...) stack never materializes on a slice — the
         reduction is O(model) per shard instead of the gather's O(M·model)."""
-        local_w = ctx.shard_rows(w_full)
+        local_w = ctx.rows(w_full)
         partial = jax.tree_util.tree_map(
             lambda t: jnp.einsum("m...,m->...", t, local_w), clients)
         return jax.tree_util.tree_map(

@@ -42,11 +42,6 @@ class LocalStrategy(Strategy):
             return common.sgd_update(p, g, self.lr)
         return jax.vmap(one)(params, xs, ys, keys), {}
 
-    def local_update(self, params, xs, ys, r, key):
-        M = ys.shape[0]
-        return self.local_update_keyed(params, xs, ys, r,
-                                       jax.random.split(key, M))
-
     def eval_params(self, state):
         return state
 

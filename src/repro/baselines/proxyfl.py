@@ -70,11 +70,6 @@ class ProxyFLStrategy(Strategy):
                                        keys)
         return {"private": private, "proxy": proxy}, {}
 
-    def local_update(self, state, xs, ys, r, key):
-        M = ys.shape[0]
-        return self.local_update_keyed(state, xs, ys, r,
-                                       jax.random.split(key, M))
-
     def aggregate(self, state, r, key):
         """Receive neighbor's proxy (directed exponential graph), average.
 

@@ -68,11 +68,6 @@ class ScaffoldStrategy(Strategy):
         return {"clients": newp, "c_clients": newc,
                 "c_global": c_global}, {}
 
-    def local_update(self, state, xs, ys, r, key):
-        M = ys.shape[0]
-        return self.local_update_keyed(state, xs, ys, r,
-                                       jax.random.split(key, M))
-
     def aggregate(self, mid, r, key):
         return {"global": common.tree_mean(mid["clients"]),
                 "c_global": common.tree_mean(mid["c_clients"]),

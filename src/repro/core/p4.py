@@ -255,13 +255,6 @@ class P4Trainer:
         return self._local_round_impl(states, xs, ys, key)
 
     # ------------------------------------------------------------------
-    @functools.partial(jax.jit, static_argnums=(0, 3))
-    def aggregate(self, states, ids, num_groups: int):
-        """Group-internal proxy aggregation (rotating aggregator in p2p.py)."""
-        return {"private": states["private"],
-                "proxy": group_mean(states["proxy"], ids, num_groups)}
-
-    # ------------------------------------------------------------------
     def form_groups(self, states, seed: int = 0,
                     topology=None) -> List[List[int]]:
         """Phase-1 grouping. ``topology`` (optional) restricts each client's
@@ -585,7 +578,7 @@ class P4Strategy(Strategy):
         ``num_groups`` so segment sums drop them."""
         padded = np.full((ctx.M_pad,), self.num_groups, np.int32)
         padded[: ctx.M] = np.asarray(self.ids)
-        return ctx.shard_rows(jnp.asarray(padded))
+        return ctx.rows(jnp.asarray(padded))
 
     def sharded_aggregate(self, states, r, key, ctx):
         if self.ids is None:
@@ -599,9 +592,9 @@ class P4Strategy(Strategy):
             # sliced fault mask realizes the identical topology everywhere.
             cfm = self._context_fault_mask(r)
             if cfm is not None:
-                local = ctx.shard_rows(cfm)
+                local = ctx.rows(cfm)
             elif self._has_faults():
-                local = ctx.shard_rows(self._fault_mask(r, key))
+                local = ctx.rows(self._fault_mask(r, key))
             else:
                 local = ctx.valid_mask()
             return {"private": states["private"],
@@ -619,9 +612,9 @@ class P4Strategy(Strategy):
             local = local_mask
             cfm = self._context_fault_mask(r)
             if cfm is not None:
-                local = local * ctx.shard_rows(cfm)
+                local = local * ctx.rows(cfm)
             elif self._has_faults():
-                local = local * ctx.shard_rows(self._fault_mask(r, key))
+                local = local * ctx.rows(self._fault_mask(r, key))
             return {"private": states["private"],
                     "proxy": masked_group_mean(states["proxy"],
                                                self._local_ids(ctx),

@@ -35,8 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.accounting import PrivacyLedger
-from repro.engine.schedule import (FullParticipation, RoundSchedule,
-                                   Scoped, sample_client_batches)
+from repro.engine.schedule import (FullParticipation, ResidentCtx,
+                                   RoundSchedule, sample_client_batches)
 from repro.engine.strategy import (FederatedData, Strategy, runtime_params)
 from repro.obs.layers import note_dispatch
 from repro.obs.probes import Probe
@@ -229,10 +229,7 @@ class Engine:
         fn = _cache_get(key_)
         if fn is not None:
             return fn
-        body = self.schedule.round_body(Scoped(self.strategy), batch_size)
-        if self.faults is not None:
-            from repro.resilience import wrap_round_body
-            body = wrap_round_body(body, self.faults)
+        schedule, strategy, faults = self.schedule, self.strategy, self.faults
         tap = None
         if self._tap_traced():
             from repro.obs.telemetry import tap_scan
@@ -240,6 +237,12 @@ class Engine:
 
         def run(state, phase_key, train_x, train_y, start, rt):
             CHUNK_STATS["traces"] += 1   # python body executes per trace only
+            # M comes from the traced stacks: the cache key does not hold it
+            body = schedule.round_body(strategy, batch_size,
+                                       ResidentCtx(train_y.shape[0]))
+            if faults is not None:
+                from repro.resilience import wrap_round_body
+                body = wrap_round_body(body, faults)
             with runtime_params(rt):
                 def scan_body(state, r):
                     return body(state, r, phase_key, train_x, train_y)

@@ -28,8 +28,9 @@ and ``tests/test_population.py``):
 
 Under ``FullParticipation`` / ``AsyncStaleness`` every client trains every
 round, so the cohort is the whole population: the engine gathers the full
-stacks and reuses the resident round body verbatim (trivially bit-exact).
-Only ``ClientSampling`` runs the true compact-cohort body.
+stacks and runs the resident chunk (``ResidentCtx``) verbatim (trivially
+bit-exact). Only ``ClientSampling`` runs its body over the compact cohort
+(``PagedCtx``).
 
 Double-buffered prefetch: while a chunk executes on device (JAX dispatch is
 asynchronous), a host thread plans and gathers the next chunk's cohort
@@ -49,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.loop import CHUNK_STATS, Engine, _cache_get, _cache_put
-from repro.engine.schedule import ClientSampling, Scoped
+from repro.engine.schedule import ClientSampling, LayoutCtx
 from repro.engine.strategy import FederatedData, runtime_params
 from repro.obs.layers import layer
 from repro.obs.probes import Probe
@@ -168,7 +169,7 @@ class _PopLeaf:
         return f"_PopLeaf({self.idx}, {self.shape}, {self.dtype})"
 
 
-class PagedCtx:
+class PagedCtx(LayoutCtx):
     """Trace-time view of the cohort boundary inside a paged chunk.
 
     ``M`` is the population size, ``C`` the padded cohort width. The chunk
@@ -207,14 +208,14 @@ class PagedCtx:
         return cm()
 
     # ------------------------------------------------------------ randomness
-    def cohort_keys(self, key):
+    def client_keys(self, key):
         """The global M-way key split's cohort rows — client i's stream is
         independent of its cohort slot (split is not prefix-stable, so the
         full split is computed then sliced, exactly like the sharded path)."""
         return jax.random.split(key, self.M)[self.ids_clip]
 
-    def sample_cohort_batches(self, train_x, train_y, key, batch_size):
-        """Paged twin of ``sample_client_batches``: the (M, B) index draw is
+    def sample_batches(self, train_x, train_y, key, batch_size):
+        """Paged ``sample_client_batches``: the (M, B) index draw is
         made at full population size and row-sliced at the cohort's global
         ids, then gathered from the compact data stacks."""
         if batch_size is None:
@@ -228,6 +229,11 @@ class PagedCtx:
                 axis=1)
             ys = jnp.take_along_axis(train_y, idx, axis=1)
         return xs, ys
+
+    def rows(self, v):
+        """The cohort slots' view of a full (M,) vector; padding slots
+        read 0, so they never merge."""
+        return v[self.ids_clip] * self.valid
 
     # --------------------------------------------------------------- metrics
     def metric_means(self, per_client: Dict[str, Any]) -> Dict[str, Any]:
@@ -274,6 +280,24 @@ class PagedCtx:
             else:
                 res.append(o)
         return jax.tree_util.tree_unflatten(out_def, res)
+
+    # ------------------------------------------------------ strategy hooks
+    # (a paged chunk runs only ClientSampling bodies: every hook is masked)
+    def local_update(self, strategy, state, xs, ys, r, key):
+        with layer("local_update"):
+            return strategy.paged_local_update(state, xs, ys, r, key, self)
+
+    def aggregate_masked(self, strategy, state, r, key, mask, rows):
+        with layer("aggregate"):
+            return strategy.paged_aggregate_masked(state, r, key, mask, self)
+
+    def _mix_step(self, tree, plan, r, key, keep, halo):
+        """The resident step's per-row gossip arithmetic with neighbor
+        reads resolved through the cohort's slot map: the planner closed
+        the cohort over in-neighbors (``paged_cohort_closure``), so every
+        participant row reads exactly the values the resident step reads."""
+        from repro.topology.mixing import mix_stacked_paged
+        return mix_stacked_paged(tree, plan, r, key, self, keep=keep)
 
 
 class CohortPrefetcher:
@@ -456,8 +480,7 @@ class PagedEngine(Engine):
         if fn is not None:
             return fn
         pctx = PagedCtx(self._M, cohort)
-        body = self.schedule.paged_round_body(Scoped(self.strategy),
-                                              batch_size, pctx)
+        body = self.schedule.round_body(self.strategy, batch_size, pctx)
         if self.faults is not None:
             from repro.resilience import wrap_round_body
             body = wrap_round_body(body, self.faults)

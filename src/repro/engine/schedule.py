@@ -19,6 +19,13 @@ schedule object) rather than per-strategy rewrites:
                        weight (1 + s)^(-staleness_pow). staleness=0 is the
                        synchronous body exactly.
 
+Each schedule writes ONE round body, against a layout context
+(``LayoutCtx``): the engine hands it ``ResidentCtx`` (``Engine``),
+``ClientShardCtx`` (``ShardedEngine``) or ``PagedCtx`` (``PagedEngine``),
+and the context draws the batches, takes its rows of a full (M,) mask and
+calls the strategy's hook for that layout. A new layout is one context
+class; a new schedule is one body.
+
 Per-round randomness matches the PR-2 derivation — ``rk = fold_in(phase_key,
 r)`` with streams 0/1/2 for batch/local/aggregate — and ClientSampling draws
 its mask from the previously unused stream 3, so adding a schedule never
@@ -44,17 +51,29 @@ from repro.obs.layers import layer
 from repro.resilience import current_faults
 
 
-def _fault_blend(agg, base, w_rows, rows: int, w_scalar):
-    """Per-client staleness-discounted fold: client-stacked leaves blend with
-    their own realized weight, replicated/server leaves with the mean weight
-    (so single-device and sharded traces agree on non-stacked state)."""
+def _blend(agg, base, w, w_rows=None):
+    """``w · agg + (1 − w) · base`` on every leaf. With ``w_rows`` (this
+    layout's rows of a per-client weight vector), client-stacked leaves blend
+    with their own row's weight and replicated/server leaves with ``w`` (so
+    every layout agrees on non-stacked state)."""
+    rows = None if w_rows is None else w_rows.shape[0]
+
     def f(a, b):
-        if a.ndim >= 1 and a.shape[:1] == (rows,):
+        ww = w
+        if rows is not None and a.ndim >= 1 and a.shape[:1] == (rows,):
             ww = w_rows.reshape((rows,) + (1,) * (a.ndim - 1))
-        else:
-            ww = w_scalar
         return (ww * a + (1.0 - ww) * b).astype(b.dtype)
     return jax.tree_util.tree_map(f, agg, base)
+
+
+def _unless_empty(mask, state, new):
+    """``new``, or ``state`` unchanged when no client participates: an empty
+    cohort's round is a no-op (stacked strategies are already frozen by the
+    merges; this guards server-style states whose cohort-weighted
+    aggregation has no cohort to weight)."""
+    empty = jnp.sum(mask) == 0
+    return jax.tree_util.tree_map(lambda s, n: jnp.where(empty, s, n),
+                                  state, new)
 
 
 def sample_client_batches(train_x, train_y, key, batch_size: Optional[int]):
@@ -75,48 +94,116 @@ def sample_client_batches(train_x, train_y, key, batch_size: Optional[int]):
     return xs, ys
 
 
-class Scoped:
-    """The strategy as every round body sees it: the engines hand
-    ``Scoped(strategy)`` to the schedule, so each body's local update runs
-    under the ``local_update`` layer and its aggregation, with the
-    ``merge_participation`` calls around it, under ``aggregate``
-    (``repro.obs.layers``). Everything else is the strategy's own."""
+class LayoutCtx:
+    """How a round body sees the engine's layout of the clients.
 
-    def __init__(self, strategy):
-        self._strategy = strategy
+    A schedule writes its round body once, against this protocol; each
+    engine hands the body its layout:
 
-    def __getattr__(self, name):
-        return getattr(self._strategy, name)
+      ``ResidentCtx``     ``Engine``: the whole (M, ...) stack in one program
+      ``ClientShardCtx``  ``ShardedEngine``: this shard's rows, in shard_map
+      ``PagedCtx``        ``PagedEngine``: the compact (C, ...) cohort rows
 
-    def _scoped(name, layer_name):
-        def call(self, *args, **kwargs):
-            with layer(layer_name):
-                return getattr(self._strategy, name)(*args, **kwargs)
-        call.__name__ = name
-        return call
+    Every layout answers:
 
-    local_update = _scoped("local_update", "local_update")
-    sharded_local_update = _scoped("sharded_local_update", "local_update")
-    paged_local_update = _scoped("paged_local_update", "local_update")
-    aggregate = _scoped("aggregate", "aggregate")
-    aggregate_masked = _scoped("aggregate_masked", "aggregate")
-    sharded_aggregate = _scoped("sharded_aggregate", "aggregate")
-    sharded_aggregate_masked = _scoped("sharded_aggregate_masked",
-                                       "aggregate")
-    paged_aggregate_masked = _scoped("paged_aggregate_masked", "aggregate")
-    merge_participation = _scoped("merge_participation", "aggregate")
-    del _scoped
+      ``M``                        the federation's client count
+      ``client_keys(key)``         this layout's rows of ``split(key, M)``
+      ``sample_batches(...)``      this layout's rows of the (M, B) draw
+      ``rows(v)``                  this layout's rows of a full (M,) vector
+      ``metric_means(per_client)`` the scalar means the resident path records
+      ``local_update``, ``aggregate``, ``aggregate_masked``,
+      ``merge_participation``      the strategy's hook for this layout,
+                                   entered under the ``local_update`` /
+                                   ``aggregate`` layer (``repro.obs.layers``)
+      ``mix(strategy, tree, ...)`` one gossip step over the strategy's plan
+      ``merge_when(pred, merge, state)``  ``merge(state)`` where ``pred``
+
+    The per-client randomness is layout-invariant: every draw is made at
+    full M and sliced, so a client's stream never depends on where it sits.
+    """
+
+    def merge_participation(self, strategy, prev, new, rows):
+        with layer("aggregate"):
+            return strategy.merge_participation(prev, new, rows)
+
+    def merge_when(self, pred, merge, state):
+        return jax.lax.cond(pred, merge, lambda s: s, state)
+
+    def mix(self, strategy, tree, r, key, halo=None):
+        """t ← W_r t on every client-stacked leaf of ``tree``, with the
+        round's link faults drawn in-jit from ``key``'s fault stream (a
+        correlated fault process's realized keep matrix supersedes the
+        plan's i.i.d. rates). Identity without a configured topology.
+        ``halo``: boundary rows a client shard prefetched last round."""
+        plan = strategy._mix_plan
+        if plan is None:
+            return tree
+        af = current_faults()
+        keep = None if af is None else af.real.keep
+        return self._mix_step(tree, plan, r, key, keep, halo)
+
+
+class ResidentCtx(LayoutCtx):
+    """The identity layout: every client's row in one (M, ...) stack."""
+
+    def __init__(self, num_clients: int):
+        self.M = int(num_clients)
+
+    def client_keys(self, key):
+        return jax.random.split(key, self.M)
+
+    def sample_batches(self, train_x, train_y, key, batch_size):
+        return sample_client_batches(train_x, train_y, key, batch_size)
+
+    def rows(self, v):
+        return v
+
+    def metric_means(self, per_client):
+        return {k: jnp.mean(v) for k, v in per_client.items()}
+
+    def local_update(self, strategy, state, xs, ys, r, key):
+        with layer("local_update"):
+            return strategy.local_update(state, xs, ys, r, key)
+
+    def aggregate(self, strategy, state, r, key):
+        with layer("aggregate"):
+            return strategy.aggregate(state, r, key)
+
+    def aggregate_masked(self, strategy, state, r, key, mask, rows):
+        with layer("aggregate"):
+            return strategy.aggregate_masked(state, r, key, mask)
+
+    def _mix_step(self, tree, plan, r, key, keep, halo):
+        from repro.topology.mixing import mix_stacked
+        return mix_stacked(tree, plan, r, key, keep=keep)
+
+
+def _masked_round(strategy, ctx, state, xs, ys, r, rk, mask):
+    """The round over the (M,) participation ``mask``: absent clients
+    neither train, send nor receive — their state is bit-unchanged."""
+    rows = ctx.rows(mask)
+    new, metrics = ctx.local_update(strategy, state, xs, ys, r,
+                                    jax.random.fold_in(rk, 1))
+    # absent clients' local training is discarded: aggregation sees their
+    # pre-round (last-known) state
+    new = ctx.merge_participation(strategy, state, new, rows)
+    new = ctx.aggregate_masked(strategy, new, r, jax.random.fold_in(rk, 2),
+                               mask, rows)
+    # ...and they receive nothing: final state is bit-unchanged
+    new = ctx.merge_participation(strategy, state, new, rows)
+    return _unless_empty(mask, state, new), (metrics, {"participation": mask})
 
 
 @dataclass(eq=False)  # identity hash: schedules are closed over by jitted chunks
 class RoundSchedule:
     """Owns the engine's scanned round body.
 
-    ``round_body(strategy, batch_size)`` returns
+    ``round_body(strategy, batch_size, ctx)`` returns
     ``body(state, r, phase_key, train_x, train_y) -> (state, (metrics, aux))``
-    where ``aux`` is an (empty or participation-carrying) dict of per-round
-    arrays stacked by the scan — the engine forwards ``aux["participation"]``
-    to byte accounting and History.
+    written against the engine's layout ``ctx`` (``LayoutCtx``), where
+    ``aux`` is an (empty or participation-carrying) dict of per-round arrays
+    stacked by the scan — the engine forwards ``aux["participation"]`` to
+    byte accounting and History.
     """
 
     name = "full"
@@ -132,16 +219,8 @@ class RoundSchedule:
         return (type(self).__name__,) + tuple(
             (f.name, getattr(self, f.name)) for f in dataclasses.fields(self))
 
-    def round_body(self, strategy, batch_size: Optional[int]):
+    def round_body(self, strategy, batch_size: Optional[int], ctx):
         raise NotImplementedError
-
-    def sharded_round_body(self, strategy, batch_size: Optional[int], ctx):
-        """Round body for a shard_map region over the client axis: same key
-        derivation and call sequence as ``round_body``, with the strategy's
-        sharded hooks in place of the single-device ones (see
-        ``repro.engine.sharded``)."""
-        raise NotImplementedError(
-            f"{type(self).__name__} has no sharded round body")
 
 
 @dataclass(eq=False)
@@ -150,60 +229,24 @@ class FullParticipation(RoundSchedule):
 
     name = "full"
 
-    def round_body(self, strategy, batch_size):
+    def round_body(self, strategy, batch_size, ctx):
         def body(state, r, phase_key, train_x, train_y):
             rk = jax.random.fold_in(phase_key, r)
-            xs, ys = sample_client_batches(
+            xs, ys = ctx.sample_batches(
                 train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
             af = current_faults()
             if af is None:
-                state, metrics = strategy.local_update(
-                    state, xs, ys, r, jax.random.fold_in(rk, 1))
-                state = strategy.aggregate(state, r, jax.random.fold_in(rk, 2))
+                state, metrics = ctx.local_update(
+                    strategy, state, xs, ys, r, jax.random.fold_in(rk, 1))
+                state = ctx.aggregate(strategy, state, r,
+                                      jax.random.fold_in(rk, 2))
                 return state, (metrics, {})
             # faults installed: down/slow clients are frozen — their training
             # is discarded, they receive nothing, and aggregation runs over
-            # the active cohort (the ClientSampling machinery, mask = active)
-            active = af.real.active()
-            new, metrics = strategy.local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1))
-            new = strategy.merge_participation(state, new, active)
-            new = strategy.aggregate_masked(new, r, jax.random.fold_in(rk, 2),
-                                            active)
-            new = strategy.merge_participation(state, new, active)
-            empty = jnp.sum(active) == 0
-            state = jax.tree_util.tree_map(
-                lambda s, n: jnp.where(empty, s, n), state, new)
-            return state, (metrics, {"participation": active})
-
-        return body
-
-    def sharded_round_body(self, strategy, batch_size, ctx):
-        def body(state, r, phase_key, train_x, train_y):
-            rk = jax.random.fold_in(phase_key, r)
-            xs, ys = ctx.sample_local_batches(
-                train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
-            af = current_faults()
-            if af is None:
-                state, metrics = strategy.sharded_local_update(
-                    state, xs, ys, r, jax.random.fold_in(rk, 1), ctx)
-                state = strategy.sharded_aggregate(
-                    state, r, jax.random.fold_in(rk, 2), ctx)
-                return state, (metrics, {})
-            # the realization is replicated (the fault carry is stepped from
-            # the phase key on every slice), so active matches single-device
-            active = af.real.active()
-            local_active = ctx.shard_rows(active)
-            new, metrics = strategy.sharded_local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1), ctx)
-            new = strategy.merge_participation(state, new, local_active)
-            new = strategy.sharded_aggregate_masked(
-                new, r, jax.random.fold_in(rk, 2), ctx, active, local_active)
-            new = strategy.merge_participation(state, new, local_active)
-            empty = jnp.sum(active) == 0
-            state = jax.tree_util.tree_map(
-                lambda s, n: jnp.where(empty, s, n), state, new)
-            return state, (metrics, {"participation": active})
+            # the active cohort (the ClientSampling machinery, mask = active;
+            # the realization is replicated, so every layout sees the same)
+            return _masked_round(strategy, ctx, state, xs, ys, r, rk,
+                                 af.real.active())
 
         return body
 
@@ -248,92 +291,20 @@ class ClientSampling(RoundSchedule):
             return jnp.zeros((M,), jnp.float32).at[idx].set(1.0)
         return (jax.random.uniform(k1, (M,)) < self.q).astype(jnp.float32)
 
-    def round_body(self, strategy, batch_size):
+    def round_body(self, strategy, batch_size, ctx):
         def body(state, r, phase_key, train_x, train_y):
-            M = train_y.shape[0]
             rk = jax.random.fold_in(phase_key, r)
-            xs, ys = sample_client_batches(
+            xs, ys = ctx.sample_batches(
                 train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
-            mask = self.draw_mask(jax.random.fold_in(rk, 3), M)
+            # the full (M,) mask is drawn in every layout — each takes its
+            # own rows of it — and the aux output stays the full mask, so
+            # byte accounting and the ledger see exactly the same cohorts
+            mask = self.draw_mask(jax.random.fold_in(rk, 3), ctx.M)
             af = current_faults()
             if af is not None:
                 # a sampled client that is down or slow still can't serve
                 mask = mask * af.real.active()
-            new, metrics = strategy.local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1))
-            # absent clients' local training is discarded: aggregation sees
-            # their pre-round (last-known) state
-            new = strategy.merge_participation(state, new, mask)
-            new = strategy.aggregate_masked(new, r, jax.random.fold_in(rk, 2),
-                                            mask)
-            # ...and they receive nothing: final state is bit-unchanged
-            new = strategy.merge_participation(state, new, mask)
-            # empty Bernoulli cohort ⇒ the round is a no-op for everyone
-            # (stacked strategies are already frozen by the merges; this
-            # guards server-style states whose cohort-weighted aggregation
-            # has no cohort to weight)
-            empty = jnp.sum(mask) == 0
-            state = jax.tree_util.tree_map(
-                lambda s, n: jnp.where(empty, s, n), state, new)
-            return state, (metrics, {"participation": mask})
-
-        return body
-
-    def paged_round_body(self, strategy, batch_size, pctx):
-        """Round body over a paged cohort (``repro.engine.population``): the
-        chunk's arrays hold the compact (C, ...) cohort rows, but every random
-        draw is made at full population size — the (M,) participation mask,
-        the M-way per-client key split, the (M, B) batch-index draw — and then
-        sliced at the cohort's global ids, so the streams (and the aux
-        participation masks the ledger and byte accounting consume) are
-        bit-identical to the resident body's."""
-        def body(state, r, phase_key, train_x, train_y):
-            rk = jax.random.fold_in(phase_key, r)
-            xs, ys = pctx.sample_cohort_batches(
-                train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
-            mask = self.draw_mask(jax.random.fold_in(rk, 3), pctx.M)
-            af = current_faults()
-            if af is not None:
-                mask = mask * af.real.active()
-            # cohort-slot view of the full mask; padding slots never merge
-            mask_c = mask[pctx.ids_clip] * pctx.valid
-            new, metrics = strategy.paged_local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1), pctx)
-            new = strategy.merge_participation(state, new, mask_c)
-            new = strategy.paged_aggregate_masked(
-                new, r, jax.random.fold_in(rk, 2), mask, pctx)
-            new = strategy.merge_participation(state, new, mask_c)
-            empty = jnp.sum(mask) == 0
-            state = jax.tree_util.tree_map(
-                lambda s, n: jnp.where(empty, s, n), state, new)
-            return state, (metrics, {"participation": mask})
-
-        return body
-
-    def sharded_round_body(self, strategy, batch_size, ctx):
-        def body(state, r, phase_key, train_x, train_y):
-            rk = jax.random.fold_in(phase_key, r)
-            xs, ys = ctx.sample_local_batches(
-                train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
-            # the full (M,) mask is drawn replicated — every shard computes
-            # the same draw the single-device body makes, then slices its own
-            # rows; the aux output stays the full mask so byte accounting and
-            # the ledger see exactly the single-device cohorts
-            mask = self.draw_mask(jax.random.fold_in(rk, 3), ctx.M)
-            af = current_faults()
-            if af is not None:
-                mask = mask * af.real.active()
-            local_mask = ctx.shard_rows(mask)
-            new, metrics = strategy.sharded_local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1), ctx)
-            new = strategy.merge_participation(state, new, local_mask)
-            new = strategy.sharded_aggregate_masked(
-                new, r, jax.random.fold_in(rk, 2), ctx, mask, local_mask)
-            new = strategy.merge_participation(state, new, local_mask)
-            empty = jnp.sum(mask) == 0
-            state = jax.tree_util.tree_map(
-                lambda s, n: jnp.where(empty, s, n), state, new)
-            return state, (metrics, {"participation": mask})
+            return _masked_round(strategy, ctx, state, xs, ys, r, rk, mask)
 
         return body
 
@@ -358,124 +329,62 @@ class AsyncStaleness(RoundSchedule):
     staleness_pow: float = 0.5
     name = "async"
 
-    def round_body(self, strategy, batch_size):
+    def round_body(self, strategy, batch_size, ctx):
         period = int(self.staleness) + 1
         weight = float(period ** (-self.staleness_pow))
 
         def body(state, r, phase_key, train_x, train_y):
             rk = jax.random.fold_in(phase_key, r)
-            xs, ys = sample_client_batches(
+            xs, ys = ctx.sample_batches(
                 train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
             af = current_faults()
             if af is not None:
-                # realized staleness: each client's merge weight comes from
-                # the rounds it actually missed ((1+age)^-pow, FedBuff form)
-                # instead of the configured scalar s — slow devices emerge
-                # from the straggler chain
-                active = af.real.active()
-                new, metrics = strategy.local_update(
-                    state, xs, ys, r, jax.random.fold_in(rk, 1))
-                new = strategy.merge_participation(state, new, active)
-                agg = strategy.aggregate_masked(
-                    new, r, jax.random.fold_in(rk, 2), active)
-                w = (1.0 + af.real.age) ** (-self.staleness_pow)
-                if strategy.state_client_stacked(state):
-                    merged = _fault_blend(agg, new, w, w.shape[0],
-                                          jnp.mean(w))
-                    hold = new
-                else:
-                    # server-style state: the aggregate folds into the
-                    # previous global model at the mean realized discount
-                    wbar = jnp.mean(w)
-                    merged = jax.tree_util.tree_map(
-                        lambda a, s: (wbar * a + (1.0 - wbar) * s)
-                        .astype(s.dtype), agg, state)
-                    hold = state
-                if period > 1:
-                    is_merge = jnp.equal(r % period, period - 1)
-                    merged = jax.tree_util.tree_map(
-                        lambda m, n: jnp.where(is_merge, m, n), merged, hold)
-                merged = strategy.merge_participation(state, merged, active)
-                empty = jnp.sum(active) == 0
-                state = jax.tree_util.tree_map(
-                    lambda s, n: jnp.where(empty, s, n), state, merged)
-                return state, (metrics, {"participation": active})
-            state, metrics = strategy.local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1))
+                return faulted(state, r, rk, xs, ys, af)
+            state, metrics = ctx.local_update(strategy, state, xs, ys, r,
+                                              jax.random.fold_in(rk, 1))
             if period == 1:   # synchronous: identical to FullParticipation
-                state = strategy.aggregate(state, r, jax.random.fold_in(rk, 2))
+                state = ctx.aggregate(strategy, state, r,
+                                      jax.random.fold_in(rk, 2))
                 return state, (metrics, {})
 
             def merge(s):
-                agg = strategy.aggregate(s, r, jax.random.fold_in(rk, 2))
-                return jax.tree_util.tree_map(
-                    lambda a, b: (weight * a + (1.0 - weight) * b).astype(b.dtype),
-                    agg, s)
+                agg = ctx.aggregate(strategy, s, r, jax.random.fold_in(rk, 2))
+                return _blend(agg, s, weight)
 
-            state = jax.lax.cond(jnp.equal(r % period, period - 1),
-                                 merge, lambda s: s, state)
+            state = ctx.merge_when(jnp.equal(r % period, period - 1), merge,
+                                   state)
             return state, (metrics, {})
 
-        return body
-
-    def sharded_round_body(self, strategy, batch_size, ctx):
-        period = int(self.staleness) + 1
-        weight = float(period ** (-self.staleness_pow))
-
-        def body(state, r, phase_key, train_x, train_y):
-            rk = jax.random.fold_in(phase_key, r)
-            xs, ys = ctx.sample_local_batches(
-                train_x, train_y, jax.random.fold_in(rk, 0), batch_size)
-            af = current_faults()
-            if af is not None:
-                active = af.real.active()
-                local_active = ctx.shard_rows(active)
-                new, metrics = strategy.sharded_local_update(
-                    state, xs, ys, r, jax.random.fold_in(rk, 1), ctx)
-                new = strategy.merge_participation(state, new, local_active)
-                agg = strategy.sharded_aggregate_masked(
-                    new, r, jax.random.fold_in(rk, 2), ctx, active,
-                    local_active)
-                w = (1.0 + af.real.age) ** (-self.staleness_pow)
-                if strategy.state_client_stacked(state):
-                    merged = _fault_blend(agg, new, ctx.shard_rows(w), ctx.m,
-                                          jnp.mean(w))
-                    hold = new
-                else:
-                    wbar = jnp.mean(w)
-                    merged = jax.tree_util.tree_map(
-                        lambda a, s: (wbar * a + (1.0 - wbar) * s)
-                        .astype(s.dtype), agg, state)
-                    hold = state
-                if period > 1:
-                    is_merge = jnp.equal(r % period, period - 1)
-                    merged = jax.tree_util.tree_map(
-                        lambda m, n: jnp.where(is_merge, m, n), merged, hold)
-                merged = strategy.merge_participation(state, merged,
-                                                      local_active)
-                empty = jnp.sum(active) == 0
-                state = jax.tree_util.tree_map(
-                    lambda s, n: jnp.where(empty, s, n), state, merged)
-                return state, (metrics, {"participation": active})
-            state, metrics = strategy.sharded_local_update(
-                state, xs, ys, r, jax.random.fold_in(rk, 1), ctx)
-            if period == 1:   # synchronous: identical to FullParticipation
-                state = strategy.sharded_aggregate(
-                    state, r, jax.random.fold_in(rk, 2), ctx)
-                return state, (metrics, {})
-            # collectives must execute uniformly across shards, so the merge
-            # is select-based rather than lax.cond: the aggregate (and its
-            # all_gather/psum) runs every round and non-merge rounds select
-            # the untouched state — bit-identical outcomes, uniform comms
-            agg = strategy.sharded_aggregate(
-                state, r, jax.random.fold_in(rk, 2), ctx)
-            merged = jax.tree_util.tree_map(
-                lambda a, b: (weight * a + (1.0 - weight) * b).astype(b.dtype),
-                agg, state)
-            is_merge = jnp.equal(r % period, period - 1)
-            state = jax.tree_util.tree_map(
-                lambda m, s: jnp.where(is_merge, m, s), merged, state)
-            return state, (metrics, {})
+        def faulted(state, r, rk, xs, ys, af):
+            # realized staleness: each client's merge weight comes from the
+            # rounds it actually missed ((1+age)^-pow, FedBuff form) instead
+            # of the configured scalar s — slow devices emerge from the
+            # straggler chain
+            active = af.real.active()
+            rows = ctx.rows(active)
+            new, metrics = ctx.local_update(strategy, state, xs, ys, r,
+                                            jax.random.fold_in(rk, 1))
+            new = ctx.merge_participation(strategy, state, new, rows)
+            agg = ctx.aggregate_masked(strategy, new, r,
+                                       jax.random.fold_in(rk, 2), active,
+                                       rows)
+            w = (1.0 + af.real.age) ** (-self.staleness_pow)
+            if strategy.state_client_stacked(state):
+                w_rows = ctx.rows(w)
+                merged = _blend(agg, new, jnp.mean(w), w_rows)
+                hold = new
+            else:
+                # server-style state: the aggregate folds into the previous
+                # global model at the mean realized discount
+                merged = _blend(agg, state, jnp.mean(w))
+                hold = state
+            if period > 1:
+                is_merge = jnp.equal(r % period, period - 1)
+                merged = jax.tree_util.tree_map(
+                    lambda m, n: jnp.where(is_merge, m, n), merged, hold)
+            merged = ctx.merge_participation(strategy, state, merged, rows)
+            return (_unless_empty(active, state, merged),
+                    (metrics, {"participation": active}))
 
         return body
 

@@ -16,7 +16,7 @@ that possible:
 
   * layout-invariant randomness — ``jax.random.split(key, M)`` is not
     prefix-stable, so every shard recomputes the full M-way split (cheap,
-    replicated) and slices its own block (``ClientShardCtx.shard_keys``);
+    replicated) and slices its own block (``ClientShardCtx.client_keys``);
     batch-index draws are likewise drawn at full (M, B) and row-sliced;
   * gather-exact aggregation — the default ``Strategy.sharded_aggregate``
     all_gathers the client stacks and runs the single-device aggregate
@@ -41,7 +41,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.engine.loop import CHUNK_STATS, Engine, _cache_get, _cache_put
-from repro.engine.schedule import Scoped
+from repro.engine.schedule import LayoutCtx, wrap_overlap
 from repro.engine.strategy import FederatedData, runtime_params
 from repro.obs.layers import layer
 from repro.sharding.rules import CLIENT_AXIS, client_specs
@@ -58,14 +58,14 @@ def _pad_rows(arr, target: int):
 
 def _pad_axis1(arr, target: int):
     """Zero-pad axis 1 (the client axis of a (rounds, M, ...) stack) to
-    ``target`` — the same zero padding ``shard_rows`` applies per round."""
+    ``target`` — the same zero padding ``rows`` applies per round."""
     if arr.shape[1] == target:
         return arr
     pad = [(0, 0), (0, target - arr.shape[1])] + [(0, 0)] * (arr.ndim - 2)
     return jnp.pad(arr, pad)
 
 
-class ClientShardCtx:
+class ClientShardCtx(LayoutCtx):
     """Trace-time view of the client mesh inside the shard_map region.
 
     ``M`` is the true client count, ``n`` the mesh-axis size, ``M_pad`` the
@@ -82,7 +82,7 @@ class ClientShardCtx:
         self.m = self.M_pad // self.n
         # per-round prefetched randomness (``prefetched``): this round's
         # (m, 2) key slice / (m, B) batch-index slice, already sharded.
-        # Single-use — consumed by the first shard_keys / batch draw of the
+        # Single-use — consumed by the first client_keys / batch draw of the
         # round, so a second call (if a strategy ever makes one) falls back
         # to the replicated recompute instead of silently reusing a stream.
         self._pf_keys = None
@@ -105,7 +105,7 @@ class ClientShardCtx:
         """First global (padded) client row held by this shard."""
         return jax.lax.axis_index(self.axis) * self.m
 
-    def shard_rows(self, arr):
+    def rows(self, arr):
         """Slice this shard's rows from a replicated full-stack array
         ((M, ...) arrays are zero-padded to (M_pad, ...) first)."""
         if arr.shape[0] == self.M:
@@ -118,7 +118,7 @@ class ClientShardCtx:
         return (idx < self.M).astype(jnp.float32)
 
     # ------------------------------------------------------------ randomness
-    def shard_keys(self, key):
+    def client_keys(self, key):
         """This shard's per-client keys — the *global* M-way split's slice,
         so client i's stream is independent of the mesh layout (split is not
         prefix-stable; every shard recomputes the full split, replicated).
@@ -127,10 +127,10 @@ class ClientShardCtx:
         if self._pf_keys is not None:
             out, self._pf_keys = self._pf_keys, None
             return out
-        return self.shard_rows(jax.random.split(key, self.M))
+        return self.rows(jax.random.split(key, self.M))
 
-    def sample_local_batches(self, train_x, train_y, key, batch_size):
-        """Sharded twin of ``sample_client_batches``: the (M, B) index draw
+    def sample_batches(self, train_x, train_y, key, batch_size):
+        """Sharded ``sample_client_batches``: the (M, B) index draw
         is replicated (identical to the single-device draw), then row-sliced
         onto this shard's data. ``batch_size=None`` = full local batch."""
         if batch_size is None:
@@ -141,7 +141,7 @@ class ClientShardCtx:
             else:
                 R = train_y.shape[1]
                 idx = jax.random.randint(key, (self.M, batch_size), 0, R)
-                idx = self.shard_rows(idx)
+                idx = self.rows(idx)
             xs = jnp.take_along_axis(
                 train_x, idx.reshape(idx.shape + (1,) * (train_x.ndim - 2)),
                 axis=1)
@@ -169,7 +169,7 @@ class ClientShardCtx:
         res = []
         for o, f in zip(out_leaves, full_leaves):
             if o.shape == f.shape and o.ndim >= 1 and o.shape[0] == self.M:
-                res.append(self.shard_rows(o))
+                res.append(self.rows(o))
             else:
                 res.append(o)
         return jax.tree_util.tree_unflatten(out_def, res)
@@ -183,6 +183,37 @@ class ClientShardCtx:
                 return jnp.mean(self.gather(v))
             return v
         return {k: mean(v) for k, v in per_client.items()}
+
+    # ------------------------------------------------------ strategy hooks
+    def local_update(self, strategy, state, xs, ys, r, key):
+        with layer("local_update"):
+            return strategy.sharded_local_update(state, xs, ys, r, key, self)
+
+    def aggregate(self, strategy, state, r, key):
+        with layer("aggregate"):
+            return strategy.sharded_aggregate(state, r, key, self)
+
+    def aggregate_masked(self, strategy, state, r, key, mask, rows):
+        with layer("aggregate"):
+            return strategy.sharded_aggregate_masked(state, r, key, self,
+                                                     mask, rows)
+
+    def merge_when(self, pred, merge, state):
+        """Collectives must execute uniformly across shards, so the merge
+        is select-based rather than ``lax.cond``: the aggregate (and its
+        all_gather/psum) runs every round and the other rounds select the
+        untouched state — bit-identical outcomes, uniform comms."""
+        merged = merge(state)
+        return jax.tree_util.tree_map(lambda m, s: jnp.where(pred, m, s),
+                                      merged, state)
+
+    def _mix_step(self, tree, plan, r, key, keep, halo):
+        """ppermute halo exchange for banded/bounded-bandwidth graphs,
+        slice-local gathers when every edge is shard-resident,
+        gather→mix→re-shard otherwise (``repro.topology.mixing``)."""
+        from repro.topology.mixing import mix_stacked_sharded
+        return mix_stacked_sharded(tree, plan, r, key, self, keep=keep,
+                                   halo=halo)
 
 
 @dataclass(eq=False)
@@ -235,9 +266,7 @@ class ShardedEngine(Engine):
         if fn is not None:
             return fn
         ctx = ClientShardCtx(self.mesh, self.client_axis, data.num_clients)
-        from repro.engine.schedule import wrap_overlap
-        body = self.schedule.sharded_round_body(Scoped(self.strategy),
-                                                batch_size, ctx)
+        body = self.schedule.round_body(self.strategy, batch_size, ctx)
         body = wrap_overlap(body, self.strategy, ctx)
         faulted = self.faults is not None
         if faulted:
@@ -253,7 +282,7 @@ class ShardedEngine(Engine):
         # inside every scanned round (replicated, so it is pure overhead
         # that scales with M). Derive the whole chunk's worth in one
         # vmapped draw — bit-identical streams, since fold_in/split/randint
-        # are elementwise over rounds — pad with the same zeros shard_rows
+        # are elementwise over rounds — pad with the same zeros ctx.rows
         # would add, and feed each round its slice through the scan xs.
         # The draw runs in its OWN unsharded jit: under the mesh constraint
         # the SPMD partitioner replicates the whole threefry chain on every

@@ -26,6 +26,8 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.engine.schedule import ResidentCtx
+
 _REGISTRY: Dict[str, type] = {}
 
 
@@ -226,21 +228,28 @@ class Strategy:
         """One round of local training on the sampled batches.
 
         Returns ``(state, metrics)`` where metrics is a (possibly empty) dict
-        of scalars with a structure that is identical every round.
+        of scalars with a structure that is identical every round. Default:
+        ``local_update_keyed`` with ``split(key, M)``, metrics meaned.
         """
-        raise NotImplementedError
+        return self._keyed_update(ResidentCtx(ys.shape[0]), state, xs, ys, r,
+                                  key)
 
     def local_update_keyed(self, state, xs, ys, r, keys):
         """Per-client-keyed form of ``local_update``: ``keys`` is the stacked
-        key array aligned with the leading client axis. Strategies that
-        support the sharded engine implement this (and express
-        ``local_update`` as ``local_update_keyed(..., split(key, M))``) so a
-        client shard can be driven with the *global* key split's slice —
+        key array aligned with the leading client axis, so a client shard or
+        a paged cohort is driven with the *global* key split's rows —
         per-client randomness becomes layout-invariant. Returns
         ``(state, per_client_metrics)`` with (M',)-shaped metric leaves."""
         raise NotImplementedError(
             f"{type(self).__name__} does not implement local_update_keyed; "
             "it cannot run under the sharded engine")
+
+    def _keyed_update(self, ctx, state, xs, ys, r, key):
+        """``local_update_keyed`` on the layout's rows of the global key
+        split, with the metric means the resident path records."""
+        state, per_client = self.local_update_keyed(
+            state, xs, ys, r, ctx.client_keys(key))
+        return state, ctx.metric_means(per_client)
 
     def aggregate(self, state, r, key):
         """Communication/aggregation step after local updates (identity by
@@ -250,48 +259,16 @@ class Strategy:
     # ------------------------------------------------------------- topology
     def set_topology(self, topology, kernels=None) -> None:
         """Install a communication graph (``repro.topology``): the mixing
-        plan is compiled once host-side and the traced ``mix``/``mix_sharded``
-        hooks below apply it per round. Changes the traced computation, so
-        compiled chunks are invalidated; ``None`` restores the strategy's
-        built-in pattern. ``kernels`` (a ``KernelConfig``) opts the halo mix
-        step into the dispatch autotuner's row-tile search."""
+        plan is compiled once host-side and the layout context's ``mix``
+        applies it per round. Changes the traced computation, so compiled
+        chunks are invalidated; ``None`` restores the strategy's built-in
+        pattern. ``kernels`` (a ``KernelConfig``) opts the halo mix step
+        into the dispatch autotuner's row-tile search."""
         from repro.topology.mixing import make_plan
         self.topology = topology
         self._mix_plan = (None if topology is None
                           else make_plan(topology, kernels=kernels))
         self.cache_token += 1
-
-    def mix(self, stacked_tree, r, key):
-        """One gossip round over the configured topology: t ← W_r t on every
-        client-stacked leaf, with the round's link faults drawn in-jit from
-        ``key``'s fault stream. Identity when no topology is configured —
-        strategies call this unconditionally and topology-free runs trace
-        nothing extra."""
-        if self._mix_plan is None:
-            return stacked_tree
-        from repro.resilience import current_faults
-        from repro.topology.mixing import mix_stacked
-        af = current_faults()
-        # a correlated fault process supersedes the plan's i.i.d. rates: the
-        # realized keep matrix (bursty links, outages, partitions) replaces
-        # the per-round memoryless draw
-        keep = None if af is None else af.real.keep
-        return mix_stacked(stacked_tree, self._mix_plan, r, key, keep=keep)
-
-    def mix_sharded(self, stacked_tree, r, key, ctx, halo=None):
-        """Sharded twin of ``mix`` (inside the shard_map region): ppermute
-        halo exchange for banded/bounded-bandwidth graphs, slice-local
-        gathers when every edge is shard-resident, gather→mix→re-shard
-        otherwise. ``halo`` optionally carries boundary rows already
-        exchanged by ``sharded_prefetch`` in the previous round (overlap)."""
-        if self._mix_plan is None:
-            return stacked_tree
-        from repro.resilience import current_faults
-        from repro.topology.mixing import mix_stacked_sharded
-        af = current_faults()
-        keep = None if af is None else af.real.keep
-        return mix_stacked_sharded(stacked_tree, self._mix_plan, r, key, ctx,
-                                   keep=keep, halo=halo)
 
     # ------------------------------------------------------- sharded engine
     # These hooks run inside a shard_map region over the client mesh axis
@@ -313,9 +290,7 @@ class Strategy:
         run's per-client keys (identical on every shard), feed this shard's
         slice to ``local_update_keyed``, and reduce metrics to the same
         global means the single-device path records."""
-        state, per_client = self.local_update_keyed(
-            state, xs, ys, r, ctx.shard_keys(key))
-        return state, ctx.metric_means(per_client)
+        return self._keyed_update(ctx, state, xs, ys, r, key)
 
     def sharded_prefetch(self, state, ctx):
         """Issue next-round boundary transfers from the end-of-round state
@@ -365,24 +340,7 @@ class Strategy:
         """Local update on the cohort's rows. Default: slice the global
         per-client key split at the cohort's ids and reduce metrics over the
         valid (non-padding) slots."""
-        state, per_client = self.local_update_keyed(
-            state, xs, ys, r, pctx.cohort_keys(key))
-        return state, pctx.metric_means(per_client)
-
-    def mix_paged(self, tree_c, r, key, pctx):
-        """Paged twin of ``mix``: the same per-row gossip arithmetic with
-        neighbor reads resolved through the cohort's slot map. The cohort
-        planner closed the cohort over in-neighbors
-        (``paged_cohort_closure``), so every participant row reads exactly
-        the values the resident step reads."""
-        if self._mix_plan is None:
-            return tree_c
-        from repro.resilience import current_faults
-        from repro.topology.mixing import mix_stacked_paged
-        af = current_faults()
-        keep = None if af is None else af.real.keep
-        return mix_stacked_paged(tree_c, self._mix_plan, r, key, pctx,
-                                 keep=keep)
+        return self._keyed_update(pctx, state, xs, ys, r, key)
 
     def paged_aggregate_masked(self, state, r, key, mask, pctx):
         """Cohort aggregation under a sampling schedule: ``mask`` is the full

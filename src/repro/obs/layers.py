@@ -9,10 +9,12 @@ buffers and the engine's chunk-cache keys are those of an unscoped trace.
 =====================  =====================================================
 layer                  entered around
 =====================  =====================================================
-``sample_batches``     ``engine.schedule.sample_client_batches`` and its
-                       sharded and paged twins (every round body)
-``local_update``       the strategy's local update (``Scoped`` in
-                       ``engine.schedule``: every round body)
+``sample_batches``     ``engine.schedule.sample_client_batches`` and the
+                       sharded and paged contexts' ``sample_batches``
+                       (every round body)
+``local_update``       the strategy's local update, entered by the layout
+                       context (``engine.schedule.LayoutCtx``) the engine
+                       hands the round body
 ``private_grad``       the private model's gradient in
                        ``P4Trainer._client_step``; on the stacked affine
                        step (``P4Trainer._affine_step``) its logit gradient
@@ -31,7 +33,8 @@ layer                  entered around
                        contraction xᵀ(s ⊙ dl)
 ``dp_noise``           the flat Eq. 11 noise draw and add
 ``aggregate``          the strategy's aggregation with the
-                       ``merge_participation`` calls around it
+                       ``merge_participation`` calls around it, entered by
+                       the layout context
 =====================  =====================================================
 
 ``op_layers()`` turns the scopes into a table: for the chunk executable the

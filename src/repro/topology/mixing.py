@@ -671,7 +671,7 @@ def _halo_mix(tree, plan: MixPlan, r, key, ctx, keep=None, halo=None):
     import jax
     import jax.numpy as jnp
     sched = halo_schedule(plan, ctx)
-    local_idx = ctx.shard_rows(
+    local_idx = ctx.rows(
         _round_slice(sched.buf_idx, r, plan.period))    # (m, degree) slots
 
     def apply(mix_fn):
@@ -703,10 +703,10 @@ def _halo_mix(tree, plan: MixPlan, r, key, ctx, keep=None, halo=None):
     M, d = plan.M, plan.degree
     s_full, w_full = _fault_adjusted_rows(
         plan, _round_slice(plan.nbr_np, r, plan.period), r, key, keep=keep)
-    s_row = ctx.shard_rows(jnp.concatenate(
+    s_row = ctx.rows(jnp.concatenate(
         [s_full, jnp.ones((ctx.M_pad - M,), s_full.dtype)]) if ctx.M_pad != M
         else s_full)
-    w_row = ctx.shard_rows(jnp.concatenate(
+    w_row = ctx.rows(jnp.concatenate(
         [w_full, jnp.zeros((ctx.M_pad - M, d), w_full.dtype)])
         if ctx.M_pad != M else w_full)
 
@@ -747,7 +747,7 @@ def _local_mix(tree, plan: MixPlan, r, key, ctx, keep=None):
     nbr_pad = _pad_rows_np(plan.nbr_np[0].astype(np.int32), ctx.M_pad, 0)
     for i in range(M, ctx.M_pad):
         nbr_pad[i] = i          # padded slots reference themselves
-    local_nbr = (ctx.shard_rows(jnp.asarray(nbr_pad))
+    local_nbr = (ctx.rows(jnp.asarray(nbr_pad))
                  - ctx.shard_offset())
 
     if plan.uniform is not None and not plan.faulty and keep is None:
@@ -763,10 +763,10 @@ def _local_mix(tree, plan: MixPlan, r, key, ctx, keep=None):
 
     s_full, w_full = _fault_adjusted_rows(plan, jnp.asarray(plan.nbr_np[0]),
                                           r, key, keep=keep)
-    s_row = ctx.shard_rows(jnp.concatenate(
+    s_row = ctx.rows(jnp.concatenate(
         [s_full, jnp.ones((ctx.M_pad - M,), s_full.dtype)]) if ctx.M_pad != M
         else s_full)
-    w_row = ctx.shard_rows(jnp.concatenate(
+    w_row = ctx.rows(jnp.concatenate(
         [w_full, jnp.zeros((ctx.M_pad - M, d), w_full.dtype)])
         if ctx.M_pad != M else w_full)
 

@@ -105,8 +105,8 @@ def test_prng_streams_invariant_to_slot_permutation(key, rng):
         clip = np.minimum(cohort_ids, M - 1)
         valid = (cohort_ids < M).astype(np.float32)
         with ctx.installed(jnp.asarray(cohort_ids), jnp.asarray(valid)):
-            ks = np.asarray(ctx.cohort_keys(key))
-            xs, ys = ctx.sample_cohort_batches(
+            ks = np.asarray(ctx.client_keys(key))
+            xs, ys = ctx.sample_batches(
                 jnp.asarray(tx[clip]), jnp.asarray(ty[clip]), key, B)
         return ks, np.asarray(xs), np.asarray(ys)
 
