@@ -17,8 +17,13 @@
   whose logits are an affine map of its input (z = x·w + b), in closed form
   from the per-example logit gradients: no per-example parameter gradient
   is built. It is returned flat, the layout ``P4Trainer``'s stacked affine
-  step updates the proxy in. The ``dp.path`` probe counts which route each
-  trace took.
+  step updates the proxy in.
+* ``dp_ghost_gradients`` — the same for a model that exposes its layers
+  (``small_models.LayerSeam``): each example's squared gradient norm as a
+  sum of per-layer ghost norms from one batched forward and backward, and
+  the clipped mean as one batched backward weighted by the clip scales. No
+  per-example parameter gradient is built. The ``dp.path`` probe counts
+  which route each trace took.
 """
 from __future__ import annotations
 
@@ -168,7 +173,8 @@ def calibrate_sigma(target_eps: float, delta: float, q: float, steps: int,
 
 #: Which DP route each trace took: one count per traced call of a route.
 DP_PATH = Probe("dp.path", {"affine_closed_form": 0, "per_example": 0,
-                            "microbatch": 0, "affine_stacked": 0})
+                            "microbatch": 0, "affine_stacked": 0,
+                            "ghost_norms": 0})
 
 
 def _per_example_grad_fn(loss_fn: Callable):
@@ -295,3 +301,90 @@ def dp_affine_flat(x, dl, key, *, clip: float, sigma: float):
     with layer("dp_noise"):
         return add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
                               float(n))
+
+
+# ---------------------------------------------------------------------------
+# Per-layer ghost norms
+# ---------------------------------------------------------------------------
+
+def _conv3x3_sq_norms(a, g):
+    """Per-example ‖∇W‖² of a 3x3 SAME, stride-1 convolution (no bias) from
+    its input a (n, C, H, W) and output gradient g (n, O, H, W), without
+    the (O, C, 3, 3) gradient: ∇W[:, :, k] = Σ_t g_t a_{t+k}ᵀ over output
+    positions t, so ‖∇W‖² = Σ_k Σ_{t,t'} (a_{t+k}·a_{t'+k})(g_t·g_{t'}):
+    the input's position Gram P, zero-padded by one position, read in nine
+    shifted windows against the output gradient's Gram."""
+    n, _, H, W = a.shape
+    hi = jax.lax.Precision.HIGHEST
+    a = a.reshape(n, a.shape[1], H * W).astype(jnp.float32)
+    g = g.reshape(n, g.shape[1], H * W).astype(jnp.float32)
+    P = jnp.einsum("bct,bcs->bts", a, a, precision=hi).reshape(n, H, W, H, W)
+    P = jnp.pad(P, ((0, 0),) + ((1, 1),) * 4)
+    G = jnp.einsum("bot,bos->bts", g, g, precision=hi).reshape(n, H, W, H, W)
+    return sum(jnp.sum(P[:, i:i + H, j:j + W, i:i + H, j:j + W] * G,
+                       axis=(1, 2, 3, 4))
+               for i in range(3) for j in range(3))
+
+
+def _dense_sq_norms(a, g):
+    """Per-example ‖∇w‖² + ‖∇b‖² of z = a·w + b: ‖g‖²·(1 + ‖a‖²)."""
+    a, g = a.astype(jnp.float32), g.astype(jnp.float32)
+    return jnp.sum(g * g, axis=-1) * (1.0 + jnp.sum(a * a, axis=-1))
+
+
+_GHOST_SQ_NORMS = {"conv3x3": _conv3x3_sq_norms, "dense": _dense_sq_norms}
+
+
+def ghost_sq_norms(kinds, inputs, grads):
+    """Each example's squared gradient norm, (n,): the sum over the layers
+    ``kinds`` names of the ghost norm from the layer's input and output
+    gradient."""
+    return sum(_GHOST_SQ_NORMS[kind](inputs[name], grads[name])
+               for name, kind in kinds.items())
+
+
+def dp_ghost_gradients(seam, params, x, logit_grads: Callable, key, *,
+                       clip: float, sigma: float, block: int = 0):
+    """``dp_gradients``' per-example result for a model with a layer seam
+    (``small_models.LayerSeam``) on the batch ``x``, with the logits it is
+    taken at: returns (gradient, logits). ``logit_grads`` maps the logits
+    (n, C) to each example's loss gradient with respect to its own logits.
+
+    One batched forward through the seam and one ``jax.vjp`` with those
+    gradients give every layer's input and every example's output gradient;
+    the per-layer ghost norms (at HIGHEST precision) sum to each example's
+    squared norm, in blocks of ``block`` examples where 0 < block < n; and
+    the clipped mean Σ sᵢ·gradᵢ is the same ``vjp`` called with s ⊙ dl,
+    which is exact because no layer mixes examples. The logits come from
+    the forward that is differentiated, so the caller needs no forward of
+    its own. The noise is the same ``add_flat_noise`` draw on the same flat
+    layout, so the same key gives bit-identical noise on both routes."""
+    DP_PATH["ghost_norms"] += 1
+    from repro.kernels.dp_clip.ref import add_flat_noise
+    n = x.shape[0]
+    logits, vjp, inputs = jax.vjp(lambda p, t: seam.forward(p, x, t), params,
+                                  seam.zero_taps(n), has_aux=True)
+    dl = logit_grads(logits).astype(jnp.float32)
+    with layer("per_example_grads"):
+        _, grads = vjp(dl)
+        if block and block < n:
+            assert n % block == 0, (n, block)
+
+            def block_sq(i):
+                # sliced in place: a block-major copy of the inputs would
+                # relayout the whole batch
+                part = jax.tree_util.tree_map(
+                    lambda t: jax.lax.dynamic_slice_in_dim(t, i * block,
+                                                           block),
+                    (inputs, grads))
+                return ghost_sq_norms(seam.kinds, *part)
+            sq = jax.lax.map(block_sq, jnp.arange(n // block)).reshape(n)
+        else:
+            sq = ghost_sq_norms(seam.kinds, inputs, grads)
+    with layer("dp_clip"):
+        scales = jnp.minimum(1.0, clip / jnp.maximum(jnp.sqrt(sq), 1e-12)) / float(n)
+        mean, _ = vjp(dl * scales[:, None])
+    with layer("dp_noise"):
+        out = add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
+                             float(n))
+    return tree_unflatten_concat(out, params), logits

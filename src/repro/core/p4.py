@@ -108,6 +108,25 @@ class P4Trainer:
         return (dpc.enabled and not dpc.microbatches
                 and self.apply_fn is linear_apply)
 
+    def _ghost_route(self) -> bool:
+        """True where the proxy's DP gradient takes the per-layer ghost-norm
+        route: per-example DP (no microbatches) on a model whose apply
+        carries a layer seam (``small_models.LayerSeam``)."""
+        dpc = self.cfg.dp
+        return (dpc.enabled and not dpc.microbatches
+                and getattr(self.apply_fn, "layer_seam", None) is not None)
+
+    def _proxy_logit_grads(self, proxy_logits, private_logits, y):
+        """Each example's gradient of its own proxy loss (Eq. 8) with
+        respect to its proxy logits, (B, C)."""
+        p4c = self.cfg.p4
+
+        def one_loss(z, t, label):
+            return distill.proxy_loss(z[None], t[None], label[None],
+                                      p4c.alpha, p4c.distill_temperature)
+        return jax.vmap(jax.grad(one_loss))(
+            proxy_logits, jax.lax.stop_gradient(private_logits), y)
+
     def _losses(self, private_logits, proxy_logits, y):
         """The round's metrics: Eqs. 9 and 8 on the given logits."""
         p4c = self.cfg.p4
@@ -136,9 +155,20 @@ class P4Trainer:
         if self._affine_route():
             return self._affine_step(private, proxy, x, y, key, lr)
         p4c, dpc = self.cfg.p4, self.cfg.dp
+        ghost = self._ghost_route()
 
         private_logits = self.apply_fn(private, x)
-        proxy_logits = self.apply_fn(proxy, x)
+        if ghost:
+            # proxy model: DP gradient of Eq. 8 from per-layer ghost norms,
+            # at the logits of the forward it differentiates
+            with layer("proxy_dp_grad"):
+                g_prox, proxy_logits = dp_lib.dp_ghost_gradients(
+                    self.apply_fn.layer_seam, proxy, x,
+                    lambda z: self._proxy_logit_grads(z, private_logits, y),
+                    key, clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
+                    block=dpc.per_example_chunk)
+        else:
+            proxy_logits = self.apply_fn(proxy, x)
 
         # private model: clean gradient of Eq. 9
         def private_obj(theta):
@@ -148,23 +178,24 @@ class P4Trainer:
         with layer("private_grad"):
             g_priv = jax.grad(private_obj)(private)
 
-        # proxy model: DP gradient of Eq. 8
+        # proxy model: DP gradient of Eq. 8 (the ghost route's is above)
         def proxy_obj(w, batch):
             lg = self.apply_fn(w, batch["x"])
             tgt = self.apply_fn(jax.lax.stop_gradient(private), batch["x"])
             return distill.proxy_loss(lg, tgt, batch["y"], p4c.alpha,
                                       p4c.distill_temperature)
-        with layer("proxy_dp_grad"):
-            if dpc.enabled:
-                g_prox = dp_lib.dp_gradients(
-                    proxy_obj, proxy, {"x": x, "y": y}, key,
-                    clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
-                    microbatches=dpc.microbatches,
-                    per_example_chunk=dpc.per_example_chunk,
-                    kernels=self.cfg.kernels)
-            else:
-                g_prox = jax.grad(
-                    lambda w: proxy_obj(w, {"x": x, "y": y}))(proxy)
+        if not ghost:
+            with layer("proxy_dp_grad"):
+                if dpc.enabled:
+                    g_prox = dp_lib.dp_gradients(
+                        proxy_obj, proxy, {"x": x, "y": y}, key,
+                        clip=dpc.clip_norm, sigma=runtime_sigma(self.sigma),
+                        microbatches=dpc.microbatches,
+                        per_example_chunk=dpc.per_example_chunk,
+                        kernels=self.cfg.kernels)
+                else:
+                    g_prox = jax.grad(
+                        lambda w: proxy_obj(w, {"x": x, "y": y}))(proxy)
 
         new_private = jax.tree_util.tree_map(lambda p, g: p - lr * g, private, g_priv)
         new_proxy = jax.tree_util.tree_map(lambda p, g: p - lr * g, proxy, g_prox)
@@ -194,13 +225,8 @@ class P4Trainer:
                                       dl_priv,
                                       precision=jax.lax.Precision.HIGHEST)}
 
-        def one_loss(z, t, label):
-            return distill.proxy_loss(z[None], t[None], label[None],
-                                      p4c.alpha, temp)
         with layer("proxy_dp_grad"):
-            # each example's logit gradient of its own loss (Eq. 8)
-            dl_prox = jax.vmap(jax.grad(one_loss))(
-                proxy_logits, jax.lax.stop_gradient(private_logits), y)
+            dl_prox = self._proxy_logit_grads(proxy_logits, private_logits, y)
             g_prox = dp_lib.dp_affine_flat(x, dl_prox, key,
                                            clip=dpc.clip_norm,
                                            sigma=runtime_sigma(self.sigma))

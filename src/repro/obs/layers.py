@@ -27,10 +27,13 @@ layer                  entered around
                        updated models
 ``per_example_grads``  the per-example ``vmap`` of the gradient and its
                        flatten to the (c, D) stack in ``core.dp.dp_gradients``;
-                       the per-row ‖x‖² in ``core.dp.dp_affine_flat``
+                       the per-row ‖x‖² in ``core.dp.dp_affine_flat``; the
+                       output-gradient backward and the per-layer ghost
+                       norms in ``core.dp.dp_ghost_gradients``
 ``dp_clip``            ``kernels.dispatch.clip_accumulate`` / ``dp_clip``;
                        the closed form's norms, scales and the proxy's
-                       contraction xᵀ(s ⊙ dl)
+                       contraction xᵀ(s ⊙ dl); the ghost route's scales and
+                       its backward weighted by them
 ``dp_noise``           the flat Eq. 11 noise draw and add
 ``aggregate``          the strategy's aggregation with the
                        ``merge_participation`` calls around it, entered by

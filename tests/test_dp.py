@@ -175,3 +175,105 @@ def test_affine_closed_form_matches_per_example(key, alpha, temperature,
                * np.max(np.abs(np.asarray(pe1[name]))))
         assert np.max(np.abs(noise_pe)) > 1e3 * ulp
         np.testing.assert_allclose(noise_cf, noise_pe, rtol=0, atol=4 * ulp)
+
+
+def _cnn_case(key, shape, n=8, classes=4, width=4):
+    """A small CNN with its layer seam, a batch scaled so that examples'
+    gradient norms spread, and each example's logit gradient of P4's proxy
+    loss (Eq. 8) against a second model's logits."""
+    from repro.core import distill
+    from repro.core.small_models import make_cnn
+    from repro.models.module import init_params
+    specs, apply = make_cnn(shape, classes, width=width)
+    proxy = init_params(specs, jax.random.fold_in(key, 3))
+    private = init_params(specs, jax.random.fold_in(key, 5))
+    x = (jax.random.normal(jax.random.fold_in(key, 1), (n,) + shape)
+         * jnp.linspace(0.05, 3.0, n)[:, None, None, None])
+    y = jax.random.randint(jax.random.fold_in(key, 2), (n,), 0, classes)
+
+    def proxy_obj(w, batch):
+        return distill.proxy_loss(apply(w, batch["x"]),
+                                  apply(private, batch["x"]),
+                                  batch["y"], 0.5)
+
+    def one_loss(z, t, label):
+        return distill.proxy_loss(z[None], t[None], label[None], 0.5)
+
+    dl = jax.vmap(jax.grad(one_loss))(apply(proxy, x), apply(private, x), y)
+    return apply, proxy, proxy_obj, x, y, dl
+
+
+@pytest.mark.parametrize("shape", [(3, 4, 4), (5, 8, 4)])
+def test_ghost_layer_norms_match_per_example_gradients(key, shape):
+    """Each layer's ghost norm, from the layer's input and output gradient,
+    equals the norm of that layer's explicit per-example gradient, the
+    SAME-padding edge positions included (at H = W = 4 every output
+    position of the second convolution touches the padding)."""
+    apply, proxy, proxy_obj, x, y, dl = _cnn_case(key, shape)
+    seam = apply.layer_seam
+    n = x.shape[0]
+    per_ex = jax.vmap(lambda xi, yi: jax.grad(proxy_obj)(
+        proxy, {"x": xi[None], "y": yi[None]}))(x, y)
+    _, vjp, inputs = jax.vjp(lambda p, t: seam.forward(p, x, t), proxy,
+                             seam.zero_taps(n), has_aux=True)
+    _, grads = vjp(dl)
+    params_of = {"c1": ("c1",), "c2": ("c2",), "head": ("w", "b")}
+    assert set(seam.kinds) == set(params_of)
+    for name, kind in seam.kinds.items():
+        want = sum(np.sum(np.square(np.asarray(per_ex[k]).reshape(n, -1)), -1)
+                   for k in params_of[name])
+        got = dp_lib.ghost_sq_norms({name: kind}, inputs, grads)
+        assert got.shape == (n,)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5)
+    total = dp_lib.ghost_sq_norms(seam.kinds, inputs, grads)
+    flat = sum(np.sum(np.square(np.asarray(v).reshape(n, -1)), -1)
+               for v in per_ex.values())
+    np.testing.assert_allclose(np.asarray(total), flat, rtol=2e-5)
+
+
+@pytest.mark.parametrize("chunk", [0, 4])
+@pytest.mark.parametrize("shape", [(3, 4, 4), (5, 8, 4)])
+def test_ghost_norm_route_matches_per_example(key, shape, chunk):
+    """The CNN's DP gradient from per-layer ghost norms equals the
+    per-example route's on P4's proxy loss, clipped and unclipped examples
+    alike, with the norms in one batch or in blocks, and draws the same
+    noise from the same key."""
+    apply, proxy, proxy_obj, x, y, dl = _cnn_case(key, shape)
+    n = x.shape[0]
+    per_ex = jax.vmap(lambda xi, yi: jax.grad(proxy_obj)(
+        proxy, {"x": xi[None], "y": yi[None]}))(x, y)
+    norms = np.sqrt(sum(np.sum(np.square(np.asarray(v).reshape(n, -1)), -1)
+                        for v in per_ex.values()))
+    clip = float(np.median(norms))
+    assert (norms > 1.01 * clip).any() and (norms < 0.99 * clip).any()
+    k = jax.random.fold_in(key, 6)
+
+    def both(sigma):
+        pe = dp_lib.dp_gradients(proxy_obj, proxy, {"x": x, "y": y}, k,
+                                 clip=clip, sigma=sigma,
+                                 per_example_chunk=chunk)
+        gh, logits = dp_lib.dp_ghost_gradients(
+            apply.layer_seam, proxy, x, lambda z: dl, k, clip=clip,
+            sigma=sigma, block=chunk)
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(apply(proxy, x)), rtol=1e-6,
+                                   atol=1e-6)
+        return pe, gh
+
+    pe0, gh0 = both(0.0)
+    pe1, gh1 = both(1.3)
+    for name in proxy:
+        assert gh0[name].shape == pe0[name].shape
+        assert gh0[name].dtype == pe0[name].dtype
+        # float32 sums over examples and positions in another order: an
+        # error of a few ulps of the gradient's largest entry
+        scale = float(np.max(np.abs(np.asarray(pe0[name]))))
+        np.testing.assert_allclose(np.asarray(gh0[name]),
+                                   np.asarray(pe0[name]), rtol=1e-5,
+                                   atol=1e-5 * scale)
+        noise_pe = np.asarray(pe1[name]) - np.asarray(pe0[name])
+        noise_gh = np.asarray(gh1[name]) - np.asarray(gh0[name])
+        ulp = (np.finfo(np.float32).eps
+               * np.max(np.abs(np.asarray(pe1[name]))))
+        assert np.max(np.abs(noise_pe)) > 1e3 * ulp
+        np.testing.assert_allclose(noise_gh, noise_pe, rtol=0, atol=4 * ulp)

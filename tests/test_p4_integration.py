@@ -117,7 +117,8 @@ def test_p4_lm_step_runs_and_loss_finite(key):
 @pytest.mark.parametrize("model", ["linear", "cnn"])
 def test_dp_route_follows_model_structure(key, model):
     """The affine linear model's proxy takes the closed-form DP route; the
-    CNN keeps the per-example route (read from the ``dp.path`` probe)."""
+    CNN, whose apply carries a layer seam, takes the ghost-norm route (read
+    from the ``dp.path`` probe)."""
     from repro.obs import probe_deltas
     xs, ys = _toy_tasks(M=4, feat=16)
     kw = {"cnn_shape": (1, 4, 4)} if model == "cnn" else {}
@@ -133,7 +134,8 @@ def test_dp_route_follows_model_structure(key, model):
     if model == "linear":
         assert routes["affine_closed_form"] > 0 and routes["per_example"] == 0
     else:
-        assert routes["per_example"] > 0 and routes["affine_closed_form"] == 0
+        assert routes["ghost_norms"] > 0 and routes["per_example"] == 0
+        assert routes["affine_closed_form"] == 0
 
 
 @pytest.mark.parametrize("local_steps", [1, 2])
@@ -194,5 +196,51 @@ def test_linear_closed_form_step_matches_per_example(key, chunk, local_steps):
     for name in ("private", "proxy"):
         moved = jax.tree_util.tree_map(
             lambda a, b: float(jnp.max(jnp.abs(a - b))), s_cf[name],
+            start[name])
+        assert min(jax.tree_util.tree_leaves(moved)) > 1e-3
+
+
+@pytest.mark.parametrize("local_steps", [1, 2])
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_cnn_ghost_step_matches_per_example(key, chunk, local_steps):
+    """Two DP co-training rounds of the CNN trainer on the ghost-norm route
+    agree with the same rounds on the per-example route (the same CNN
+    behind a wrapper that carries no layer seam): both models' states and
+    the round's losses."""
+    from repro.obs import probe_deltas
+    xs, ys = _toy_tasks(M=4, feat=16)
+    cfg = _run_cfg(dp=DPConfig(epsilon=15.0, rounds=40, sample_rate=0.5,
+                               clip_norm=1.0, per_example_chunk=chunk,
+                               local_steps=local_steps))
+    ghost = P4Trainer(feat_dim=16, num_classes=4, cfg=cfg, model="cnn",
+                      cnn_shape=(1, 4, 4))
+    per_ex = P4Trainer(feat_dim=16, num_classes=4, cfg=cfg, model="cnn",
+                       cnn_shape=(1, 4, 4))
+    seamed = per_ex.apply_fn
+    per_ex.apply_fn = lambda p, x: seamed(p, x)
+    xb, yb = jnp.asarray(xs[:, :16]), jnp.asarray(ys[:, :16])
+    s_gh = s_pe = ghost.init_clients(key, 4)
+    with probe_deltas("dp.path") as d_gh:
+        for r in range(2):
+            s_gh, m_gh = ghost.local_round(s_gh, xb, yb,
+                                           jax.random.fold_in(key, r))
+    with probe_deltas("dp.path") as d_pe:
+        for r in range(2):
+            s_pe, m_pe = per_ex.local_round(s_pe, xb, yb,
+                                            jax.random.fold_in(key, r))
+    assert d_gh["dp.path"]["ghost_norms"] > 0
+    assert d_gh["dp.path"]["per_example"] == 0
+    assert d_pe["dp.path"]["ghost_norms"] == 0
+    assert d_pe["dp.path"]["per_example"] > 0
+    for a, b in zip(jax.tree_util.tree_leaves((s_gh, m_gh)),
+                    jax.tree_util.tree_leaves((s_pe, m_pe))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+    # both models moved: the private gradient and the proxy's DP gradient
+    # took effect
+    start = ghost.init_clients(key, 4)
+    for name in ("private", "proxy"):
+        moved = jax.tree_util.tree_map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), s_gh[name],
             start[name])
         assert min(jax.tree_util.tree_leaves(moved)) > 1e-3
