@@ -41,6 +41,21 @@ class MoEConfig:
     shared_expert: bool = False     # llama4-style shared expert alongside routed
     capacity_factor: float = 0.0    # 0 => dense (masked einsum) dispatch
     dispatch: str = "global"        # "local" = per-data-shard dispatch (§Perf)
+    # expert share (models/moe.py ``expert_share``): this chip holds experts
+    # [held_first, held_first + held_count) of ``num_experts``; 0 => all of
+    # them, under the capacity dispatch above
+    held_first: int = 0
+    held_count: int = 0
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (arXiv:2309.00071) as HF ``rope_parameters`` give it."""
+    factor: float = 1.0
+    original_max_position: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0   # cos and sin are scaled by it
 
 
 @dataclass(frozen=True)
@@ -71,7 +86,12 @@ class ModelConfig:
     rope_theta: float = 10000.0
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl M-RoPE (t, h, w) split of head_dim/2
     window: int = 0                 # sliding-window size; 0 => full attention
-    swa_every: int = 1              # 1 => all layers windowed when window>0; mixtral=1
+    # per-layer attention types, one period scanned over the depth:
+    # "sliding" layers use ``window``, "full" layers attend to every earlier
+    # position and take ``yarn`` where it is set; () => every layer "sliding"
+    # (full attention where ``window`` is 0)
+    layer_pattern: Tuple[str, ...] = ()
+    yarn: Optional[YarnConfig] = None
     # beyond-paper perf knob: pad query heads up to a mesh-divisible count so
     # attention shards over the model axis (e.g. qwen3/llama4: 40 -> 48).
     # Zero-extra-capacity heads: a strict superset model, HLO-validated in

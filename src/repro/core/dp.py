@@ -24,6 +24,15 @@
   the clipped mean as one batched backward weighted by the clip scales. No
   per-example parameter gradient is built. The ``dp.path`` probe counts
   which route each trace took.
+* ``dp_layer_clipped_sum`` — the same clipped sum for a sequence model
+  whose layers take their own per-example norms in the backward pass
+  (``tracked_dense``, ``tracked_scale``, ``tracked_embed`` and the expert
+  share of ``models.moe``): one forward, one backward with each example's
+  logit gradient that leaves every example's squared norm on a probe, and
+  the backward with s ⊙ dl. Storing every layer's input and output
+  gradient, as ``dp_ghost_gradients`` does, would hold about 4 GB for one
+  8,192-token sequence of a four-layer MoE model; here each layer's norm is
+  taken while its backward runs, from the layer's per-example gradient.
 """
 from __future__ import annotations
 
@@ -131,14 +140,16 @@ def rdp_increment(q: float, sigma: float, alpha: float) -> float:
     """Per-step RDP of the subsampled Gaussian at order ``alpha``.
 
     Additive across steps and across segments with different q — the unit
-    the PrivacyLedger accumulates. Orders unusable under subsampling (the
-    computable bound needs integer α ≥ 2 when q < 1) return ``inf`` so they
-    drop out of the min without special-casing at the call site."""
+    the PrivacyLedger accumulates. Subsampling never raises a mechanism's
+    Rényi divergence (joint quasi-convexity), so at q < 1 the Gaussian's own
+    RDP bounds every order, and the computable subsampled bound (integer
+    α ≥ 2) tightens it where it applies: ε never grows as q falls."""
     if q >= 1.0:
         return _rdp_gaussian(sigma, alpha)
     if alpha == int(alpha) and alpha >= 2:
-        return _rdp_subsampled(q, sigma, int(alpha))
-    return math.inf
+        return min(_rdp_subsampled(q, sigma, int(alpha)),
+                   _rdp_gaussian(sigma, alpha))
+    return _rdp_gaussian(sigma, alpha)
 
 
 def rdp_to_epsilon(rdp: float, alpha: float, delta: float) -> float:
@@ -174,7 +185,7 @@ def calibrate_sigma(target_eps: float, delta: float, q: float, steps: int,
 #: Which DP route each trace took: one count per traced call of a route.
 DP_PATH = Probe("dp.path", {"affine_closed_form": 0, "per_example": 0,
                             "microbatch": 0, "affine_stacked": 0,
-                            "ghost_norms": 0})
+                            "ghost_norms": 0, "layer_norms": 0})
 
 
 def _per_example_grad_fn(loss_fn: Callable):
@@ -388,3 +399,143 @@ def dp_ghost_gradients(seam, params, x, logit_grads: Callable, key, *,
         out = add_flat_noise(tree_flatten_concat(mean), key, sigma, clip,
                              float(n))
     return tree_unflatten_concat(out, params), logits
+
+
+# ---------------------------------------------------------------------------
+# Per-layer norms of a sequence model, taken in its backward pass
+# ---------------------------------------------------------------------------
+
+def seq_dense_sq_norms(a, g):
+    """Per-example ‖aᵢᵀgᵢ‖², (n,), of z = a·w on sequences a (n, s, d_in)
+    with output gradients g (n, s, d_out): each example's gradient aᵢᵀgᵢ
+    (d_in·d_out floats, at the default precision, as the layer's own
+    gradient), then its squares. At s = 8,192 tokens this costs
+    s·d_in·d_out against the s²·(d_in + d_out) of the ghost form
+    ⟨aaᵀ, ggᵀ⟩ for every layer of the language model in ``core/lm.py``."""
+    a, g = a.astype(jnp.float32), g.astype(jnp.float32)
+    return jnp.sum(jnp.square(jnp.einsum("nsi,nso->nio", a, g)), axis=(1, 2))
+
+
+def _probe_sq(probe, sq):
+    """The probe's cotangent: each example's squared norm ``sq()``, or
+    zeros where the probe is empty (a forward that takes no norms)."""
+    if probe.shape[0] == 0:
+        return jnp.zeros_like(probe)
+    with layer("per_example_grads"):
+        return sq()
+
+
+@jax.custom_vjp
+def tracked_dense(a, w, probe):
+    """a (n, s, d_in) · w (d_in, d_out); its backward adds each example's
+    ‖∇w‖² to the cotangent of ``probe`` (n,) (none where the probe has
+    length 0)."""
+    return jnp.einsum("nsi,io->nso", a, w)
+
+
+def _tracked_dense_fwd(a, w, probe):
+    return jnp.einsum("nsi,io->nso", a, w), (a, w, probe)
+
+
+def _tracked_dense_bwd(res, g):
+    a, w, probe = res
+    return (jnp.einsum("nso,io->nsi", g, w), jnp.einsum("nsi,nso->io", a, g),
+            _probe_sq(probe, lambda: seq_dense_sq_norms(a, g)))
+
+
+tracked_dense.defvjp(_tracked_dense_fwd, _tracked_dense_bwd)
+
+
+@jax.custom_vjp
+def tracked_scale(xhat, scale, probe):
+    """xhat (n, s, d) ⊙ scale (d,), RMSNorm's learned scale; its backward
+    adds each example's ‖Σₜ gₜ ⊙ x̂ₜ‖² to the cotangent of ``probe``."""
+    return xhat * scale
+
+
+def _tracked_scale_fwd(xhat, scale, probe):
+    return xhat * scale, (xhat, scale, probe)
+
+
+def _tracked_scale_bwd(res, g):
+    xhat, scale, probe = res
+    per_ex = jnp.sum(g * xhat, axis=1)                     # (n, d)
+    return (g * scale, jnp.sum(per_ex, axis=0),
+            _probe_sq(probe, lambda: jnp.sum(jnp.square(per_ex), axis=-1)))
+
+
+tracked_scale.defvjp(_tracked_scale_fwd, _tracked_scale_bwd)
+
+
+@jax.custom_vjp
+def tracked_embed(table, tokens, probe):
+    """Rows ``tokens`` (n, s) of ``table`` (V, d); its backward adds each
+    example's Σᵥ ‖Σ_{t: token t = v} gₜ‖² (the direct form: the example's
+    own (V, d) gradient, one example at a time) to the cotangent of
+    ``probe``."""
+    return jnp.take(table, tokens, axis=0)
+
+
+def _tracked_embed_fwd(table, tokens, probe):
+    return jnp.take(table, tokens, axis=0), (table, tokens, probe)
+
+
+def _tracked_embed_bwd(res, g):
+    table, tokens, probe = res
+    V = table.shape[0]
+    d_table = jax.ops.segment_sum(g.reshape(-1, g.shape[-1]),
+                                  tokens.reshape(-1), V)
+    return d_table, None, _probe_sq(probe, lambda: jax.lax.map(
+        lambda tg: jnp.sum(jnp.square(jax.ops.segment_sum(tg[1], tg[0], V))),
+        (tokens, g)))
+
+
+tracked_embed.defvjp(_tracked_embed_fwd, _tracked_embed_bwd)
+
+
+def add_noise_by_leaf(tree, key, sigma, clip: float, denom: float):
+    """``add_flat_noise`` on ``tree``'s flat layout (leaves in sorted key
+    order, each raveled), added leaf by leaf: the same draw and the same
+    values, without a flat copy of the tree."""
+    from repro.kernels.dp_clip.ref import static_zero_sigma
+    if static_zero_sigma(sigma):
+        return tree
+    if key is None:
+        raise ValueError("sigma > 0 requires a PRNG key (refusing to return "
+                         "unnoised gradients from a DP path)")
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    z = jax.random.normal(key, (sum(t.size for t in leaves),), jnp.float32)
+    scale = jnp.float32(2.0 * clip / denom) * jnp.asarray(sigma, jnp.float32)
+    out, off = [], 0
+    for t in leaves:
+        out.append(t + scale * z[off:off + t.size].reshape(t.shape))
+        off += t.size
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def dp_layer_clipped_sum(forward: Callable, params, x, logit_grads: Callable,
+                         *, clip: float, denom: float):
+    """Σᵢ sᵢ·gradᵢ / ``denom`` over the examples of ``x`` (no noise), with
+    sᵢ = min(1, C/‖gradᵢ‖) (Eq. 10), and the logits it is taken at: returns
+    (sum, logits). ``forward(params, x, probe)`` is the model with every
+    parametric layer built from this module's tracked layers (or one that
+    likewise adds its per-example squared norms to the probe's cotangent);
+    ``logit_grads`` maps the logits to each example's loss gradient with
+    respect to its own logits.
+
+    One forward and one ``jax.vjp``: the backward with dl gives every
+    example's squared norm on the probe (its parameter gradients are
+    dropped), the backward with s ⊙ dl the clipped sum, exact because no
+    layer mixes examples. A caller sums blocks of examples and adds the
+    noise once (``kernels.dp_clip.ref.add_flat_noise``)."""
+    DP_PATH["layer_norms"] += 1
+    n = x.shape[0]
+    logits, vjp = jax.vjp(lambda p, z: forward(p, x, z), params,
+                          jnp.zeros((n,), jnp.float32))
+    dl = logit_grads(logits).astype(jnp.float32)
+    with layer("per_example_grads"):
+        _, sq = vjp(dl)
+    with layer("dp_clip"):
+        scales = jnp.minimum(1.0, clip / jnp.maximum(jnp.sqrt(sq), 1e-12)) / denom
+        total, _ = vjp(dl * scales.reshape((n,) + (1,) * (dl.ndim - 1)))
+    return total, logits

@@ -5,6 +5,17 @@ Small-scale (paper-faithful): ``P4Trainer`` simulates M clients as stacked
 aggregation is a segment-mean over proxy parameters, grouping is the greedy
 decentralized procedure on first-step weights.
 
+A language model (``model="lm"``, ``core/lm.py``) takes the same path: each
+client's rows are int32 token sequences of s + 1 tokens (the model reads
+the first s, each position's label is its next token, and the label rows
+``y`` carry nothing; ``token_rows`` maps rows stored otherwise to these),
+one example is one sequence, Eqs. 8-9
+are applied per token and averaged over each sequence, the proxy's DP
+gradient takes the per-layer norm route (``dp.dp_layer_clipped_sum``), and
+every pass over a batch runs in blocks of ``per_example_chunk`` sequences,
+so activation memory is bounded by the block; clients are stepped one after
+another (``lax.map``), since one client's step fills the chip.
+
 LM-scale (framework feature): ``make_p4_lm_step`` builds one jitted step over
 G client *groups* (G = the ``pod`` mesh axis in multi-pod runs — DESIGN.md §4):
 parameters carry a leading G dim sharded over ``pod``; vmap over G makes every
@@ -15,7 +26,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,13 +79,22 @@ class P4Trainer:
     feat_dim: int
     num_classes: int
     cfg: RunConfig
-    model: str = "linear"                 # linear | cnn
+    model: str = "linear"                 # linear | cnn | lm
     cnn_shape: Optional[Tuple[int, int, int]] = None  # (C, H, W) for model=cnn
+    lm: Optional[dict] = None   # model=lm: ModelConfig fields (core.lm.lm_config)
+    # model=lm: the data's rows (b, ...) -> int32 token rows (b, s + 1), for
+    # data stored in another form; None: the rows are token rows
+    token_rows: Optional[Callable] = None
 
     def __post_init__(self):
+        self.lm_cfg = None
         if self.model == "linear":
             self.specs = linear_specs(self.feat_dim, self.num_classes)
             self.apply_fn = linear_apply
+        elif self.model == "lm":
+            from repro.core.lm import lm_config, make_lm
+            self.lm_cfg = lm_config(self.lm)
+            self.specs, self.apply_fn = make_lm(self.lm_cfg)
         else:
             self.specs, self.apply_fn = make_cnn(self.cnn_shape, self.num_classes)
         dpc = self.cfg.dp
@@ -154,6 +174,8 @@ class P4Trainer:
         """One local step for ONE client (vmapped across M)."""
         if self._affine_route():
             return self._affine_step(private, proxy, x, y, key, lr)
+        if self.lm_cfg is not None:
+            return self._lm_step(private, proxy, x, y, key, lr)
         p4c, dpc = self.cfg.p4, self.cfg.dp
         ghost = self._ghost_route()
 
@@ -237,6 +259,84 @@ class P4Trainer:
         return (new_private, new_proxy,
                 self._losses(private_logits, proxy_logits, y))
 
+    def _in_blocks(self, f, x, y, zeros):
+        """Σ over the batch's blocks of ``per_example_chunk`` sequences of
+        ``f(xb, yb)``, each block's value weighted by its share c/n of the
+        batch (a batch mean from block means); ``zeros`` is the sum's zero
+        tree. One block where the chunk is 0 or holds the batch."""
+        n = x.shape[0]
+        c = self.cfg.dp.per_example_chunk or n
+        if c >= n:
+            return f(x, y)
+        assert n % c == 0, (n, c)
+        xs, ys = (t.reshape((n // c, c) + t.shape[1:]) for t in (x, y))
+
+        def add(acc, xy):
+            return jax.tree_util.tree_map(
+                lambda a, v: a + v * (c / n), acc, f(*xy)), None
+        return jax.lax.scan(add, zeros, (xs, ys))[0]
+
+    def _lm_step(self, private, proxy, x, y, key, lr):
+        """``_client_step`` for a language model on token rows x (n, s + 1)
+        (``token_rows``), the labels of each row's first s tokens its next
+        ones, in blocks of ``per_example_chunk`` sequences. Each block runs one forward of each model: the private
+        model's gradient of Eq. 9 is its ``vjp`` with the block's logit
+        gradient (scope ``private_grad``), and the proxy's clipped sum of
+        Eq. 8 comes from ``dp.dp_layer_clipped_sum`` at its own logits
+        (scope ``proxy_dp_grad``). The noise is drawn once for the batch,
+        the flat draw of the other routes added leaf by leaf
+        (``dp.add_noise_by_leaf``), so no flat copy of the model is made."""
+        p4c, dpc = self.cfg.p4, self.cfg.dp
+        x = self._tokens(x)
+        n = x.shape[0]
+        c = min(dpc.per_example_chunk or n, n)
+        seam = self.apply_fn.layer_forward
+
+        def block(t, _):
+            xb, yb = t[:, :-1], t[:, 1:]
+            with layer("private_grad"):
+                pr_logits, pr_vjp = jax.vjp(
+                    lambda p: self.apply_fn(p, xb), private)
+            with layer("proxy_dp_grad"):
+                g_px, px_logits = dp_lib.dp_layer_clipped_sum(
+                    seam, proxy, xb,
+                    lambda z: self._proxy_logit_grads(z, pr_logits, yb),
+                    clip=dpc.clip_norm, denom=float(c))
+            with layer("private_grad"):
+                dl = jax.grad(distill.private_loss)(
+                    pr_logits, px_logits, yb, p4c.beta,
+                    p4c.distill_temperature)
+                # the private backward after the proxy's: XLA would
+                # otherwise run the two as one loop, with both backwards'
+                # temporaries live at once
+                dl, g_px = jax.lax.optimization_barrier((dl, g_px))
+                g_pr, = pr_vjp(dl)
+            return g_pr, g_px
+
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, (private, proxy))
+        g_pr, g_px = self._in_blocks(block, x, y, zeros)
+        with layer("proxy_dp_grad"), layer("dp_noise"):
+            g_px = dp_lib.add_noise_by_leaf(g_px, key, runtime_sigma(self.sigma),
+                                            dpc.clip_norm, float(n))
+        new_private = jax.tree_util.tree_map(lambda p, g: p - lr * g, private, g_pr)
+        new_proxy = jax.tree_util.tree_map(lambda p, g: p - lr * g, proxy, g_px)
+        return new_private, new_proxy, None
+
+    def _lm_losses(self, private, proxy, x, y):
+        """The round's metrics (Eqs. 9 and 8) of a language model, in blocks
+        of ``per_example_chunk`` sequences."""
+        def losses(t, _):
+            xb, yb = t[:, :-1], t[:, 1:]
+            return self._losses(self.apply_fn(private, xb),
+                                self.apply_fn(proxy, xb), yb)
+        zeros = {"private_loss": jnp.zeros((), jnp.float32),
+                 "proxy_loss": jnp.zeros((), jnp.float32)}
+        return self._in_blocks(losses, self._tokens(x), y, zeros)
+
+    def _tokens(self, x):
+        """A language model's int32 token rows (b, s + 1) of data rows x."""
+        return x if self.token_rows is None else self.token_rows(x)
+
     # ------------------------------------------------------------------
     def _local_round_keyed(self, states, xs, ys, keys):
         """K local steps, one PRNG key per client row (the seam the sharded
@@ -245,6 +345,7 @@ class P4Trainer:
         lr = self.cfg.train.learning_rate
         K = self.cfg.dp.local_steps
         affine = self._affine_route()
+        lm = self.lm_cfg is not None
 
         def one_client(private, proxy, x, y, ckey):
             def body(carry, k):
@@ -254,7 +355,9 @@ class P4Trainer:
                 return (pr, px), None
             (pr, px), _ = jax.lax.scan(body, (private, proxy), jnp.arange(K))
             with layer("metrics_step"):
-                if affine:
+                if lm:
+                    metrics = self._lm_losses(pr, px, x, y)
+                elif affine:
                     # one forward of both updated models
                     metrics = self._losses(*self._both_logits(pr, px, x), y)
                 else:
@@ -264,8 +367,11 @@ class P4Trainer:
                         pr, px, x, y, jax.random.fold_in(ckey, K), 0.0)
             return pr, px, metrics
 
-        priv, prox, metrics = jax.vmap(one_client)(
-            states["private"], states["proxy"], xs, ys, keys)
+        args = (states["private"], states["proxy"], xs, ys, keys)
+        if lm:
+            priv, prox, metrics = jax.lax.map(lambda a: one_client(*a), args)
+        else:
+            priv, prox, metrics = jax.vmap(one_client)(*args)
         return {"private": priv, "proxy": prox}, metrics
 
     def _local_round_impl(self, states, xs, ys, key):
@@ -425,6 +531,19 @@ class P4Trainer:
 
 @functools.partial(jax.jit, static_argnums=0)
 def _evaluate(trainer: P4Trainer, states, xs, ys):
+    if trainer.lm_cfg is not None:
+        # a language model: the share of test tokens whose next token is
+        # the argmax, client by client in blocks of sequences
+        def lm_one(a):
+            private, x, y = a
+
+            def hits(t, _):
+                pred = jnp.argmax(trainer.apply_fn(private, t[:, :-1]), -1)
+                return jnp.mean((pred == t[:, 1:]).astype(jnp.float32))
+            return trainer._in_blocks(hits, trainer._tokens(x), y,
+                                      jnp.zeros((), jnp.float32))
+        return jax.lax.map(lm_one, (states["private"], xs, ys))
+
     def one(private, x, y):
         return accuracy(trainer.apply_fn(private, x), y)
     return jax.vmap(one)(states["private"], xs, ys)
@@ -659,7 +778,8 @@ class P4Strategy(Strategy):
                   else tuple(tuple(g) for g in self.groups))
         topo = None if self.topology is None else self.topology.fingerprint()
         return ("p4", self.cache_token, t.model, t.feat_dim, t.num_classes,
-                t.cnn_shape, cfg.p4, cfg.kernels, cfg.train.learning_rate,
+                t.cnn_shape, t.lm_cfg, t.token_rows, cfg.p4, cfg.kernels,
+                cfg.train.learning_rate,
                 cfg.dp.enabled, cfg.dp.clip_norm, cfg.dp.local_steps,
                 cfg.dp.microbatches, cfg.dp.per_example_chunk,
                 isinstance(t.sigma, (int, float)) and t.sigma > 0,

@@ -77,7 +77,9 @@ def _unless_empty(mask, state, new):
 
 
 def sample_client_batches(train_x, train_y, key, batch_size: Optional[int]):
-    """Per-client minibatches drawn on device: (M, B, ...), (M, B).
+    """Per-client minibatches drawn on device: (M, B, ...), (M, B), from
+    rows (M, R, ...) of any rank: feature rows, or a language model's
+    token rows (M, R, s + 1), each with its next tokens.
 
     ``batch_size=None`` means full-batch (returns the stacks unchanged —
     used by P4's bootstrap phase, which trains on the whole local dataset).

@@ -71,7 +71,8 @@ def _pv(p, v):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
 
 
-def _project_qkv(params, x, cfg: ModelConfig, positions, mrope_positions):
+def _project_qkv(params, x, cfg: ModelConfig, positions, mrope_positions,
+                 rope=(None, 1.0)):
     dtype = x.dtype
     hd = cfg.resolved_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
@@ -84,8 +85,11 @@ def _project_qkv(params, x, cfg: ModelConfig, positions, mrope_positions):
         q = rope_lib.apply_mrope(q, mrope_positions, cfg.mrope_sections, cfg.rope_theta)
         k = rope_lib.apply_mrope(k, mrope_positions, cfg.mrope_sections, cfg.rope_theta)
     else:
-        q = rope_lib.apply_rope(q, positions, cfg.rope_theta)
-        k = rope_lib.apply_rope(k, positions, cfg.rope_theta)
+        freqs, scale = rope
+        q = rope_lib.apply_rope(q, positions, cfg.rope_theta, freqs=freqs,
+                                scale=scale)
+        k = rope_lib.apply_rope(k, positions, cfg.rope_theta, freqs=freqs,
+                                scale=scale)
     q = shard_act(q, ("batch", "seq", "heads", "head_dim"))
     k = shard_act(k, ("batch", "seq", "kv_heads", "head_dim"))
     v = shard_act(v, ("batch", "seq", "kv_heads", "head_dim"))
@@ -167,10 +171,43 @@ def _chunked_attention(q, k, v, cfg, window, q_chunk=512, kv_chunk=1024,
     return out
 
 
+def blocked_attention(q, k, v, window: int, q_block: int):
+    """Causal GQA attention of q (n, s, hq, hd) over k, v (n, s, kvh, hd),
+    in blocks of ``q_block`` queries, each block under ``jax.checkpoint``
+    (its backward recomputes the block's scores instead of keeping the
+    (s × s) probabilities). A block of a windowed layer (``window`` > 0)
+    reads only the ``q_block + window`` keys that can reach it; a full
+    layer's block reads every key under the causal mask."""
+    n, s, hq, hd = q.shape
+    kvh = k.shape[2]
+    Q = min(q_block, s)
+    assert s % Q == 0, (s, Q)
+    span = s if not window else min(s, Q + window)
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+
+    @jax.checkpoint
+    def one(i, qb):
+        start = 0 if span == s else jnp.clip(i * Q + Q - span, 0, s - span)
+        kb = lax.dynamic_slice_in_dim(k, start, span, axis=1)
+        vb = lax.dynamic_slice_in_dim(v, start, span, axis=1)
+        sc = _qk_logits(qb.reshape(n, Q, kvh, hq // kvh, hd), kb, 0.0) * scale
+        m = _mask(i * Q + jnp.arange(Q), start + jnp.arange(span), window)
+        p = jax.nn.softmax(jnp.where(m[None, None, None], sc, NEG_INF), -1)
+        return _pv(p, vb).reshape(n, Q, hq, hd)
+
+    qs = q.reshape(n, s // Q, Q, hq, hd).swapaxes(0, 1)
+    out = lax.map(lambda a: one(*a), (jnp.arange(s // Q), qs))
+    return out.swapaxes(0, 1).reshape(n, s, hq, hd)
+
+
 def attention(params, x, cfg: ModelConfig, *, positions=None, mrope_positions=None,
               window: int = 0, cache=None, cache_index=None, chunked=None,
-              return_kv: bool = False, kv_dtype=jnp.bfloat16):
+              return_kv: bool = False, kv_dtype=jnp.bfloat16,
+              rope=(None, 1.0)):
     """Returns (output (b, s, d_model), new_cache or None).
+
+    rope: (frequencies or None, cos/sin scale) of the layer's RoPE
+    (``transformer.layer_rope``).
 
     cache: {"k": (b, S, kvh, hd), "v": ...} — serve path writes the new token
     at ``cache_index`` then attends over positions <= cache_index.
@@ -181,7 +218,7 @@ def attention(params, x, cfg: ModelConfig, *, positions=None, mrope_positions=No
     hd = cfg.resolved_head_dim
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q, k, v = _project_qkv(params, x, cfg, positions, mrope_positions)
+    q, k, v = _project_qkv(params, x, cfg, positions, mrope_positions, rope)
 
     if cache is not None:
         # --- decode: one (or few) new token(s) against the cache -----------

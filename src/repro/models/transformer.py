@@ -108,18 +108,38 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn) if cfg.remat == "block" else fn
 
 
+def layer_rope(cfg: ModelConfig, kind: str):
+    """(frequencies, cos/sin scale) of a layer type's RoPE: YaRN on "full"
+    layers where ``cfg.yarn`` is set, else θ's own (None, 1.0)."""
+    if kind == "full" and cfg.yarn is not None:
+        from repro.models.rope import yarn_freqs
+        freqs, scale = yarn_freqs(cfg.resolved_head_dim, cfg.rope_theta,
+                                  cfg.yarn)
+        return jnp.asarray(freqs), scale
+    return None, 1.0
+
+
+def layer_window(cfg: ModelConfig, kind: str) -> int:
+    """A layer type's attention window: ``cfg.window`` on "sliding" layers,
+    0 (every earlier position) on "full" ones."""
+    return cfg.window if kind == "sliding" else 0
+
+
 def _dense_stack(params, x, cfg: ModelConfig, *, positions, mrope_positions,
                  caches=None, cache_index=None, return_kv=False):
-    """Scan over stacked dense/moe blocks. Returns (x, aux, new_caches)."""
+    """Scan over stacked dense/moe blocks, one period of ``cfg.layer_pattern``
+    per scan step. Returns (x, aux, new_caches)."""
+    pattern = cfg.layer_pattern or ("sliding",)
+    P = len(pattern)
 
-    def body(carry, inp):
-        x, aux = carry
-        layer_params, cache_l = inp
+    def one_layer(x, aux, layer_params, cache_l, kind):
         h, kv = attention(
             layer_params["attn_attn"], rmsnorm(layer_params["attn_ln"], x, cfg.norm_eps),
             cfg, positions=positions, mrope_positions=mrope_positions,
-            window=cfg.window, cache=cache_l, cache_index=cache_index,
-            return_kv=return_kv, kv_dtype=jnp.dtype(cfg.kv_cache_dtype))
+            window=layer_window(cfg, kind), cache=cache_l,
+            cache_index=cache_index, return_kv=return_kv,
+            kv_dtype=jnp.dtype(cfg.kv_cache_dtype),
+            rope=layer_rope(cfg, kind))
         x = x + h
         h = rmsnorm(layer_params["ffn_ln"], x, cfg.norm_eps)
         if cfg.moe.num_experts:
@@ -128,12 +148,38 @@ def _dense_stack(params, x, cfg: ModelConfig, *, positions, mrope_positions,
         else:
             y = swiglu(layer_params["ffn"], h)
         x = shard_act(x + y, ("batch", "seq", "embed_act"))
-        return (x, aux), kv
+        return x, aux, kv
+
+    def body(carry, inp):
+        x, aux = carry
+        period_params, period_caches = inp
+        if P == 1:
+            x, aux, kv = one_layer(x, aux, period_params, period_caches,
+                                   pattern[0])
+            return (x, aux), kv
+        kvs = []
+        for j, kind in enumerate(pattern):
+            at = functools.partial(lambda t, j: t[j], j=j)
+            x, aux, kv = one_layer(
+                x, aux, jax.tree_util.tree_map(at, period_params),
+                None if period_caches is None
+                else jax.tree_util.tree_map(at, period_caches), kind)
+            kvs.append(kv)
+        return (x, aux), jax.tree_util.tree_map(lambda *t: jnp.stack(t), *kvs)
+
+    def by_period(tree):
+        if P == 1 or tree is None:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda t: t.reshape((t.shape[0] // P, P) + t.shape[1:]), tree)
 
     body = _maybe_remat(body, cfg)
     (x, aux), kvs = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                             (params["blocks"], caches),
+                             (by_period(params["blocks"]), by_period(caches)),
                              unroll=cfg.unroll_layers)
+    if P > 1 and kvs is not None:
+        kvs = jax.tree_util.tree_map(
+            lambda t: t.reshape((t.shape[0] * P,) + t.shape[2:]), kvs)
     return x, aux, kvs
 
 

@@ -38,6 +38,15 @@ layer                  entered around
 ``aggregate``          the strategy's aggregation with the
                        ``merge_participation`` calls around it, entered by
                        the layout context
+``attention``          a language model's attention layer in
+                       ``core.lm``: its norm, projections, RoPE and the
+                       blocked attention, with ``sliding_attention`` or
+                       ``full_attention`` inside it (forward and backward)
+``moe_route``          the router, top-k, sort, gather and scatter of the
+                       expert share (``models.moe.share_route`` /
+                       ``expert_share``)
+``moe_experts``        the held experts' grouped products, forward and
+                       backward (``models.moe.expert_share``)
 =====================  =====================================================
 
 ``op_layers()`` turns the scopes into a table: for the chunk executable the
@@ -59,9 +68,11 @@ import jax
 
 from repro.obs.probes import REGISTRY
 
+#: the layers inside a language model's forward and backward (``core.lm``)
+MODEL_LAYERS = ("attention", "moe_route", "moe_experts")
 LAYERS = ("sample_batches", "local_update", "private_grad", "proxy_dp_grad",
           "metrics_step", "per_example_grads", "dp_clip", "dp_noise",
-          "aggregate")
+          "aggregate") + MODEL_LAYERS
 _LAYER_SET = frozenset(LAYERS)
 
 
