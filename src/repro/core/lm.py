@@ -35,20 +35,22 @@ layers and the expert share), the seam of ``dp.dp_layer_clipped_sum``.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig, MoEConfig, YarnConfig
+from repro.config import KernelConfig, ModelConfig, MoEConfig, YarnConfig
 from repro.core import dp as dp_lib
+from repro.kernels import dispatch
 from repro.models import moe as moe_lib
-from repro.models.attention import blocked_attention
 from repro.models.module import ParamSpec
 from repro.models.rope import apply_rope
 from repro.models.transformer import layer_rope, layer_window
 from repro.obs import layer
 
-#: queries per attention block (models.attention.blocked_attention)
+#: queries per attention block of the ref backend
+#: (models.attention.blocked_attention)
 Q_BLOCK = 256
 
 
@@ -81,9 +83,10 @@ def lm_specs(cfg: ModelConfig):
     }
 
 
-def make_lm(cfg: ModelConfig):
+def make_lm(cfg: ModelConfig, kernels: Optional[KernelConfig] = None):
     """(specs, apply) of the language model ``cfg`` describes. ``apply``
-    maps params and tokens (n, s) to logits (n, s, V)."""
+    maps params and tokens (n, s) to logits (n, s, V); attention takes the
+    backend ``kernels`` selects (``kernels.dispatch.flash_attention``)."""
     pattern = cfg.layer_pattern or ("sliding",)
     P = len(pattern)
     assert cfg.num_layers % P == 0, (cfg.num_layers, pattern)
@@ -108,6 +111,17 @@ def make_lm(cfg: ModelConfig):
             return xhat * scale
         return dp_lib.tracked_scale(xhat, scale, probe)
 
+    @partial(jax.checkpoint, static_argnums=(5,))
+    def attend(q, k, v, wo, probe, window):
+        """Attention and its output projection under one checkpoint: the
+        layer's backward recomputes the attention's f32 output for wo's
+        gradient rather than keep it (134 MB a sequence at 8,192 tokens)
+        through the expert share's backward."""
+        n, s = q.shape[:2]
+        o = dispatch.flash_attention(q, k, v, window=window,
+                                     ref_q_block=Q_BLOCK, kernels=kernels)
+        return dense(o.reshape(n, s, -1), wo, probe)
+
     def block(x, lp, kind, probe):
         n, s, _ = x.shape
         freqs, scale = layer_rope(cfg, kind)
@@ -120,8 +134,7 @@ def make_lm(cfg: ModelConfig):
             pos = jnp.arange(s)[None, :]
             q = apply_rope(q, pos, cfg.rope_theta, freqs=freqs, scale=scale)
             k = apply_rope(k, pos, cfg.rope_theta, freqs=freqs, scale=scale)
-            o = blocked_attention(q, k, v, window, Q_BLOCK)
-            x = x + dense(o.reshape(n, s, -1), lp["wo"], probe)
+            x = x + attend(q, k, v, lp["wo"], probe, window)
         h = norm(x, lp["moe_norm"], probe)
         with layer("moe_route"):
             route = moe_lib.share_route(

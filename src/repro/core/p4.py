@@ -94,7 +94,7 @@ class P4Trainer:
         elif self.model == "lm":
             from repro.core.lm import lm_config, make_lm
             self.lm_cfg = lm_config(self.lm)
-            self.specs, self.apply_fn = make_lm(self.lm_cfg)
+            self.specs, self.apply_fn = make_lm(self.lm_cfg, self.cfg.kernels)
         else:
             self.specs, self.apply_fn = make_cnn(self.cnn_shape, self.num_classes)
         dpc = self.cfg.dp

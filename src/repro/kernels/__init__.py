@@ -3,7 +3,8 @@
   dp_clip          — per-example gradient clip-scale-accumulate, the DP-SGD
                      throughput bottleneck (paper Phase 2 inner loop)
   l1_distance      — pairwise ℓ1 over flattened client weights (Phase 1)
-  flash_attention  — blocked online-softmax attention (prefill at 32k/500k)
+  flash_attention  — causal (and sliding-window) GQA flash attention, forward
+                     and backward: the language model's attention
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper), ref.py (pure-jnp oracle). Backend selection and tile autotuning

@@ -24,7 +24,9 @@ naming the kernel and shape.
 
 Every dispatch records the backend it resolved to in the
 ``kernels.backend`` probe (``"<kernel>:<backend>"`` counters, bumped once
-per trace), so a run can prove which implementation it traced.
+per trace), so a run can prove which implementation it traced; a shape the
+kernel does not tile counts as the ``ref`` route it takes
+(``flash_attention``).
 
 Fused DP-SGD entry points (paper Eqs. 10–11 hot loop): ``dp_clip`` /
 ``dp_clip_flat`` fuse flatten→norm→scale→accumulate→noise so the (B, D)
@@ -48,7 +50,9 @@ from repro.obs.probes import Probe
 from repro.kernels.dp_clip import kernel as dp_kernel, ops as dp_ops, ref as dp_ref
 from repro.kernels.dp_round import (kernel as dpr_kernel, ops as dpr_ops,
                                     ref as dpr_ref)
+from repro.kernels.flash_attention import ops as fl_ops
 from repro.kernels.l1_distance import kernel as l1_kernel, ops as l1_ops, ref as l1_ref
+from repro.models.attention import blocked_attention
 from repro.utils.pytree import tree_flatten_concat, tree_unflatten_concat
 
 # Platforms with a Pallas compile path (Mosaic). GPU/Triton is untested in
@@ -90,7 +94,7 @@ _TUNE_STATS = Probe("kernels.autotune", {"hits": 0, "misses": 0,
                                          "candidates_failed": 0,
                                          "search_seconds": 0.0})
 
-_KERNELS = ("dp_clip", "dp_round", "l1_distance")
+_KERNELS = ("dp_clip", "dp_round", "l1_distance", "flash_attention")
 # resolved backend per dispatched kernel, counted at trace time
 _BACKEND_STATS = Probe("kernels.backend",
                        {f"{k}:{b}": 0 for k in _KERNELS
@@ -114,8 +118,13 @@ def tuned_tiles() -> Dict[str, Dict[Tuple[int, ...], Tuple[int, ...]]]:
     return out
 
 
-def _resolve_for(kernel_name: str, cfg: KernelConfig) -> str:
+def _resolve_for(kernel_name: str, cfg: KernelConfig,
+                 tiled: bool = True) -> str:
+    """The backend ``kernel_name`` takes, counted in ``kernels.backend``;
+    "ref" where the kernel does not tile the shape (``tiled`` false)."""
     backend = resolve_backend(cfg.backend)
+    if not tiled:
+        backend = "ref"
     _BACKEND_STATS[f"{kernel_name}:{backend}"] += 1
     return backend
 
@@ -402,3 +411,23 @@ def pairwise_l1(weights, kernels: Optional[KernelConfig] = None):
     tm, td = l1_tiles(tuple(weights.shape), weights.dtype, cfg, backend)
     return l1_ops.pairwise_l1(weights, interpret=(backend == "interpret"),
                               tm=tm, td=td)
+
+
+def flash_attention(q, k, v, *, window: int, ref_q_block: int,
+                    kernels: Optional[KernelConfig] = None):
+    """Causal GQA attention of q (n, s, hq, d) over k, v (n, s, kvh, d),
+    each query reading the keys at or before it (the last ``window`` of
+    them where ``window`` > 0), differentiable in q, k and v.
+
+    The Pallas flash kernels (``kernels.flash_attention``: forward, dq and
+    dk/dv under one custom VJP) where they tile the shape (``ops.tiles``);
+    the ref backend, and any shape they do not tile, takes
+    ``models.attention.blocked_attention`` in blocks of ``ref_q_block``
+    queries."""
+    cfg = _cfg(kernels)
+    backend = _resolve_for("flash_attention", cfg,
+                           fl_ops.tiles(q.shape[1], q.shape[3]))
+    if backend == "ref":
+        return blocked_attention(q, k, v, window, ref_q_block)
+    return fl_ops.flash_attention(q, k, v, window=window,
+                                  interpret=(backend == "interpret"))

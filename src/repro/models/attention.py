@@ -8,9 +8,12 @@ Three execution paths:
                    materialized, which is what makes 32k prefill fit.
   * ``decode``   — one query token against a (possibly windowed) KV cache.
 
-The Pallas TPU kernel (repro.kernels.flash_attention) implements the chunked
-algorithm with explicit VMEM BlockSpecs; on-CPU it is validated in interpret
-mode against repro.kernels.flash_attention.ref.
+The Pallas TPU kernels (repro.kernels.flash_attention) implement the chunked
+algorithm with explicit VMEM BlockSpecs, forward and backward; they are the
+co-train language model's attention (``core.lm``, through
+``kernels.dispatch.flash_attention``), whose ref backend is
+``blocked_attention`` below. On the CPU they are validated in interpret mode
+against it and against repro.kernels.flash_attention.ref.
 """
 from __future__ import annotations
 

@@ -40,8 +40,11 @@ layer                  entered around
                        the layout context
 ``attention``          a language model's attention layer in
                        ``core.lm``: its norm, projections, RoPE and the
-                       blocked attention, with ``sliding_attention`` or
-                       ``full_attention`` inside it (forward and backward)
+                       attention (``kernels.dispatch.flash_attention``: the
+                       Pallas kernels ``flash_fwd``, ``flash_dq`` and
+                       ``flash_dkv``, or the ref backend's blocks), with
+                       ``sliding_attention`` or ``full_attention`` inside it
+                       (forward and backward)
 ``moe_route``          the router, top-k, sort, gather and scatter of the
                        expert share (``models.moe.share_route`` /
                        ``expert_share``)

@@ -141,6 +141,32 @@ def test_backend_probe_records_resolved_backend(key):
     assert got == {"l1_distance:ref": 1, "dp_clip:interpret": 1}
 
 
+@pytest.mark.parametrize("hd,s,route", [(128, 32, "interpret"),
+                                         (32, 32, "ref"), (128, 24, "ref")],
+                         ids=["tiled", "narrow-heads", "untiled-length"])
+def test_flash_attention_dispatch_counts_the_route_taken(key, monkeypatch,
+                                                         hd, s, route):
+    """The language model's attention takes the kernels where they tile the
+    shape (heads of 128 lanes, s a multiple of the blocks) and
+    ``blocked_attention`` otherwise, and the ``kernels.backend`` probe
+    counts the route taken; both routes give the same attention."""
+    from repro.kernels.flash_attention import kernel as fl_kernel
+    from repro.models.attention import blocked_attention
+    from repro.obs import probe_deltas
+    monkeypatch.setattr(fl_kernel, "BLOCK_Q", 16)
+    monkeypatch.setattr(fl_kernel, "BLOCK_K", 16)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, s, h, hd))
+               for i, h in enumerate((4, 2, 2)))
+    with probe_deltas("kernels.backend") as d:
+        got = dispatch.flash_attention(q, k, v, window=5, ref_q_block=8,
+                                       kernels=KernelConfig(
+                                           backend="interpret"))
+    counted = {k: v for k, v in d["kernels.backend"].items() if v}
+    assert counted == {f"flash_attention:{route}": 1}
+    np.testing.assert_allclose(got, blocked_attention(q, k, v, 5, 8),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_explicit_tile_override_bypasses_autotune():
     cfg = KernelConfig(dp_clip_tile=(4, 512), l1_tile=(4, 256))
     assert dispatch.dp_clip_tiles((16, 1024), jnp.float32, cfg, "pallas") == (4, 512)

@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels.dp_clip import ops as dp_ops, ref as dp_ref
 from repro.kernels.dp_round import ops as dpr_ops, ref as dpr_ref
-from repro.kernels.flash_attention import kernel as fl_kernel, ops as fl_ops, ref as fl_ref
+from repro.kernels.flash_attention import ops as fl_ops, ref as fl_ref
 from repro.kernels.l1_distance import ops as l1_ops, ref as l1_ref
 
 
@@ -65,12 +65,13 @@ def test_l1_sweep(key, M, D, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_sweep(key, S, d, blocks, window, dtype):
     BH = 4
-    q = jax.random.normal(key, (BH, S, d)).astype(dtype)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (BH, S, d)).astype(dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (BH, S, d)).astype(dtype)
-    got = fl_kernel.flash_attention(q, k, v, causal=True, window=window,
-                                    block_q=blocks[0], block_k=blocks[1])
-    want = fl_ref.attention(q, k, v, causal=True, window=window)
+    q = jax.random.normal(key, (BH, S, 1, d)).astype(dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (BH, S, 1, d)).astype(dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (BH, S, 1, d)).astype(dtype)
+    got = fl_ops.flash_attention(q, k, v, window=window, block_q=blocks[0],
+                                 block_k=blocks[1])[:, :, 0]
+    want = fl_ref.attention(q[:, :, 0], k[:, :, 0], v[:, :, 0], causal=True,
+                            window=window)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), rtol=tol, atol=tol)
@@ -81,7 +82,7 @@ def test_flash_gqa_wrapper(key):
     q = jax.random.normal(key, (b, s, hq, d))
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hkv, d))
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, hkv, d))
-    got = fl_ops.flash_attention_gqa(q, k, v, block_q=64, block_k=64)
+    got = fl_ops.flash_attention(q, k, v, block_q=64, block_k=64)
     kx, vx = jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2)
     def bh(t):
         return t.transpose(0, 2, 1, 3).reshape(b * hq, s, d)
@@ -101,9 +102,91 @@ def test_flash_matches_model_chunked_path(key):
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, h, d))
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h, d))
     chunked = _chunked_attention(q, k, v, cfg, window=0, q_chunk=64, kv_chunk=64)
-    flash = fl_ops.flash_attention_gqa(q, k, v, block_q=64, block_k=64)
+    flash = fl_ops.flash_attention(q, k, v, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(flash),
                                rtol=2e-5, atol=2e-5)
+
+
+def _gqa(key, n, s, hq, kvh, d):
+    return (jax.random.normal(jax.random.fold_in(key, i), (n, s, h, d))
+            for i, h in enumerate((hq, kvh, kvh)))
+
+
+def _dense_attention(q, k, v, window):
+    """The masked softmax over every key, at HIGHEST precision."""
+    n, s, hq, d = q.shape
+    g = hq // k.shape[2]
+    kk, vv = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    sc = jnp.einsum("nqhd,nkhd->nhqk", q, kk,
+                    precision=jax.lax.Precision.HIGHEST) / np.sqrt(d)
+    pos = jnp.arange(s)
+    ok = pos[None, :] <= pos[:, None]
+    if window:
+        ok &= pos[None, :] > pos[:, None] - window
+    p = jax.nn.softmax(jnp.where(ok, sc, -jnp.inf), -1)
+    return jnp.einsum("nhqk,nkhd->nqhd", p, vv,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+# (bq, bk) and a window: 21 is no multiple of 16, so the last query block's
+# reach (keys 28 on) starts inside key block 1; 40 against blocks of 32 and 16
+@pytest.mark.parametrize("blocks,window", [((16, 16), 0), ((16, 32), 0),
+                                           ((32, 16), 0), ((16, 16), 21),
+                                           ((32, 16), 40), ((16, 32), 21)],
+                         ids=["full-16x16", "full-16x32", "full-32x16",
+                              "sliding21-16x16", "sliding40-32x16",
+                              "sliding21-16x32"])
+@pytest.mark.parametrize("g", [1, 4])
+def test_flash_gradients_match_blocked_and_dense(key, blocks, window, g):
+    """Forward and ``jax.grad`` with respect to q, k and v of the kernels'
+    custom VJP against ``blocked_attention`` (the ref backend) and the
+    dense masked softmax, on full and sliding layers, with g query heads a
+    KV head."""
+    from repro.models.attention import blocked_attention
+    n, s, kvh, d = 2, 64, 2, 32
+    q, k, v = _gqa(key, n, s, kvh * g, kvh, d)
+    ct = jax.random.normal(jax.random.fold_in(key, 9), q.shape)
+
+    def flash(q, k, v):
+        return fl_ops.flash_attention(q, k, v, window=window,
+                                      block_q=blocks[0], block_k=blocks[1])
+
+    impls = {"flash": flash,
+             "blocked": lambda q, k, v: blocked_attention(q, k, v, window, 8),
+             "dense": lambda q, k, v: _dense_attention(q, k, v, window)}
+    outs = {name: f(q, k, v) for name, f in impls.items()}
+    grads = {name: jax.grad(lambda *a: jnp.sum(f(*a) * ct), (0, 1, 2))(q, k, v)
+             for name, f in impls.items()}
+    for other in ("blocked", "dense"):
+        np.testing.assert_allclose(outs["flash"], outs[other],
+                                   rtol=2e-5, atol=2e-5)
+        for a, b in zip(grads["flash"], grads[other]):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [0, 21])
+def test_flash_keys_outside_the_window_get_no_gradient(key, window):
+    """A loss on one query's output: the keys it cannot read (after it, or
+    before its window) get exactly zero dk and dv, and the keys it reads
+    do not; dq is zero at every other query."""
+    n, s, kvh, g, d, at = 1, 64, 2, 4, 32, 50
+    q, k, v = _gqa(key, n, s, kvh * g, kvh, d)
+
+    def loss(q, k, v):
+        o = fl_ops.flash_attention(q, k, v, window=window, block_q=16,
+                                   block_k=16)
+        return jnp.sum(o[:, at] ** 2)
+
+    dq, dk, dv = jax.grad(loss, (0, 1, 2))(q, k, v)
+    pos = np.arange(s)
+    reach = pos <= at
+    if window:
+        reach &= pos > at - window
+    for grad in (dk, dv):
+        moved = np.asarray(jnp.max(jnp.abs(grad), axis=(0, 2, 3)) > 0)
+        np.testing.assert_array_equal(moved, reach)
+    moved_q = np.asarray(jnp.max(jnp.abs(dq), axis=(0, 2, 3)) > 0)
+    np.testing.assert_array_equal(moved_q, pos == at)
 
 
 # ---------------------------------------------------------------------------

@@ -279,3 +279,52 @@ def test_a_language_model_round_names_its_layers():
     assert seen == set(LAYERS) and set(MODEL_LAYERS) <= seen
     acc = trainer.evaluate(state, data.test_x, data.test_y)
     assert acc.shape == (2,) and bool(jnp.all((acc >= 0) & (acc <= 1)))
+
+
+def test_flash_kernels_match_the_ref_path_in_the_dp_step(monkeypatch):
+    """The language model with its attention on the Pallas kernels
+    (interpret) against the ref backend (``blocked_attention``), at this
+    file's CPU size with heads of 128 (the kernels' lanes) and blocks of 8
+    of the 16 tokens: the logits, each sequence's squared norm the
+    per-layer route leaves on its probe, and the clipped sum of
+    ``dp.dp_layer_clipped_sum`` agree to float32 sums in another order.
+    So the kernels' custom VJP composes with the layer checkpoint, the
+    probes and both backwards of one forward."""
+    from repro.config import KernelConfig
+    from repro.kernels.flash_attention import kernel as fl_kernel
+    from repro.obs import probe_deltas
+    monkeypatch.setattr(fl_kernel, "BLOCK_Q", 8)
+    monkeypatch.setattr(fl_kernel, "BLOCK_K", 8)
+    cfg = lm_config(dict(LM, head_dim=128))
+    key = jax.random.PRNGKey(13)
+    t = jax.random.randint(jax.random.fold_in(key, 1), (3, 17), 0, 300)
+
+    def logit_grads(z):
+        return jax.vmap(jax.grad(lambda zz, y: -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(zz), y[:, None], -1))))(z, t[:, 1:])
+
+    got = {}
+    for backend in ("interpret", "ref"):
+        specs, apply = make_lm(cfg, KernelConfig(backend=backend))
+        p = init_params(specs, key)
+        with probe_deltas("kernels.backend") as d:
+            logits, vjp = jax.vjp(lambda q, z: apply.layer_forward(
+                q, t[:, :-1], z), p, jnp.zeros((3,)))
+            _, sq = vjp(logit_grads(logits))
+            clip = float(jnp.sqrt(jnp.median(sq)))
+            total, _ = dp.dp_layer_clipped_sum(
+                apply.layer_forward, p, t[:, :-1], logit_grads, clip=clip,
+                denom=3.0)
+        assert d["kernels.backend"][f"flash_attention:{backend}"] > 0
+        got[backend] = (apply(p, t[:, :-1]), logits, sq, clip, total)
+    flash, ref = got["interpret"], got["ref"]
+    for a, b in zip(flash[:2], ref[:2]):
+        np.testing.assert_allclose(a, b, rtol=F32_REL,
+                                   atol=F32_REL * float(jnp.max(jnp.abs(b))))
+    np.testing.assert_allclose(flash[2], ref[2], rtol=1e-4)
+    assert flash[3] == pytest.approx(ref[3], rel=1e-4)
+    assert float(jnp.min(ref[2])) < ref[3] ** 2 < float(jnp.max(ref[2]))
+    for k in ref[4]:
+        np.testing.assert_allclose(
+            flash[4][k], ref[4][k], rtol=1e-4,
+            atol=1e-5 * float(jnp.max(jnp.abs(ref[4][k]))))

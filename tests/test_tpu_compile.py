@@ -8,13 +8,18 @@ autotuner may pick, and assert that the Mosaic kernel is in the executable:
 
   * ``dp_clip``     B = 32, D = 155,530, plain and vmapped over 64 clients;
   * ``l1_distance`` M = 64, D = 155,530;
-  * ``dp_round``    B = 32, F = 15552, C = 10, plain and vmapped over 64.
+  * ``dp_round``    B = 32, F = 15552, C = 10, plain and vmapped over 64;
+  * ``flash_attention`` forward and backward at the language-model cell's
+    widths (s = 8,192, 32 query and 4 KV heads of 128), full and sliding
+    (window 1,024), and inside a small language model's DP step, where
+    ``obs.hlo_layers`` must map each kernel's custom call to ``attention``.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +102,74 @@ def test_dp_round_vmapped_over_clients_compiles(one_chip, tf):
     _assert_mosaic(jax.vmap(_dp_round(tf)), params,
                    _spec(one_chip, (M, B, F)),
                    _spec(one_chip, (M, B), jnp.int32))
+
+
+# the language-model cell's attention (Mellum2: 32 query, 4 KV heads of 128)
+N, S, HQ, KVH, HD = 1, 8192, 32, 4, 128
+
+
+def _custom_calls(text):
+    """(instruction name, kernel name) of each Mosaic call in HLO text."""
+    out = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=", line)
+            if m:
+                out.append((m.group(1), m.group(1).rsplit(".", 1)[0]))
+    return out
+
+
+@pytest.mark.parametrize("window", [0, 1024], ids=["full", "sliding"])
+def test_flash_attention_compiles(one_chip, window):
+    from repro.kernels.flash_attention import ops as fl_ops
+
+    def fwd_bwd(q, k, v):
+        o, vjp = jax.vjp(lambda *a: fl_ops.flash_attention(
+            *a, window=window, interpret=False), q, k, v)
+        return vjp(o)
+
+    q = _spec(one_chip, (N, S, HQ, HD))
+    k = _spec(one_chip, (N, S, KVH, HD))
+    text = jax.jit(fwd_bwd).lower(q, k, k).compile().as_text()
+    names = sorted(kernel for _, kernel in _custom_calls(text))
+    assert names == ["flash_dkv", "flash_dq", "flash_fwd"]
+
+
+def test_lm_attention_kernels_map_to_the_attention_layer(one_chip,
+                                                          monkeypatch):
+    """A small language model's DP step (``dp_layer_clipped_sum``: one
+    forward, both backwards under the layer checkpoint) on the Pallas
+    backend: every forward, dq and dk/dv kernel call is in the op -> layer
+    table under ``attention``, so ``attention_share`` counts the kernels."""
+    from repro.config import KernelConfig
+    from repro.core import dp
+    from repro.core.lm import lm_config, make_lm
+    from repro.models.module import abstract_params
+    from repro.obs.layers import hlo_layers
+    monkeypatch.setattr(dispatch, "_PALLAS_PLATFORMS", ("tpu", "cpu"))
+    cfg = lm_config(dict(
+        num_layers=2, d_model=256, num_heads=8, num_kv_heads=1, head_dim=128,
+        d_ff=128, vocab_size=512, window=256, layer_pattern=["sliding", "full"],
+        moe=dict(num_experts=8, experts_per_token=2, held_count=4)))
+    specs, apply = make_lm(cfg, KernelConfig(backend="pallas"))
+    params = {k: _spec(one_chip, v.shape)
+              for k, v in abstract_params(specs).items()}
+
+    def step(p, t):
+        total, _ = dp.dp_layer_clipped_sum(
+            apply.layer_forward, p, t, lambda z: 1e-3 * jnp.ones_like(z),
+            clip=1.0, denom=2.0)
+        return total
+
+    text = jax.jit(step).lower(
+        params, _spec(one_chip, (2, 512), jnp.int32)).compile().as_text()
+    table = hlo_layers(text)
+    calls = [(name, kernel) for name, kernel in _custom_calls(text)
+             if kernel.startswith("flash_")]
+    # dq and dk/dv in each of the two backwards, for each of the two
+    # layers, beside the forwards and their recomputations
+    kinds = [kernel for _, kernel in calls]
+    assert kinds.count("flash_dq") == kinds.count("flash_dkv") == 4
+    assert kinds.count("flash_fwd") >= 6
+    for name, _ in calls:
+        assert "attention" in table[name], (name, table.get(name))
